@@ -1,4 +1,4 @@
-"""Batched vs serial fit serving, 16 same-shape requests on one CPU device.
+"""Batched vs serial fit serving, 16 same-shape requests on one device.
 
   - serial  : ``DecsvmFitServer(max_batch=1)`` — every request resolves
               through its own path-program execution (the PR-4 behavior:
@@ -19,17 +19,15 @@ max abs deviation <= 1e-5.
 from __future__ import annotations
 
 import json
-import os
 import time
 from pathlib import Path
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import jax
+import numpy as np
 
-import numpy as np                 # noqa: E402
-
-from repro.core import ADMMConfig, SimConfig, generate, tuning  # noqa: E402
-from repro.core.graph import erdos_renyi  # noqa: E402
-from repro.serving import DecsvmFitServer, FitRequest  # noqa: E402
+from repro.core import ADMMConfig, SimConfig, generate, tuning
+from repro.core.graph import erdos_renyi
+from repro.serving import DecsvmFitServer, FitRequest
 
 M, N, P, GRID, MAX_ITER, NREQ = 4, 80, 24, 8, 200, 16
 MODE = "warm"           # the server default: continuation + KKT early stop
@@ -83,7 +81,8 @@ def run() -> dict:
         "bench": "fit_serving",
         "config": {"m": M, "n": N, "p": P, "grid": GRID,
                    "max_iter": MAX_ITER, "requests": NREQ, "mode": MODE,
-                   "backend": os.environ.get("JAX_PLATFORMS", "cpu")},
+                   "backend": jax.devices()[0].platform,
+                   "device_kind": jax.devices()[0].device_kind},
         "end_to_end_s": {"serial": ser_e2e, "batched": bat_e2e},
         "steady_state_s": {"serial": ser_ss, "batched": bat_ss},
         "throughput_fits_per_s": {"serial": NREQ / ser_ss,

@@ -27,18 +27,15 @@ reads X once per k-round block.
 from __future__ import annotations
 
 import json
-import os
 import time
 from pathlib import Path
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import jax
+import jax.numpy as jnp
 
-import jax                     # noqa: E402  (env must be set pre-import)
-import jax.numpy as jnp        # noqa: E402
-
-from repro.core import ADMMConfig, SimConfig, decsvm_fit, generate, losses  # noqa: E402
-from repro.core.graph import erdos_renyi  # noqa: E402
-from repro.kernels.csvm_update import megakernel_vmem_bytes  # noqa: E402
+from repro.core import ADMMConfig, SimConfig, decsvm_fit, generate, losses
+from repro.core.graph import erdos_renyi
+from repro.kernels.csvm_update import megakernel_vmem_bytes
 
 M, N, P, MAX_ITER = 8, 100, 50, 300
 STEADY_REPS = 5
@@ -103,7 +100,8 @@ def run() -> dict:
     result = {
         "bench": "megakernel",
         "config": {"m": M, "n": N, "p": P, "max_iter": MAX_ITER, "h": h,
-                   "backend": jax.default_backend(),
+                   "backend": jax.devices()[0].platform,
+                   "device_kind": jax.devices()[0].device_kind,
                    "pallas_interpret": jax.default_backend() != "tpu"},
         "end_to_end_s": e2e,
         "steady_state_s": steady,

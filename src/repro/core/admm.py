@@ -63,7 +63,7 @@ def admm_step(X: Array, y: Array, W: Array, deg: Array, rho: Array,
     omega = 1.0 / (2.0 * cfg.tau * deg + rho + cfg.lam0)
     prob = solver.Problem(X.astype(solver.problem_dtype(cfg)), y, deg, rho,
                           omega, None)
-    step = solver.make_step(cfg, lambda B: W @ B, W=W)
+    step = solver.make_step(cfg, lambda B: solver.mm(W, B), W=W)
     st = solver.SolverState(state.B, state.P, state.t,
                             jnp.asarray(jnp.inf, jnp.float32))
     new = step(prob, st, cfg.lam, lam_weights)
@@ -72,7 +72,7 @@ def admm_step(X: Array, y: Array, W: Array, deg: Array, rho: Array,
 
 def _decsvm_fit_impl(X, y, W, beta0, lam_weights, cfg, track_history):
     prob = solver.make_problem(X, y, W, cfg)
-    step = solver.make_step(cfg, lambda B: W @ B, W=W)
+    step = solver.make_step(cfg, lambda B: solver.mm(W, B), W=W)
     state = solver.init_state(prob, B0=beta0)
     out = solver.run_fixed(step, prob, cfg.lam, lam_weights,
                            num_iters=cfg.max_iter, state=state,
@@ -123,7 +123,7 @@ def decsvm_fit(X: Array, y: Array, W: Array, cfg: ADMMConfig,
 def objective(X: Array, y: Array, beta: Array, cfg: ADMMConfig) -> Array:
     """Network-wide smoothed elastic-net objective (eq. 3/4) at a common beta."""
     k = losses.get_kernel(cfg.kernel)
-    margins = y * jnp.einsum("mnp,p->mn", X, beta)
+    margins = y * jnp.einsum("mnp,p->mn", X, beta, precision=solver.F32)
     data = jnp.mean(k.loss(margins, cfg.h))
     return data + 0.5 * cfg.lam0 * jnp.sum(beta**2) + cfg.lam * jnp.sum(jnp.abs(beta))
 

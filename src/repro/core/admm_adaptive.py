@@ -24,7 +24,7 @@ Array = jax.Array
 
 def _fit_tol_impl(X, y, W, tol, cfg, stop_rule, check_every):
     prob = solver.make_problem(X, y, W, cfg)
-    step = solver.make_step(cfg, lambda B: W @ B, W=W)
+    step = solver.make_step(cfg, lambda B: solver.mm(W, B), W=W)
     residual_fn = (solver.kkt_residual_fn(cfg) if stop_rule == "kkt"
                    else None)
     final = solver.run_tol(step, prob, cfg.lam, max_iter=cfg.max_iter,
@@ -72,7 +72,7 @@ def decsvm_fit_tol(X: Array, y: Array, W: Array, cfg: ADMMConfig,
 
 def _fit_uneven_impl(X, y, mask, W, cfg):
     prob = solver.make_problem(X, y, W, cfg, mask=mask)
-    step = solver.make_step(cfg, lambda B: W @ B, W=W)
+    step = solver.make_step(cfg, lambda B: solver.mm(W, B), W=W)
     final = solver.run_fixed(step, prob, cfg.lam, num_iters=cfg.max_iter)
     return final.B
 

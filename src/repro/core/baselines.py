@@ -18,6 +18,7 @@ import numpy as np
 from repro.core import losses
 from repro.core.admm import ADMMConfig, power_iteration_lmax, soft_threshold
 from repro.core.graph import metropolis_weights
+from repro.core.solver import mm
 
 Array = jax.Array
 
@@ -38,8 +39,8 @@ def pooled_csvm(X: Array, y: Array, cfg: ADMMConfig, max_iter: int = 500) -> Arr
     step = 1.0 / (L * 1.01)
 
     def smooth_grad(b):
-        margin = y * (X @ b)
-        return X.T @ (kern.dloss(margin, cfg.h) * y) / N + cfg.lam0 * b
+        margin = y * mm(X, b)
+        return mm(X.T, kern.dloss(margin, cfg.h) * y) / N + cfg.lam0 * b
 
     def body(carry, _):
         b, z, tk = carry
@@ -63,7 +64,7 @@ def average_consensus(B_local: Array, W: np.ndarray, rounds: int = 100) -> Array
     M = jnp.asarray(metropolis_weights(np.asarray(W)))
 
     def body(B, _):
-        return M @ B, None
+        return mm(M, B), None
 
     B, _ = jax.lax.scan(body, B_local, None, length=rounds)
     return B
@@ -80,12 +81,12 @@ def d_subgd(X: Array, y: Array, Wmix: Array, lam: float = 0.05,
     m, n, p = X.shape
 
     def node_subgrad(Xl, yl, bl):
-        margin = yl * (Xl @ bl)
-        g = Xl.T @ (losses.hinge_subgrad(margin) * yl) / n
+        margin = yl * mm(Xl, bl)
+        g = mm(Xl.T, losses.hinge_subgrad(margin) * yl) / n
         return g + lam * jnp.sign(bl)
 
     def body(B, t):
-        mixed = Wmix @ B
+        mixed = mm(Wmix, B)
         G = jax.vmap(node_subgrad)(X, y, mixed)
         eta = lr0 / jnp.sqrt(t + 1.0)
         return mixed - eta * G, None

@@ -44,32 +44,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import sanitize, solver
 from repro.core.admm import ADMMConfig
 
 Array = jax.Array
 
-# JAX >= 0.7 requires zero-init scan carries inside shard_map to be marked
-# varying over the manual axes; older JAX has no pvary and needs no mark.
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
+def _pvary(x, axes):
+    """Mark a zero-init scan carry as varying over the manual axes (shard_map
+    requires carries that become shard-local to start out varying)."""
+    return jax.lax.pcast(x, axes, to="varying")
 
 
-def _shard_map_no_rep_check(f, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across JAX versions.
+def _replicated(mesh: Mesh, x: Array) -> Array:
+    """An engine's result, replicated on every device of ``mesh``.
 
-    JAX 0.4.x has no replication rule for while_loop (the early-stopped
-    warm traversal inside the mesh program), so checking must be disabled;
-    the flag is ``check_rep`` there and ``check_vma`` on newer JAX.
-    """
-    for kw in ("check_rep", "check_vma"):
-        try:
-            return shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **{kw: False})
-        except TypeError:
-            continue
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    Outputs leave ``shard_map`` sharded over the node (and lam) axes.  On a
+    mesh with explicit axis types (``jax.make_mesh``'s default) slicing or
+    indexing such an axis outside the program -- the ghost-row trim, the
+    selected path row -- is a sharding type error, so every engine hands its
+    result back replicated, as the sharded engines always documented."""
+    return jax.device_put(x, NamedSharding(mesh, P()))
 
 
 def make_node_mesh(n_devices: Optional[int] = None) -> Mesh:
@@ -108,7 +103,7 @@ def _neighbor_sum_fn(schedule: str, ndev: int, Wl: Optional[Array]):
 
     def gather_sum(Bl):
         B_all = jax.lax.all_gather(Bl, "node", axis=0, tiled=True)   # (m, p)
-        return Wl @ B_all
+        return solver.mm(Wl, B_all)
 
     return gather_sum
 
@@ -134,7 +129,7 @@ def _block_neighbor_sum_fn(axis: str, ndev: int, Wd_l: Array,
     rows of the K kept off-diagonal block diagonals.
     """
     def block_sum(Bl):
-        acc = Wd_l @ Bl
+        acc = solver.mm(Wd_l, Bl)
         moving = Bl
         prev = 0
         for j, k in enumerate(offsets):
@@ -143,7 +138,7 @@ def _block_neighbor_sum_fn(axis: str, ndev: int, Wd_l: Array,
             # the permute ``moving`` on device d holds chunk (d + k)'s B
             perm = [(s, (s - shift) % ndev) for s in range(ndev)]
             moving = jax.lax.ppermute(moving, axis, perm)
-            acc = acc + Woff_l[j] @ moving
+            acc = acc + solver.mm(Woff_l[j], moving)
             prev = k
         return acc
 
@@ -199,7 +194,7 @@ def build_sharded_admm(m: int, p: int, cfg: ADMMConfig, mesh: Mesh,
         return solver.run_fixed(step, prob, cfg.lam, lamw,
                                 num_iters=cfg.max_iter, state=state).B
 
-    fn = shard_map(
+    fn = jax.shard_map(
         sharded_loop, mesh=mesh,
         in_specs=(P("node"), P("node"), P("node"), P("node"), P("node"),
                   P()),
@@ -237,7 +232,7 @@ def build_sharded_path(m: int, p: int, L: int, cfg: ADMMConfig, mesh: Mesh,
         prog0 = _pvary(jnp.full((L,), jnp.inf, sdt), ("node",))
         return jax.vmap(fit_one)(lams, B0, P0, prog0)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         sharded_loop, mesh=mesh,
         in_specs=(P("node"), P("node"), P("node"), P("node"), P("node"),
                   P(), P()),
@@ -292,7 +287,8 @@ def decsvm_fit_sharded(X: Array, y: Array, W: np.ndarray, cfg: ADMMConfig,
     X = jax.device_put(X.astype(solver.problem_dtype(cfg)), node_sharded)
     y = jax.device_put(y, node_sharded)
     fitted = build_sharded_admm(m, p, cfg, mesh, schedule)
-    return fitted(X, y, Wj, deg, rho, _lamw(lam_weights, p, jnp.float32))
+    return _replicated(mesh, fitted(X, y, Wj, deg, rho,
+                                    _lamw(lam_weights, p, jnp.float32)))
 
 
 def decsvm_path_sharded(X: Array, y: Array, W: np.ndarray, lams,
@@ -321,7 +317,8 @@ def decsvm_path_sharded(X: Array, y: Array, W: np.ndarray, lams,
     X = jax.device_put(X.astype(solver.problem_dtype(cfg)), node_sharded)
     y = jax.device_put(y, node_sharded)
     fitted = build_sharded_path(m, p, int(lams.shape[0]), cfg, mesh, schedule)
-    return fitted(X, y, Wj, deg, rho, lams, _lamw(lam_weights, p, jnp.float32))
+    return _replicated(mesh, fitted(X, y, Wj, deg, rho, lams,
+                                    _lamw(lam_weights, p, jnp.float32)))
 
 
 # --------------------------------------------------------------------------
@@ -414,8 +411,8 @@ def build_chunked_admm(m_pad: int, p: int, cfg: ADMMConfig, mesh: Mesh,
                                    check_every=check_every)
         return final.B, final.t
 
-    fn = _shard_map_no_rep_check(
-        chunk_loop, mesh=mesh,
+    fn = jax.shard_map(
+        chunk_loop, mesh=mesh, check_vma=False,
         in_specs=(P("node_chunk"), P("node_chunk"), P("node_chunk"),
                   P(None, "node_chunk"), P("node_chunk"), P("node_chunk"),
                   P(), P("node_chunk")),
@@ -454,7 +451,7 @@ def build_chunked_path(m_pad: int, p: int, L: int, cfg: ADMMConfig,
         prog0 = _pvary(jnp.full((L,), jnp.inf, sdt), ("node_chunk",))
         return jax.vmap(fit_one)(lams, B0, P0, prog0)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         chunk_loop, mesh=mesh,
         in_specs=(P("node_chunk"), P("node_chunk"), P("node_chunk"),
                   P(None, "node_chunk"), P("node_chunk"), P("node_chunk"),
@@ -489,7 +486,7 @@ def decsvm_fit_chunked(X: Array, y: Array, W, cfg: ADMMConfig,
     B, t = fitted(ops["X"], ops["y"], ops["W_diag"], ops["W_off"],
                   ops["deg"], ops["rho"],
                   _lamw(lam_weights, p, jnp.float32), ops["nmask"])
-    B = B[:m]
+    B = _replicated(mesh, B)[:m]
     return (B, t) if tol is not None else B
 
 
@@ -512,7 +509,7 @@ def decsvm_path_chunked(X: Array, y: Array, W, lams, cfg: ADMMConfig,
     path = fitted(ops["X"], ops["y"], ops["W_diag"], ops["W_off"],
                   ops["deg"], ops["rho"], lams,
                   _lamw(lam_weights, p, jnp.float32))
-    return path[:, :m]
+    return _replicated(mesh, path)[:, :m]
 
 
 # --------------------------------------------------------------------------
@@ -714,7 +711,7 @@ def build_mesh_path(m: int, p: int, C: int, cfg: ADMMConfig, mesh: Mesh,
         # rows are exactly 0, so supp needs no correction).
         N_total = m_real * n
         f32 = jnp.float32
-        margins = jnp.einsum("mnp,cmp->cmn", Xl, path,
+        margins = jnp.einsum("mnp,cmp->cmn", Xl, path, precision=solver.F32,
                              preferred_element_type=f32) * yl[None]
         hinge = jnp.maximum(1.0 - margins, 0.0)              # (C_local, m, n)
         if nmask is not None:
@@ -748,8 +745,8 @@ def build_mesh_path(m: int, p: int, C: int, cfg: ADMMConfig, mesh: Mesh,
     base_specs = (P(nax), P(nax), wspec, P(nax),
                   P("lam"), P("lam", nax), P())
     in_specs = base_specs + ((P("lam", nax),) if with_masks else ())
-    fn = _shard_map_no_rep_check(
-        prog, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(
+        prog, mesh=mesh, in_specs=in_specs, check_vma=False,
         out_specs=(P("lam", nax), P("lam"), P("lam")))
     return jax.jit(fn)
 
@@ -877,7 +874,8 @@ def decsvm_path_mesh(X: Array, y: Array, W: np.ndarray, lams,
                              stop_rule, with_masks=cell_masks is not None,
                              check_every=check_every, handoff=handoff,
                              offsets=offsets, m_real=m)
-    path_cells, scores, iters = fitted(*operands)
+    path_cells, scores, iters = (_replicated(mesh, a)
+                                 for a in fitted(*operands))
 
     path = path_cells[:L, :m]
     if criterion == "cv":
@@ -930,4 +928,4 @@ def consensus_mix(grads: Array, Wmix: Array, axis: str = "node") -> Array:
     """
     flat = grads.reshape(grads.shape[0], -1)
     all_flat = jax.lax.all_gather(flat, axis, axis=0, tiled=True)
-    return (Wmix @ all_flat).reshape(grads.shape)
+    return solver.mm(Wmix, all_flat).reshape(grads.shape)

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.graph import metropolis_weights
+from repro.core.solver import F32, mm
 
 Array = jax.Array
 
@@ -47,7 +48,7 @@ def gossip_average(values: Array, W: Array, rounds: int = 50) -> Array:
     M = M.astype(flat.dtype)
 
     def body(v, _):
-        return M @ v, None
+        return mm(M, v), None
 
     out, _ = jax.lax.scan(body, flat, None, length=rounds)
     return out.reshape(values.shape)
@@ -77,7 +78,7 @@ def decentralized_bic(X: Array, y: Array, B: Array, W: np.ndarray,
     X, y, B = jnp.asarray(X), jnp.asarray(y), jnp.asarray(B)
     m, n, p = X.shape
     N = m * n
-    margins = y * jnp.einsum("mnp,mp->mn", X, B)
+    margins = y * jnp.einsum("mnp,mp->mn", X, B, precision=F32)
     local_hinge = jnp.maximum(1.0 - margins, 0.0).sum(axis=1)      # (m,)
     local_supp = (jnp.abs(B) > tol).sum(axis=1).astype(jnp.float32)
     scalars = jnp.stack([local_hinge, local_supp], axis=1)          # (m, 2)

@@ -75,7 +75,7 @@ def decsvm_path_batched(X: Array, y: Array, W: Array, lams: Array,
     """
     sanitize.reject_unsupported(cfg, "decsvm_path_batched")
     prob = solver.make_problem(X, y, W, cfg)
-    step = solver.make_step(cfg, lambda B: W @ B, W=W)
+    step = solver.make_step(cfg, lambda B: solver.mm(W, B), W=W)
     lams = jnp.asarray(lams, X.dtype)
 
     def fit_one(lam):
@@ -107,7 +107,7 @@ def decsvm_path_warm(X: Array, y: Array, W: Array, lams: Array,
         raise ValueError(f"stop_rule {stop_rule!r} not in ('kkt', 'progress')")
     sanitize.reject_unsupported(cfg, "decsvm_path_warm")
     prob = solver.make_problem(X, y, W, cfg)
-    step = solver.make_step(cfg, lambda B: W @ B, W=W)
+    step = solver.make_step(cfg, lambda B: solver.mm(W, B), W=W)
     lams = jnp.asarray(lams, X.dtype)
     residual_fn = (solver.kkt_residual_fn(cfg) if stop_rule == "kkt"
                    else None)
@@ -146,7 +146,7 @@ def decsvm_path_cv(X: Array, y: Array, W: Array, lams: Array,
     """
     sanitize.reject_unsupported(cfg, "decsvm_path_cv")
     lams = jnp.asarray(lams, X.dtype)
-    step = solver.make_step(cfg, lambda B: W @ B, W=W)
+    step = solver.make_step(cfg, lambda B: solver.mm(W, B), W=W)
 
     def fold_scores(mask):
         prob = solver.make_problem(X, y, W, cfg, mask=mask)
@@ -157,7 +157,8 @@ def decsvm_path_cv(X: Array, y: Array, W: Array, lams: Array,
 
         path = jax.vmap(fit_one)(lams)                      # (L, m, p)
         val = 1.0 - mask                                    # held-out rows
-        margins = jnp.einsum("mnp,lmp->lmn", X, path) * y[None]
+        margins = jnp.einsum("mnp,lmp->lmn", X, path,
+                             precision=solver.F32) * y[None]
         hinge = jnp.maximum(1.0 - margins, 0.0) * val[None]
         return jnp.sum(hinge, axis=(1, 2)) / jnp.maximum(jnp.sum(val), 1.0)
 
@@ -247,7 +248,7 @@ def decsvm_fit_many(Xs: Array, ys: Array, Ws: Array, lams: Array,
 
     def one(X, y, W, lam, w):
         prob = solver.make_problem(X, y, W, cfg)
-        step = solver.make_step(cfg, lambda B: W @ B, W=W)
+        step = solver.make_step(cfg, lambda B: solver.mm(W, B), W=W)
         return solver.run_fixed(step, prob, lam, w,
                                 num_iters=cfg.max_iter).B
 

@@ -32,6 +32,7 @@ Update (per node l, with deg_l = |N(l)|):
 from __future__ import annotations
 
 import functools
+import warnings
 from typing import Callable, NamedTuple, Optional
 
 import jax
@@ -40,6 +41,19 @@ import jax.numpy as jnp
 from repro.core import losses
 
 Array = jax.Array
+
+
+# fp32 contractions.  On the TPU an f32 dot at default precision rounds its
+# operands to bf16: at the paper's Section 4.1 design that moved a 300-round
+# fit by 5.7e-2 (max |dB|, v5e) and changed the lambda the BIC selects.  Every
+# contraction of the algorithm runs at HIGHEST, which is plain fp32 on the
+# CPU as well.
+F32 = jax.lax.Precision.HIGHEST
+
+
+def mm(a: Array, b: Array) -> Array:
+    """``a @ b`` in full fp32 on every backend."""
+    return jnp.matmul(a, b, precision=F32)
 
 
 def soft_threshold(v: Array, t) -> Array:
@@ -64,16 +78,16 @@ def power_iteration_lmax(X: Array, iters: int = 50) -> Array:
     v = v / jnp.linalg.norm(v)
 
     def body(v, _):
-        w = X.T @ (X @ v) / n
+        w = mm(X.T, mm(X, v)) / n
         nrm = jnp.linalg.norm(w)
         safe = jnp.where(nrm > 0.0, nrm, 1.0)
         return jnp.where(nrm > 0.0, w / safe, v), None
 
     v, _ = jax.lax.scan(body, v, None, length=iters)
-    w = X.T @ (X @ v) / n
-    vv = jnp.vdot(v, v)
-    return jnp.where(vv > 0.0,
-                     jnp.vdot(v, w) / jnp.where(vv > 0.0, vv, 1.0), 0.0)
+    w = mm(X.T, mm(X, v)) / n
+    vv = jnp.vdot(v, v, precision=F32)
+    return jnp.where(vv > 0.0, jnp.vdot(v, w, precision=F32)
+                     / jnp.where(vv > 0.0, vv, 1.0), 0.0)
 
 
 @functools.partial(jax.jit, static_argnames=("h", "kernel", "safety"))
@@ -182,14 +196,14 @@ def local_update(X: Array, y: Array, beta: Array, p_dual: Array,
     only place the update's math lives.
     """
     kern = losses.get_kernel(kernel)
-    margin = y * (X @ beta)
+    margin = y * mm(X, beta)
     w = kern.dloss(margin, h) * y
     if mask is None:
         n_eff = X.shape[0]
     else:
         w = w * mask
         n_eff = jnp.maximum(jnp.sum(mask), 1.0)
-    grad = X.T @ w / n_eff
+    grad = mm(X.T, w) / n_eff
     z = rho * beta - grad - p_dual + neigh_term
     return soft_threshold(omega * z, lam_vec * omega)
 
@@ -239,7 +253,7 @@ def make_step(cfg, neighbor_sum: Callable[[Array], Array], *,
             )(prob.X, prob.y, B, P, neigh_term, prob.rho, prob.omega)
         if backend in MEGAKERNEL_BACKENDS and prob.mask is None:
             from repro.kernels import ops
-            if ops.megakernel_supported(*prob.X.shape, prob.X.dtype):
+            if _fits_megakernel(prob.X):
                 return ops.csvm_block_update(
                     prob.X, prob.y, B, P, neigh_term, prob.rho, prob.omega,
                     lam_vec, h=h, kernel=kernel)
@@ -306,9 +320,7 @@ def make_step(cfg, neighbor_sum: Callable[[Array], Array], *,
             problem is masked or exceeds the VMEM residency budget."""
             from repro.kernels import ops
             lam_vec = _lam_vec(lam, lam_weights, state.B.shape[-1])
-            if (prob.mask is None
-                    and ops.megakernel_supported(*prob.X.shape,
-                                                 prob.X.dtype)):
+            if prob.mask is None and _fits_megakernel(prob.X):
                 Bn, Pn, stat = ops.csvm_round_block(
                     prob.X, prob.y, state.B, state.P, W, prob.deg, prob.rho,
                     prob.omega, lam_vec, rounds_active, tau=tau,
@@ -333,6 +345,27 @@ def make_step(cfg, neighbor_sum: Callable[[Array], Array], *,
         step.round_block = round_block
 
     return step
+
+
+def _fits_megakernel(X: Array) -> bool:
+    """The megakernel's VMEM residency check, said aloud when it fails: the
+    caller then runs the streaming jnp rounds instead of the kernel."""
+    from repro.kernels import ops
+    if ops.megakernel_supported(*X.shape, X.dtype):
+        return True
+    _warn_megakernel_fallback(tuple(X.shape), jnp.dtype(X.dtype).name)
+    return False
+
+
+@functools.lru_cache(maxsize=None)
+def _warn_megakernel_fallback(shape, dtype: str) -> None:
+    from repro.kernels import ops
+    need = ops.megakernel_vmem_bytes(*shape, jnp.dtype(dtype).itemsize)
+    budget = ops.megakernel_budget()
+    warnings.warn(
+        f"megakernel backend: X {dtype}{shape} needs {need / 2**20:.1f} MiB "
+        f"of VMEM, over the {budget / 2**20:.0f} MiB residency budget; "
+        "running the streaming jnp rounds instead", stacklevel=2)
 
 
 def init_state(prob: Problem, B0: Optional[Array] = None,
@@ -558,12 +591,12 @@ def kkt_residual(prob: Problem, cfg, B: Array, lam,
 
     def node_grad(Xl, yl, ml):
         kern = losses.get_kernel(cfg.kernel)
-        margin = yl * (Xl @ beta_bar)
+        margin = yl * mm(Xl, beta_bar)
         w = kern.dloss(margin, cfg.h) * yl
         if ml is not None:
             w = w * ml
-            return Xl.T @ w / jnp.maximum(jnp.sum(ml), 1.0)
-        return Xl.T @ w / Xl.shape[0]
+            return mm(Xl.T, w) / jnp.maximum(jnp.sum(ml), 1.0)
+        return mm(Xl.T, w) / Xl.shape[0]
 
     if prob.mask is None:
         grads = jax.vmap(lambda Xl, yl: node_grad(Xl, yl, None))(

@@ -50,7 +50,7 @@ from typing import Callable, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import metrics
+from repro.core import metrics, solver
 
 
 def modified_bic(X: np.ndarray, y: np.ndarray, B: np.ndarray,
@@ -70,7 +70,7 @@ def modified_bic_jnp(X, y, B, tol: float = 1e-8):
     fuse scoring into the same compiled program as the fits."""
     m, n, p = X.shape
     N = m * n
-    margins = y * jnp.einsum("mnp,mp->mn", X, B)
+    margins = y * jnp.einsum("mnp,mp->mn", X, B, precision=solver.F32)
     hinge = jnp.sum(jnp.maximum(1.0 - margins, 0.0)) / N
     mean_supp = jnp.mean(jnp.sum(jnp.abs(B) > tol, axis=1).astype(X.dtype))
     return hinge + math.sqrt(math.log(N)) * math.log(p) * mean_supp / N

@@ -20,19 +20,25 @@ into the same kernel — a uniform l1 level is just a constant column.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import losses
+
+# fp32 dots at full precision: at default precision Mosaic, like XLA, runs an
+# f32 dot as one bf16 pass (2.3e-3 relative error per dot on v5e).
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _margin_weights_kernel(x_ref, y_ref, beta_ref, w_ref, *, h: float,
                            kernel: str, n_total: int):
     """Accumulate partial X@beta; at the last p-tile convert to weights."""
     j = pl.program_id(1)
-    partial = jnp.dot(x_ref[...], beta_ref[...],
+    partial = jnp.dot(x_ref[...], beta_ref[...], precision=_F32,
                       preferred_element_type=jnp.float32)
 
     @pl.when(j == 0)
@@ -55,7 +61,7 @@ def _grad_update_kernel(x_ref, w_ref, beta_ref, pdual_ref, neigh_ref,
                         rho_ref, omega_ref, lam_ref, out_ref):
     """Accumulate X^T w; at the last n-tile apply the 7a' soft-threshold."""
     k = pl.program_id(1)
-    partial = jnp.dot(x_ref[...].T, w_ref[...],
+    partial = jnp.dot(x_ref[...].T, w_ref[...], precision=_F32,
                       preferred_element_type=jnp.float32)
 
     @pl.when(k == 0)
@@ -189,6 +195,33 @@ def _resolve_interpret(interpret):
 #            and the KKT consensus max masks them with an iota row filter.
 
 
+def _batched_grad(x_ref, y_ref, B, h: float, kern, cd):
+    """Per-node smoothed-loss gradients sum_i L_h'(y_i x_i'b_l) y_i x_i.
+
+    B (M, P) fp32 -> (M, P) fp32.  The two batched MXU dots take the shapes
+    Mosaic lowers (those of flash attention's q k^T and p v): the margins
+    contract b_l (M, 1, P) with X (M, N, P) over P, the gradient contracts
+    the weights (M, 1, N) with X over N.  X is read from its VMEM ref here,
+    inside the round, so no copy of it is carried across the round loop.
+
+    These dots keep Mosaic's default precision, one bf16 pass even for fp32
+    X (1.1e-4 from the fp32 reference after 300 rounds at the paper's design,
+    v5e): at HIGHEST Mosaic splits X into bf16 parts in its scoped stack,
+    which then no longer fits VMEM at that design.
+    """
+    X = x_ref[...]
+    Y = y_ref[...]                                               # (M, 1, N)
+    Mp, _, Pp = X.shape
+    marg = jax.lax.dot_general(
+        B.astype(cd).reshape(Mp, 1, Pp), X, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)                      # (M, 1, N)
+    wts = kern.dloss(Y * marg, h) * Y
+    g = jax.lax.dot_general(
+        wts.astype(cd), X, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)                      # (M, 1, P)
+    return g.reshape(Mp, Pp)
+
+
 def _round_megakernel(x_ref, y_ref, wadj_ref, deg_ref, rho_ref, omega_ref,
                       lam_ref, nact_ref, b0_ref, p0_ref,
                       bout_ref, pout_ref, stat_ref, *, tau: float,
@@ -196,44 +229,35 @@ def _round_megakernel(x_ref, y_ref, wadj_ref, deg_ref, rho_ref, omega_ref,
                       n_real: int, m_real: int, want_kkt: bool):
     """k full ADMM rounds + optional KKT epilogue, all state in VMEM.
 
-    Shapes (padded): X (M, N, P) compute-dtype; y (M, N); W (M, M);
+    Shapes (padded): X (M, N, P) compute-dtype; y (M, 1, N); W (M, M);
     deg/rho/omega (M, 1); lam (1, P); nact (1, 1) traced round count
     (rounds past it are held — the while-driver's max_iter guard); B/P
     (M, P) fp32.  Outputs: B, P (M, P) fp32 and the (1, 1) stop statistic
     (KKT residual when ``want_kkt``, else max|B_k - B_{k-1}|).
     """
     kern = losses.get_kernel(kernel)
-    X = x_ref[...]
-    Y = y_ref[...]
     A = wadj_ref[...]
     deg = deg_ref[...]
     rho = rho_ref[...]
     omega = omega_ref[...]
     lam = lam_ref[...]
     nact = nact_ref[0, 0]
-    cd = X.dtype
+    cd = x_ref.dtype
     inv_n = 1.0 / n_real
-
-    def grad_all(B):
-        # margins_l = X_l @ b_l per node: batched (M, N, P) x (M, P) dot.
-        marg = jax.lax.dot_general(
-            X, B.astype(cd), (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)              # (M, N) fp32
-        wts = kern.dloss(Y * marg, h) * Y * inv_n
-        return jax.lax.dot_general(
-            X, wts.astype(cd), (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)              # (M, P) fp32
 
     def round_body(i, carry):
         B, P, delta = carry
         active = i < nact
-        WB = jnp.dot(A, B, preferred_element_type=jnp.float32)
-        z = rho * B - grad_all(B) - P + tau * (deg * B + WB)
+        WB = jnp.dot(A, B, precision=_F32,
+                     preferred_element_type=jnp.float32)
+        grad = _batched_grad(x_ref, y_ref, B, h, kern, cd) * inv_n
+        z = rho * B - grad - P + tau * (deg * B + WB)
         zo = omega * z
         thr = lam * omega
         # declint: disable=R1 fused in-kernel prox, parity-tested vs solver.local_update
         Bn = jnp.sign(zo) * jnp.maximum(jnp.abs(zo) - thr, 0.0)
-        WBn = jnp.dot(A, Bn, preferred_element_type=jnp.float32)
+        WBn = jnp.dot(A, Bn, precision=_F32,
+                      preferred_element_type=jnp.float32)
         Pn = P + tau * (deg * Bn - WBn)
         d = jnp.max(jnp.abs(Bn - B))
         hold = lambda new, old: jnp.where(active, new, old)
@@ -248,17 +272,15 @@ def _round_megakernel(x_ref, y_ref, wadj_ref, deg_ref, rho_ref, omega_ref,
     if want_kkt:
         # Same pass, same VMEM-resident X: stationarity (unit-step
         # prox-gradient fixed point at beta_bar) + consensus, the statistic
-        # of ``solver.kkt_residual``.  Flattening (M, N, P) -> (M*N, P)
-        # turns the network-mean gradient into one MXU dot.
-        Mp, Np, Pp = X.shape
+        # of ``solver.kkt_residual``.  beta_bar is broadcast to every node
+        # row so the network-mean gradient reuses the per-node batched dots
+        # (ghost rows have X = y = 0 and add nothing to the sum).
+        Mp, Pp = B.shape
         bb = jnp.sum(B, axis=0, keepdims=True) * (1.0 / m_real)   # (1, P)
-        X2 = X.reshape(Mp * Np, Pp)
-        marg = jnp.dot(X2, bb.astype(cd).T,
-                       preferred_element_type=jnp.float32).reshape(Mp, Np)
-        wts = kern.dloss(Y * marg, h) * Y
-        g = jnp.dot(wts.reshape(1, Mp * Np).astype(cd), X2,
-                    preferred_element_type=jnp.float32) * (inv_n / m_real)
-        g = g + lam0 * bb
+        g = jnp.sum(_batched_grad(x_ref, y_ref,
+                                  jnp.broadcast_to(bb, (Mp, Pp)), h, kern,
+                                  cd), axis=0, keepdims=True)
+        g = g * (inv_n / m_real) + lam0 * bb
         v = bb - g
         # declint: disable=R1 in-pass KKT prox epilogue, matches solver.kkt_residual
         prox = jnp.sign(v) * jnp.maximum(jnp.abs(v) - lam, 0.0)
@@ -295,7 +317,7 @@ def csvm_round_block(X, y, B, P, W, deg, rho, omega, lam_vec, nact, *,
     f32 = jnp.float32
     Xp = _pad0(X.astype(cd), ((0, m_pad - m), (0, n_pad - n),
                               (0, p_pad - p)))
-    yp = _pad0(y.astype(f32), ((0, m_pad - m), (0, n_pad - n)))
+    yp = _pad0(y.astype(f32), ((0, m_pad - m), (0, n_pad - n)))[:, None, :]
     Bp = _pad0(B.astype(f32), ((0, m_pad - m), (0, p_pad - p)))
     Pp = _pad0(P.astype(f32), ((0, m_pad - m), (0, p_pad - p)))
     Wp = _pad0(W.astype(f32), ((0, m_pad - m), (0, m_pad - m)))
@@ -313,6 +335,7 @@ def csvm_round_block(X, y, B, P, W, deg, rho, omega, lam_vec, nact, *,
             jax.ShapeDtypeStruct((m_pad, p_pad), f32),
             jax.ShapeDtypeStruct((1, 1), f32),
         ),
+        compiler_params=_compiler_params(m, n, p, Xp.dtype.itemsize),
         interpret=interpret,
     )(Xp, yp, Wp, col(deg), col(rho), col(omega), lam_row, nact2, Bp, Pp)
     return Bn[:m, :p], Pn[:m, :p], stat[0, 0]
@@ -326,17 +349,9 @@ def _block_update_kernel(x_ref, y_ref, b_ref, p_ref, neigh_ref, rho_ref,
     term arrives as an operand so sharded engines can run their collective
     between kernel launches."""
     kern = losses.get_kernel(kernel)
-    X = x_ref[...]
-    Y = y_ref[...]
     B = b_ref[...]
-    cd = X.dtype
-    marg = jax.lax.dot_general(
-        X, B.astype(cd), (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    wts = kern.dloss(Y * marg, h) * Y * (1.0 / n_real)
-    grad = jax.lax.dot_general(
-        X, wts.astype(cd), (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+    grad = _batched_grad(x_ref, y_ref, B, h, kern, x_ref.dtype) * (
+        1.0 / n_real)
     z = rho_ref[...] * B - grad - p_ref[...] + neigh_ref[...]
     zo = omega_ref[...] * z
     thr = lam_ref[...] * omega_ref[...]
@@ -363,7 +378,7 @@ def csvm_block_update(X, y, B, P, neigh, rho, omega, lam_vec, *, h: float,
     f32 = jnp.float32
     Xp = _pad0(X.astype(cd), ((0, m_pad - m), (0, n_pad - n),
                               (0, p_pad - p)))
-    yp = _pad0(y.astype(f32), ((0, m_pad - m), (0, n_pad - n)))
+    yp = _pad0(y.astype(f32), ((0, m_pad - m), (0, n_pad - n)))[:, None, :]
     pad_mp = lambda a: _pad0(a.astype(f32), ((0, m_pad - m),
                                              (0, p_pad - p)))
     col = lambda v: _pad0(v.astype(f32), (0, m_pad - m))[:, None]
@@ -374,21 +389,55 @@ def csvm_block_update(X, y, B, P, neigh, rho, omega, lam_vec, *, h: float,
         functools.partial(_block_update_kernel, h=h, kernel=kernel,
                           n_real=n),
         out_shape=jax.ShapeDtypeStruct((m_pad, p_pad), f32),
+        compiler_params=_compiler_params(m, n, p, Xp.dtype.itemsize),
         interpret=interpret,
     )(Xp, yp, pad_mp(B), pad_mp(P), pad_mp(neigh), col(rho), col(omega),
       lam_row)
     return out[:m, :p]
 
 
-def megakernel_vmem_bytes(m: int, n: int, p: int, itemsize: int = 4) -> int:
-    """VMEM footprint of one megakernel residency (padded operands + the
-    fp32 state/intermediates).  See kernels/README.md for the budget math."""
+def _tiled_bytes(shape, itemsize: int = 4) -> int:
+    """VMEM bytes of one array under Mosaic's tiling of its two minor dims
+    into (32 / itemsize, 128) tiles: a (M, 1, N) slab costs 8 sublanes a
+    row in fp32, not one."""
+    *lead, rows, cols = shape
+    return (math.prod(lead) * _rup(rows, 32 // itemsize) * _rup(cols, 128)
+            * itemsize)
+
+
+def megakernel_vmem(m: int, n: int, p: int, itemsize: int = 4):
+    """(window_bytes, scoped_bytes) of one round-megakernel residency.
+
+    Windows are the kernel's operands and outputs, each held once in VMEM
+    (no grid, so single-buffered), tile-padded.  Scoped bytes are Mosaic's
+    stack for the kernel's intermediates: the (M, 1, P) and (M, 1, N) slabs
+    of the batched dots and, at some shapes, working copies of X for the
+    dots: one in fp32, and under bf16 X one more in bf16 (measured against
+    the v5e compiler: kernels/README.md).
+    The block-update kernel's footprint is below this one."""
     sub = 16 if itemsize == 2 else 8
-    mp_, np_, pp_ = _rup(m, 8), _rup(n, sub), _rup(p, 128)
-    x_bytes = mp_ * np_ * pp_ * itemsize
-    state = 4 * mp_ * pp_ * 4            # B, P (in + out copies)
-    margins = 2 * mp_ * np_ * 4          # y + one live margin/weight buffer
-    adj = mp_ * mp_ * 4
-    vecs = (3 * mp_ + pp_) * 4
-    scalars = 2 * 4                      # nact round count + stat output, (1,1)
-    return x_bytes + state + margins + adj + vecs + scalars
+    M, N, P = _rup(m, 8), _rup(n, sub), _rup(p, 128)
+    windows = (_tiled_bytes((M, N, P), itemsize)          # X
+               + _tiled_bytes((M, 1, N))                  # y
+               + _tiled_bytes((M, M))                     # W
+               + 3 * _tiled_bytes((M, 1))                 # deg, rho, omega
+               + _tiled_bytes((1, P))                     # lam
+               + 2 * _tiled_bytes((1, 1))                 # nact, stat
+               + 4 * _tiled_bytes((M, P)))                # B, P in and out
+    copies = 4 + (itemsize if itemsize == 2 else 0)   # fp32 (+ bf16) copy
+    scoped = (M * N * P * copies + 3 * _tiled_bytes((M, 1, P))
+              + 4 * _tiled_bytes((M, 1, N)) + 2**20)
+    return windows, scoped
+
+
+def megakernel_vmem_bytes(m: int, n: int, p: int, itemsize: int = 4) -> int:
+    """Whole VMEM footprint of one megakernel residency (windows + scoped
+    stack); ``ops.megakernel_supported`` compares it with the budget."""
+    return sum(megakernel_vmem(m, n, p, itemsize))
+
+
+def _compiler_params(m: int, n: int, p: int, itemsize: int):
+    """Raise Mosaic's scoped-VMEM limit (16 MiB by default on v5e) to the
+    kernel's modelled stack; the windows are allocated outside it."""
+    scoped = megakernel_vmem(m, n, p, itemsize)[1]
+    return pltpu.CompilerParams(vmem_limit_bytes=_rup(scoped, 2**20))

@@ -22,12 +22,20 @@ def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-# Whole-problem VMEM residency budget for the round megakernel: real TPU
-# VMEM is ~16 MiB/core (leave headroom for the compiler); interpret mode
-# emulates VMEM in host memory, where the only limit worth enforcing is
-# "don't materialize something absurd".
-_VMEM_BUDGET_TPU = 12 * 2**20
+# Whole-problem VMEM residency budget for the round megakernel.  A v5e
+# core has 128 MiB of VMEM; the kernel's operand windows plus its scoped
+# stack (``csvm_update.megakernel_vmem``) must fit in it, and 16 MiB stay
+# free for the compiler.  Interpret mode emulates VMEM in host memory,
+# where the only limit worth enforcing is "don't materialize something
+# absurd".
+_VMEM_BUDGET_TPU = 112 * 2**20
 _VMEM_BUDGET_INTERPRET = 512 * 2**20
+
+
+def megakernel_budget(interpret=None) -> int:
+    """VMEM bytes one megakernel residency may take on this backend."""
+    interpret = _default_interpret() if interpret is None else interpret
+    return _VMEM_BUDGET_INTERPRET if interpret else _VMEM_BUDGET_TPU
 
 
 def megakernel_supported(m: int, n: int, p: int, dtype=None,
@@ -35,10 +43,9 @@ def megakernel_supported(m: int, n: int, p: int, dtype=None,
     """True when the (m, n, p) problem fits the megakernel's whole-state
     VMEM residency (drivers fall back to the streaming/jnp path otherwise)."""
     import jax.numpy as jnp
-    interpret = _default_interpret() if interpret is None else interpret
     itemsize = 2 if dtype == jnp.bfloat16 else 4
-    budget = _VMEM_BUDGET_INTERPRET if interpret else _VMEM_BUDGET_TPU
-    return megakernel_vmem_bytes(m, n, p, itemsize) <= budget
+    return (megakernel_vmem_bytes(m, n, p, itemsize)
+            <= megakernel_budget(interpret))
 
 
 def csvm_local_update(X, y, beta, p_dual, neigh, rho, omega, lam, *,

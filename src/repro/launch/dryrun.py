@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import repro.configs as configs
 from repro.data.synthetic import SHAPES, input_specs
 from repro.launch import sharding as shd
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.serve import make_jitted_serve_step
 from repro.launch.train import make_jitted_train_step
 from repro.models import model
@@ -208,7 +208,7 @@ def _layer_cost(cfg, mesh, sh, mode: str, fsdp: bool = True,
                 (shd.to_named(shd.cache_pspecs(cross_kv, cfg, mesh), mesh)
                  if cross_kv is not None else None),
             ))
-            with use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 comp = jb.lower(lp_structs, x_struct, caches,
                                 cross_kv).compile()
         else:
@@ -242,7 +242,7 @@ def _layer_cost(cfg, mesh, sh, mode: str, fsdp: bool = True,
                 (shd.to_named(P(dp, None, None), mesh)
                  if enc_struct is not None else None),
             ))
-            with use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 comp = jb.lower(lp_structs, x_struct, enc_struct).compile()
 
         f_, b_, c_ = _analyze(comp)
@@ -288,7 +288,7 @@ def run_one(arch: str, shape_name: str, mesh_kind: str, verbose: bool = True,
         params_struct = jax.eval_shape(
             functools.partial(model.init_params, cfg), jax.random.PRNGKey(0))
         opt_struct = jax.eval_shape(adamw_init, params_struct)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_struct, opt_struct, batch_struct)
     elif sh.kind == "prefill":
         batch_struct = input_specs(cfg, sh)
@@ -305,7 +305,7 @@ def run_one(arch: str, shape_name: str, mesh_kind: str, verbose: bool = True,
         jitted = jax.jit(prefill,
                          in_shardings=(shd.to_named(p_specs, mesh),
                                        shd.to_named(b_specs, mesh)))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_struct, batch_struct)
     else:  # decode
         jitted, _ = make_jitted_serve_step(cfg, mesh, sh.global_batch,
@@ -317,7 +317,7 @@ def run_one(arch: str, shape_name: str, mesh_kind: str, verbose: bool = True,
                               sh.seq_len, mode))
         tok = jax.ShapeDtypeStruct((sh.global_batch,), jnp.int32)
         pos = jax.ShapeDtypeStruct((), jnp.int32)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_struct, cache_struct, tok, pos)
 
     t_lower = time.time() - t0

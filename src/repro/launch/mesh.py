@@ -49,21 +49,10 @@ def make_host_mesh(model_axis: int = 1):
     """Small mesh over whatever devices exist (tests / CPU examples)."""
     n = len(jax.devices())
     assert n % model_axis == 0
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def data_axes(mesh) -> tuple:
     return tuple(a for a in mesh.axis_names if a in ("pod", "data"))
 
-
-def use_mesh(mesh):
-    """Context manager setting the ambient mesh, across JAX versions.
-
-    JAX >= 0.6 exposes ``jax.sharding.set_mesh`` (required for bare
-    PartitionSpec sharding constraints); on older JAX the ``Mesh`` object
-    itself is the context manager that sets the global physical mesh.
-    """
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh

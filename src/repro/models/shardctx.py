@@ -1,6 +1,6 @@
 """Activation-sharding constraints that are no-ops off-mesh.
 
-The launch layer wraps tracing in ``jax.sharding.use_mesh(mesh)``; inside
+The launch layer wraps tracing in ``jax.set_mesh(mesh)``; inside
 the model we then pin the few activation layouts GSPMD gets wrong on its
 own (notably: vocab-sharded logits, batch-sharded residual stream).
 ``constrain(x, "data", None, "model")`` filters axis names against the

@@ -283,7 +283,7 @@ def test_r7_allows_host_numpy_outside_traced_scope():
 def test_r8_flags_uncached_shard_map_jit_builder():
     bad = """
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def build(mesh, m):
         def fn(X):
@@ -298,7 +298,7 @@ def test_r8_allows_lru_cached_builder():
     ok = """
     import functools
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     @functools.lru_cache(maxsize=64)
     def build(mesh, m):

@@ -16,8 +16,7 @@ def run_py(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC
-    # Forcing the host-platform device count works on the CPU platform;
-    # pinning it skips jax's TPU probe (formerly ~60 s per subprocess).
+    # Forcing the host-platform device count works on the CPU platform only.
     env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env, timeout=600)
@@ -202,7 +201,7 @@ def test_jitted_train_step_on_host_mesh():
     run_py("""
         import jax, jax.numpy as jnp, functools
         import repro.configs as configs
-        from repro.launch.mesh import make_host_mesh, use_mesh
+        from repro.launch.mesh import make_host_mesh
         from repro.launch.train import make_jitted_train_step
         from repro.optim import AdamWConfig, adamw_init
         from repro.models import model
@@ -215,7 +214,7 @@ def test_jitted_train_step_on_host_mesh():
         jitted, (p_specs, o_specs, b_specs) = make_jitted_train_step(
             cfg, AdamWConfig(lr=1e-3), mesh, b0)
         from repro.launch import sharding as shd
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = model.init_params(cfg, jax.random.PRNGKey(0))
             params = jax.device_put(params, shd.to_named(p_specs, mesh))
             opt = jax.device_put(adamw_init(params), shd.to_named(o_specs, mesh))
@@ -233,7 +232,7 @@ def test_consensus_mix_shard_map():
     run_py("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core.graph import erdos_renyi, metropolis_weights
         from repro.core.decentral import consensus_mix, make_node_mesh
         m = 8

@@ -56,8 +56,7 @@ def test_walker_marks_scan_consts_loop_invariant():
 
 
 def test_f64_aval_flagged_and_f32_clean():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         closed = jax.make_jaxpr(lambda x: x * 2.0)(
             jnp.ones((3,), jnp.float64))
     found = check_driver("syn", closed, bf16=False)

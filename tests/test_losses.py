@@ -133,3 +133,18 @@ def test_default_bandwidth_rule():
     h = losses.default_bandwidth(2000, 100)
     assert abs(h - max((np.log(100) / 2000) ** 0.25, 0.05)) < 1e-12
     assert losses.default_bandwidth(10**9, 10) == 0.05
+
+
+def test_rational_erf_matches_jax_scipy():
+    """The Gaussian dloss spells erf out in ops Pallas TPU lowers; it must
+    stay within fp32 rounding of jax.scipy's erf, tails included."""
+    from jax.scipy.special import erf
+    x = jnp.linspace(-9.0, 9.0, 20001, dtype=jnp.float32)
+    err = np.max(np.abs(np.asarray(losses.erf_rational(x))
+                        - np.asarray(erf(x))))
+    assert err < 1e-6, err
+    v = jnp.linspace(-4.0, 6.0, 2001)
+    from jax.scipy.stats import norm
+    np.testing.assert_allclose(
+        losses.get_kernel("gaussian").dloss(v, 0.25),
+        -norm.cdf((1.0 - v) / 0.25), atol=1e-6)

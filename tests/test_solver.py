@@ -117,6 +117,61 @@ def test_megakernel_bf16_tolerance_tier(fixture, dense_B):
                                   np.sign(dense_B)[supp_ref])
 
 
+def test_megakernel_over_budget_says_so_once_per_shape(fixture, monkeypatch):
+    """A megakernel backend over the VMEM residency budget runs the
+    streaming rounds (same result) and warns once per shape, naming the
+    shape and the budget -- never a silent fallback that hides the device."""
+    import warnings
+
+    from repro.kernels import ops
+    _, X, y, Wj, _ = fixture
+    monkeypatch.setattr(ops, "_VMEM_BUDGET_INTERPRET", 0)
+    solver._warn_megakernel_fallback.cache_clear()
+    # a round count no other test traces, so the jit caches cannot skip
+    # the trace that decides the fallback
+    rounds = MAX_ITER // 2 + 1
+    acfg = ADMMConfig(lam=LAM, max_iter=rounds, backend="megakernel")
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        B = decsvm_fit(X, y, Wj, acfg)
+        decsvm_fit_tol(X, y, Wj, acfg, tol=-1.0)
+    solver._warn_megakernel_fallback.cache_clear()
+    msgs = [str(w.message) for w in caught
+            if "residency budget" in str(w.message)]
+    assert len(msgs) == 1, msgs
+    assert str(tuple(X.shape)) in msgs[0] and "0 MiB" in msgs[0]
+    want = decsvm_fit(X, y, Wj, ADMMConfig(lam=LAM, max_iter=rounds))
+    np.testing.assert_allclose(np.asarray(B), np.asarray(want), atol=ATOL)
+
+
+def _dot_precisions(closed):
+    """Precision params of every dot_general in a traced program."""
+    def walk(jx):
+        for eqn in jx.eqns:
+            if eqn.primitive.name == "dot_general":
+                yield eqn.params["precision"]
+            for val in eqn.params.values():
+                for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        yield from walk(inner)
+    return list(walk(closed.jaxpr))
+
+
+@pytest.mark.parametrize("name", ["dense", "tol", "path-warm",
+                                  "sharded-gather", "mesh-2d"])
+def test_streaming_contractions_are_pinned_fp32(fixture, name):
+    """On the TPU an f32 dot at default precision rounds to bf16 (a 300-round
+    fit moved by 5.7e-2 on v5e).  The CPU cannot show that numerically, so
+    the guard is structural: every contraction the jnp engines trace runs at
+    HIGHEST."""
+    import jax
+    thunk = _drivers(fixture)[name]
+    precs = _dot_precisions(jax.make_jaxpr(thunk)())
+    assert precs, name
+    assert set(precs) == {(jax.lax.Precision.HIGHEST,) * 2}, (name, precs)
+
+
 def test_megakernel_check_every_under_vmap(fixture):
     """check_every-blocked KKT stopping composes with vmap over a problem
     batch on the megakernel backend: the fused while-body runs k rounds

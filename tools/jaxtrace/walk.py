@@ -2,7 +2,7 @@
 
 `jax.make_jaxpr` gives the program the compiler actually traces — after
 jnp sugar, broadcasting, weak-type promotion and vmap batching have all
-been lowered — but as a *tree*: `scan`/`while`/`cond`/`pjit`/`shard_map`/
+been lowered — but as a *tree*: `scan`/`while`/`cond`/`jit`/`shard_map`/
 `pallas_call` equations each carry whole sub-jaxprs in their params.
 This module flattens that tree into a stream of `(eqn, ctx)` pairs where
 `Ctx` records everything the contract checks need to know about *where*
@@ -17,7 +17,7 @@ an equation sits:
   cost model), and how many enclosing `while` loops have trace-unknown
   trip counts;
 - `const_vars`: ids of variables known loop-invariant in the current
-  jaxpr (scan/while const sections, closed-over consts, and pjit
+  jaxpr (scan/while const sections, closed-over consts, and jit
   pass-throughs of the same) — the cast-churn detector flags
   `convert_element_type` of these inside loop bodies, because that cast
   re-executes every ADMM round over an operand that never changes.
@@ -31,12 +31,12 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Iterator, Tuple
 
-# Params that hold sub-jaxprs on the jax we pin (verified on 0.4.x):
+# Params that hold sub-jaxprs on the jax we pin (verified on 0.9):
 #   scan   -> jaxpr (Closed), num_consts, num_carry, length
 #   while  -> cond_jaxpr/body_jaxpr (Closed), cond_nconsts/body_nconsts
 #   cond   -> branches (tuple of Closed)
-#   pjit   -> jaxpr (Closed)
-#   shard_map -> jaxpr (open), mesh
+#   jit    -> jaxpr (Closed)
+#   shard_map -> jaxpr (open), mesh, in_specs
 #   pallas_call -> jaxpr (open), grid, interpret
 #   custom_jvp/vjp_call -> call_jaxpr (Closed)
 
@@ -94,7 +94,7 @@ def _subjaxprs(value) -> Iterator[Any]:
 
 def _const_section(prim: str, key: str, eqn, sub) -> frozenset:
     """Var ids in `sub` that are loop-invariant: closed-over constvars
-    always; const sections of scan/while; pjit invars whose call-site
+    always; const sections of scan/while; jit invars whose call-site
     operand was itself a known const (positional pass-through)."""
     ids = {id(v) for v in getattr(sub, "constvars", ())}
     invars = sub.invars
@@ -127,7 +127,7 @@ def _child_ctx(eqn, key: str, sub_open, ctx: Ctx) -> Ctx:
     # propagate loop-invariance through the boundary, then add this
     # sub-jaxpr's own const sections
     carried = set()
-    if prim == "pjit" and len(eqn.invars) == len(sub_open.invars):
+    if prim == "jit" and len(eqn.invars) == len(sub_open.invars):
         from jax._src.core import Literal  # type: ignore
         for atom, v in zip(eqn.invars, sub_open.invars):
             if isinstance(atom, Literal) or id(atom) in ctx.const_vars:

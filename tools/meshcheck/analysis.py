@@ -11,8 +11,8 @@ Transfer rules:
 
 - default: the output of an equation varies along the union of its
   operands' axes;
-- seeding: `shard_map` `in_names` mark operands varying along every axis
-  their dicts mention (that axis *splits* the array — each shard holds
+- seeding: `shard_map` `in_specs` mark operands varying along every axis
+  their PartitionSpecs mention (that axis *splits* the array — each shard holds
   different rows); replicated operands stay uniform.  `axis_index` is the
   other variation source — its output IS the shard coordinate;
 - laundering: `psum`/`pmax`/`pmin`/`all_gather` remove their named axes
@@ -124,6 +124,18 @@ class Scope:
         return None
 
 
+def _spec_axes(spec) -> FrozenSet[str]:
+    """Mesh axes a shard_map ``PartitionSpec`` splits its operand over
+    (each entry is None, one axis name, or a tuple of names)."""
+    out = set()
+    for entry in spec or ():
+        if isinstance(entry, str):
+            out.add(entry)
+        elif entry is not None:
+            out.update(entry)
+    return frozenset(out)
+
+
 class DriverAnalysis:
     """One driver's uniformity analysis: findings, fingerprint, stats."""
 
@@ -180,7 +192,7 @@ class DriverAnalysis:
             in_ax = [read(a) for a in eqn.invars]
             joined = frozenset().union(*in_ax) if in_ax else EMPTY
 
-            if prim == "pjit":
+            if prim == "jit":
                 sub = walk._open(eqn.params["jaxpr"])
                 out_ax, dem = self.eval_jaxpr(sub, list(in_ax),
                                               scope.child(prim), emit)
@@ -195,10 +207,8 @@ class DriverAnalysis:
                     dict(sizes)) - frozenset(dict(scope.axis_sizes))
                 sub = walk._open(eqn.params["jaxpr"])
                 sub_in = []
-                for names, ax in zip(eqn.params.get("in_names", ()), in_ax):
-                    mentioned = frozenset(
-                        a for t in dict(names or {}).values() for a in t)
-                    sub_in.append(ax | mentioned)
+                for spec, ax in zip(eqn.params["in_specs"], in_ax):
+                    sub_in.append(ax | _spec_axes(spec))
                 while len(sub_in) < len(sub.invars):  # defensive
                     sub_in.append(joined)
                 out_ax, dem = self.eval_jaxpr(
