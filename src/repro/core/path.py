@@ -48,7 +48,7 @@ from typing import NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.core import sanitize, solver
+from repro.core import sanitize, solver, trace
 from repro.core.admm import ADMMConfig
 from repro.core.tuning import modified_bic_jnp
 
@@ -129,7 +129,8 @@ def decsvm_path_warm(X: Array, y: Array, W: Array, lams: Array,
 @jax.jit
 def score_path(X: Array, y: Array, path: Array) -> Array:
     """Modified BIC at every path point, on-device.  path: (L, m, p)."""
-    return jax.vmap(lambda B: modified_bic_jnp(X, y, B))(path)
+    with jax.named_scope(trace.BIC):
+        return jax.vmap(lambda B: modified_bic_jnp(X, y, B))(path)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))

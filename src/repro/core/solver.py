@@ -38,7 +38,7 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import losses
+from repro.core import losses, trace
 
 Array = jax.Array
 
@@ -105,17 +105,18 @@ def compute_rho(X: Array, h: float, kernel: str, safety: float = 1.05,
     trace contract in tests/test_solver.py).
     """
     c_h = losses.get_kernel(kernel).lipschitz(h)
-    if mask is None:
-        lmax = jax.vmap(power_iteration_lmax)(X)
-    else:
-        Xm = X * mask[..., None]
+    with jax.named_scope(trace.RHO):
+        if mask is None:
+            lmax = jax.vmap(power_iteration_lmax)(X)
+        else:
+            Xm = X * mask[..., None]
 
-        def node_lmax(Xl, ml):
-            return power_iteration_lmax(Xl) * Xl.shape[0] / jnp.maximum(
-                jnp.sum(ml), 1.0)
+            def node_lmax(Xl, ml):
+                return power_iteration_lmax(Xl) * Xl.shape[0] / jnp.maximum(
+                    jnp.sum(ml), 1.0)
 
-        lmax = jax.vmap(node_lmax)(Xm, mask)
-    return safety * c_h * lmax
+            lmax = jax.vmap(node_lmax)(Xm, mask)
+        return safety * c_h * lmax
 
 
 class SolverState(NamedTuple):
@@ -270,13 +271,15 @@ def make_step(cfg, neighbor_sum: Callable[[Array], Array], *,
 
     def step(prob: Problem, state: SolverState, lam,
              lam_weights: Optional[Array] = None) -> SolverState:
-        B, P = state.B, state.P
-        neigh_term = tau * (prob.deg[:, None] * B + neighbor_sum(B))
-        lam_vec = _lam_vec(lam, lam_weights, B.shape[-1])
-        B_new = _primal(prob, B, P, neigh_term, lam_vec)
-        P_new = P + tau * (prob.deg[:, None] * B_new - neighbor_sum(B_new))
-        return SolverState(B_new, P_new, state.t + 1,
-                           jnp.max(jnp.abs(B_new - B)))
+        with jax.named_scope(trace.ROUND):
+            B, P = state.B, state.P
+            neigh_term = tau * (prob.deg[:, None] * B + neighbor_sum(B))
+            lam_vec = _lam_vec(lam, lam_weights, B.shape[-1])
+            B_new = _primal(prob, B, P, neigh_term, lam_vec)
+            P_new = P + tau * (prob.deg[:, None] * B_new
+                               - neighbor_sum(B_new))
+            return SolverState(B_new, P_new, state.t + 1,
+                               jnp.max(jnp.abs(B_new - B)))
 
     def cached_round(prob: Problem, state: SolverState, S, lam,
                      lam_weights: Optional[Array] = None):
@@ -286,14 +289,15 @@ def make_step(cfg, neighbor_sum: Callable[[Array], Array], *,
         (``run_fixed_cached``) halves the neighbour exchanges per round
         — the collectives, in the sharded/chunked engines — at
         bit-identical math (same values through the same ops)."""
-        B, P = state.B, state.P
-        neigh_term = tau * (prob.deg[:, None] * B + S)
-        lam_vec = _lam_vec(lam, lam_weights, B.shape[-1])
-        B_new = _primal(prob, B, P, neigh_term, lam_vec)
-        S_new = neighbor_sum(B_new)
-        P_new = P + tau * (prob.deg[:, None] * B_new - S_new)
-        return SolverState(B_new, P_new, state.t + 1,
-                           jnp.max(jnp.abs(B_new - B))), S_new
+        with jax.named_scope(trace.ROUND):
+            B, P = state.B, state.P
+            neigh_term = tau * (prob.deg[:, None] * B + S)
+            lam_vec = _lam_vec(lam, lam_weights, B.shape[-1])
+            B_new = _primal(prob, B, P, neigh_term, lam_vec)
+            S_new = neighbor_sum(B_new)
+            P_new = P + tau * (prob.deg[:, None] * B_new - S_new)
+            return SolverState(B_new, P_new, state.t + 1,
+                               jnp.max(jnp.abs(B_new - B))), S_new
 
     step.cached_round = cached_round
     step.neighbor_sum = neighbor_sum
@@ -319,28 +323,32 @@ def make_step(cfg, neighbor_sum: Callable[[Array], Array], *,
             Falls back to an equivalent scan of single rounds when the
             problem is masked or exceeds the VMEM residency budget."""
             from repro.kernels import ops
-            lam_vec = _lam_vec(lam, lam_weights, state.B.shape[-1])
-            if prob.mask is None and _fits_megakernel(prob.X):
-                Bn, Pn, stat = ops.csvm_round_block(
-                    prob.X, prob.y, state.B, state.P, W, prob.deg, prob.rho,
-                    prob.omega, lam_vec, rounds_active, tau=tau,
-                    lam0=cfg.lam0, h=h, kernel=kernel,
-                    num_rounds=num_rounds, want_kkt=want_kkt)
-                t_new = state.t + jnp.asarray(rounds_active, state.t.dtype)
-                return SolverState(Bn, Pn, t_new, stat)
+            with jax.named_scope(trace.ROUND):
+                lam_vec = _lam_vec(lam, lam_weights, state.B.shape[-1])
+                if prob.mask is None and _fits_megakernel(prob.X):
+                    Bn, Pn, stat = ops.csvm_round_block(
+                        prob.X, prob.y, state.B, state.P, W, prob.deg,
+                        prob.rho, prob.omega, lam_vec, rounds_active, tau=tau,
+                        lam0=cfg.lam0, h=h, kernel=kernel,
+                        num_rounds=num_rounds, want_kkt=want_kkt)
+                    t_new = state.t + jnp.asarray(rounds_active,
+                                                  state.t.dtype)
+                    return SolverState(Bn, Pn, t_new, stat)
 
-            def inner(s, i):
-                stepped = step(prob, s, lam, lam_weights)
-                held = jax.tree.map(
-                    lambda a, b: jnp.where(i < rounds_active, a, b),
-                    stepped, s)
-                return held, None
+                def inner(s, i):
+                    stepped = step(prob, s, lam, lam_weights)
+                    held = jax.tree.map(
+                        lambda a, b: jnp.where(i < rounds_active, a, b),
+                        stepped, s)
+                    return held, None
 
-            new, _ = jax.lax.scan(inner, state, jnp.arange(num_rounds))
-            if want_kkt:
-                stat = kkt_residual(prob, cfg, new.B, lam, lam_weights)
-                return new._replace(progress=stat)
-            return new
+                new, _ = jax.lax.scan(inner, state,
+                                      jnp.arange(num_rounds))
+                if want_kkt:
+                    stat = kkt_residual(prob, cfg, new.B, lam,
+                                        lam_weights)
+                    return new._replace(progress=stat)
+                return new
 
         step.round_block = round_block
 
@@ -576,53 +584,55 @@ def kkt_residual(prob: Problem, cfg, B: Array, lam,
     real nodes — the chunked engine's zero-padded ghost rows carry zero
     grads and zero B but must not dilute the network means.
     """
-    if node_mask is not None:
-        nm = node_mask.astype(B.dtype)
-        b_sum = jnp.sum(B * nm[:, None], axis=0)
-        n_real = jnp.sum(nm)
-        if axis_name is not None:
-            b_sum = jax.lax.psum(b_sum, axis_name)
-            n_real = jax.lax.psum(n_real, axis_name)
-        beta_bar = b_sum / n_real
-    else:
-        local_mean = jnp.mean(B, axis=0)
-        beta_bar = (local_mean if axis_name is None
-                    else jax.lax.pmean(local_mean, axis_name))
+    with jax.named_scope(trace.KKT_CHECK):
+        if node_mask is not None:
+            nm = node_mask.astype(B.dtype)
+            b_sum = jnp.sum(B * nm[:, None], axis=0)
+            n_real = jnp.sum(nm)
+            if axis_name is not None:
+                b_sum = jax.lax.psum(b_sum, axis_name)
+                n_real = jax.lax.psum(n_real, axis_name)
+            beta_bar = b_sum / n_real
+        else:
+            local_mean = jnp.mean(B, axis=0)
+            beta_bar = (local_mean if axis_name is None
+                        else jax.lax.pmean(local_mean, axis_name))
 
-    def node_grad(Xl, yl, ml):
-        kern = losses.get_kernel(cfg.kernel)
-        margin = yl * mm(Xl, beta_bar)
-        w = kern.dloss(margin, cfg.h) * yl
-        if ml is not None:
-            w = w * ml
-            return mm(Xl.T, w) / jnp.maximum(jnp.sum(ml), 1.0)
-        return mm(Xl.T, w) / Xl.shape[0]
+        def node_grad(Xl, yl, ml):
+            kern = losses.get_kernel(cfg.kernel)
+            margin = yl * mm(Xl, beta_bar)
+            w = kern.dloss(margin, cfg.h) * yl
+            if ml is not None:
+                w = w * ml
+                return mm(Xl.T, w) / jnp.maximum(jnp.sum(ml), 1.0)
+            return mm(Xl.T, w) / Xl.shape[0]
 
-    if prob.mask is None:
-        grads = jax.vmap(lambda Xl, yl: node_grad(Xl, yl, None))(
-            prob.X, prob.y)
-    else:
-        grads = jax.vmap(node_grad)(prob.X, prob.y, prob.mask)
-    if node_mask is not None:
-        g_sum = jnp.sum(grads * nm[:, None], axis=0)
-        if axis_name is not None:
-            g_sum = jax.lax.psum(g_sum, axis_name)
-        g = g_sum / n_real
-    else:
-        g_local = jnp.mean(grads, axis=0)
-        g = (g_local if axis_name is None
-             else jax.lax.pmean(g_local, axis_name))
-    g = g + cfg.lam0 * beta_bar
-    p_dim = beta_bar.shape[-1]
-    if lam_weights is None:
-        lam_vec = jnp.broadcast_to(jnp.asarray(lam, beta_bar.dtype), (p_dim,))
-    else:
-        lam_vec = lam * lam_weights
-    stat = jnp.abs(beta_bar - soft_threshold(beta_bar - g, lam_vec))
-    dev = jnp.abs(B - beta_bar[None, :])
-    if node_mask is not None:
-        dev = dev * nm[:, None]
-    cons_local = jnp.max(dev)
-    cons = (cons_local if axis_name is None
-            else jax.lax.pmax(cons_local, axis_name))
-    return jnp.maximum(jnp.max(stat), cons)
+        if prob.mask is None:
+            grads = jax.vmap(lambda Xl, yl: node_grad(Xl, yl, None))(
+                prob.X, prob.y)
+        else:
+            grads = jax.vmap(node_grad)(prob.X, prob.y, prob.mask)
+        if node_mask is not None:
+            g_sum = jnp.sum(grads * nm[:, None], axis=0)
+            if axis_name is not None:
+                g_sum = jax.lax.psum(g_sum, axis_name)
+            g = g_sum / n_real
+        else:
+            g_local = jnp.mean(grads, axis=0)
+            g = (g_local if axis_name is None
+                 else jax.lax.pmean(g_local, axis_name))
+        g = g + cfg.lam0 * beta_bar
+        p_dim = beta_bar.shape[-1]
+        if lam_weights is None:
+            lam_vec = jnp.broadcast_to(jnp.asarray(lam, beta_bar.dtype),
+                                       (p_dim,))
+        else:
+            lam_vec = lam * lam_weights
+        stat = jnp.abs(beta_bar - soft_threshold(beta_bar - g, lam_vec))
+        dev = jnp.abs(B - beta_bar[None, :])
+        if node_mask is not None:
+            dev = dev * nm[:, None]
+        cons_local = jnp.max(dev)
+        cons = (cons_local if axis_name is None
+                else jax.lax.pmax(cons_local, axis_name))
+        return jnp.maximum(jnp.max(stat), cons)

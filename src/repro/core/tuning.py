@@ -49,8 +49,9 @@ from typing import Callable, Optional, Sequence
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-from repro.core import metrics, solver
+from repro.core import metrics, solver, trace
 
 
 def modified_bic(X: np.ndarray, y: np.ndarray, B: np.ndarray,
@@ -165,32 +166,37 @@ def select_lambda_path(X, y, W, cfg, lams: Optional[Sequence[float]] = None,
     from repro.core import path as path_mod  # local import: avoid cycle
 
     if lams is None:
-        lams = lambda_grid(np.asarray(X), np.asarray(y), num=num)
-    if engine in ("mesh", "chunked"):
-        from repro.core import decentral  # local import: avoid cycle
-        if engine == "chunked":
-            schedule = "block"
+        with TraceAnnotation(trace.SPAN_LAMBDA_GRID):
+            lams = lambda_grid(np.asarray(X), np.asarray(y), num=num)
+    with TraceAnnotation(trace.SPAN_PATH_PROGRAM):
+        if engine in ("mesh", "chunked"):
+            from repro.core import decentral  # local import: avoid cycle
+            if engine == "chunked":
+                schedule = "block"
+            else:
+                W = np.asarray(W)
+            res = decentral.decsvm_path_mesh(
+                jnp.asarray(X), jnp.asarray(y), W, lams, cfg,
+                mesh=mesh, schedule=schedule, mode=mode, tol=tol,
+                lam_weights=lam_weights, stop_rule=stop_rule,
+                criterion=criterion, cv_folds=cv_folds, cv_seed=cv_seed)
+        elif engine == "dense":
+            res = path_mod.decsvm_path_select(
+                jnp.asarray(X), jnp.asarray(y), jnp.asarray(W),
+                jnp.asarray(lams), cfg, mode=mode, tol=tol,
+                lam_weights=lam_weights, stop_rule=stop_rule,
+                criterion=criterion, cv_folds=cv_folds, cv_seed=cv_seed,
+                check_every=check_every)
         else:
-            W = np.asarray(W)
-        res = decentral.decsvm_path_mesh(
-            jnp.asarray(X), jnp.asarray(y), W, lams, cfg,
-            mesh=mesh, schedule=schedule, mode=mode, tol=tol,
-            lam_weights=lam_weights, stop_rule=stop_rule,
-            criterion=criterion, cv_folds=cv_folds, cv_seed=cv_seed)
-    elif engine == "dense":
-        res = path_mod.decsvm_path_select(
-            jnp.asarray(X), jnp.asarray(y), jnp.asarray(W),
-            jnp.asarray(lams), cfg, mode=mode, tol=tol,
-            lam_weights=lam_weights, stop_rule=stop_rule,
-            criterion=criterion, cv_folds=cv_folds, cv_seed=cv_seed,
-            check_every=check_every)
-    else:
-        raise ValueError(
-            f"engine {engine!r} not in ('dense', 'mesh', 'chunked')")
-    table = [(float(l), float(c), metrics.mean_support_size(np.asarray(B)))
-             for l, c, B in zip(np.asarray(res.lams), np.asarray(res.criteria),
-                                np.asarray(res.path))]
-    return float(res.best_lam), np.asarray(res.best_B), table, res
+            raise ValueError(
+                f"engine {engine!r} not in ('dense', 'mesh', 'chunked')")
+    with TraceAnnotation(trace.SPAN_BIC_TABLE):
+        table = [(float(l), float(c),
+                  metrics.mean_support_size(np.asarray(B)))
+                 for l, c, B in zip(np.asarray(res.lams),
+                                    np.asarray(res.criteria),
+                                    np.asarray(res.path))]
+        return float(res.best_lam), np.asarray(res.best_B), table, res
 
 
 def shared_lambda_grid(Xs: np.ndarray, ys: np.ndarray, num: int = 12,

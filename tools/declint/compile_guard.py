@@ -2,9 +2,9 @@
 
 ``jax.monitoring`` fires ``/jax/core/compile/backend_compile_duration``
 once per *actual* backend compilation — a jit cache hit does not fire.
-A process-global listener accumulates the count (jax.monitoring has no
-unregister API, so it is installed once, lazily) and the
-``compile_guard`` pytest fixture hands tests a delta-based view.
+The program's compile counter (``repro.core.trace.compiles``, the
+process's one listener) accumulates the count, and the ``compile_guard``
+pytest fixture hands tests a delta-based view of it.
 
 The enforceable contract is **steady state**: cold-start counts include
 version-dependent internal helper jits (empirically ~2.5 events per
@@ -22,30 +22,20 @@ Loaded as a pytest plugin from ``tests/conftest.py``
 from __future__ import annotations
 
 import contextlib
-import threading
-from typing import Iterator, Optional
+from typing import Iterator
 
 import pytest
 
-COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
 
 class CompileGuard:
-    """Monotone counter of XLA backend compilations in this process."""
+    """Delta-based view of the program's monotone backend-compile count."""
 
-    def __init__(self) -> None:
-        self._lock = threading.Lock()
-        self._count = 0
-
-    def _on_event(self, event: str, duration: float, **kwargs) -> None:
-        if event == COMPILE_EVENT:
-            with self._lock:
-                self._count += 1
+    def __init__(self, counter) -> None:
+        self._counter = counter
 
     @property
     def count(self) -> int:
-        with self._lock:
-            return self._count
+        return self._counter.backend
 
     def snapshot(self) -> int:
         return self.count
@@ -70,21 +60,9 @@ class CompileGuard:
             f"program builder missing @functools.lru_cache (declint R8).")
 
 
-_guard: Optional[CompileGuard] = None
-
-
-def install() -> CompileGuard:
-    """Idempotently install the process-global compile listener."""
-    global _guard
-    if _guard is None:
-        import jax.monitoring
-
-        _guard = CompileGuard()
-        jax.monitoring.register_event_duration_secs_listener(_guard._on_event)
-    return _guard
-
-
 @pytest.fixture
 def compile_guard() -> CompileGuard:
     """Delta-based view of the process compile counter (see module doc)."""
-    return install()
+    from repro.core.trace import compiles
+
+    return CompileGuard(compiles)
