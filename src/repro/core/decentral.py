@@ -245,7 +245,8 @@ def _prep(X, W, cfg, schedule):
         _assert_ring(W)
     Wj = jnp.asarray(W, X.dtype)
     deg = jnp.sum(Wj, axis=1)
-    rho = solver.compute_rho(X, cfg.h, cfg.kernel, cfg.rho_safety)
+    rho = solver.compute_rho(X, cfg.h, cfg.kernel, cfg.rho_safety,
+                             backend=solver.resolve_backend(cfg))
     return Wj, deg, rho
 
 
@@ -355,7 +356,8 @@ def _chunk_prep(X, y, W, cfg, mesh):
     deg[:m] = top.degrees()
     nmask = np.zeros((m_pad,), np.float32)
     nmask[:m] = 1.0
-    rho = solver.compute_rho(Xp, cfg.h, cfg.kernel, cfg.rho_safety)
+    rho = solver.compute_rho(Xp, cfg.h, cfg.kernel, cfg.rho_safety,
+                             backend=solver.resolve_backend(cfg))
     cs = NamedSharding(mesh, P("node_chunk"))
     ops = dict(
         X=jax.device_put(Xp.astype(solver.problem_dtype(cfg)), cs),
@@ -822,7 +824,8 @@ def decsvm_path_mesh(X: Array, y: Array, W: np.ndarray, lams,
         offsets, m_work = (), m
         row_valid = np.ones((m,), np.float32)
 
-    rho_full = solver.compute_rho(X, cfg.h, cfg.kernel, cfg.rho_safety)
+    rho_full = solver.compute_rho(X, cfg.h, cfg.kernel, cfg.rho_safety,
+                                  backend=solver.resolve_backend(cfg))
     if criterion == "cv":
         from repro.core.tuning import kfold_masks  # local: avoid cycle
         folds = np.asarray(kfold_masks(m, n, cv_folds, seed=cv_seed))

@@ -21,7 +21,10 @@ Pluggable pieces of ``make_step``:
   the dual — exactly update (7a')/(7b).
 - **local-gradient backend**: the jnp reference (``local_update``,
   optionally sample-masked for uneven n / cross-validation folds) or the
-  fused Pallas TPU kernel (``kernels.ops.csvm_local_update``).
+  fused Pallas TPU kernel (``kernels.ops.csvm_local_update``).  Under
+  ``backend="auto"`` every product over X (round, KKT check, power
+  iteration) is ``node_xtphi``, which picks by shape between XLA's two
+  HIGHEST contractions and the one-pass kernel ``kernels.ops.xpass``.
 
 Update (per node l, with deg_l = |N(l)|):
     grad_l = (1/n) sum_i L_h'(y_i x_i' b_l) y_i x_i
@@ -61,7 +64,63 @@ def soft_threshold(v: Array, t) -> Array:
     return jnp.sign(v) * jnp.maximum(jnp.abs(v) - t, 0.0)
 
 
-def power_iteration_lmax(X: Array, iters: int = 50) -> Array:
+# A node's X block above this many bytes streams through the one-pass kernel
+# (``kernels/xpass.py``) under backend "auto" on a TPU.  On a v5e the kernel
+# took half the time of XLA's pair of fusions at every block of a sweep over
+# n at p=2001, from 1.6 MB (n=200) to 320 MB (PERF.md); the threshold sits
+# below that sweep and above the 0.4 MB blocks of the paper's own design,
+# whose latency-bound path keeps XLA's fusions.
+XPASS_MIN_BYTES = 2**20
+
+
+def _platform() -> str:
+    return jax.default_backend()
+
+
+def xpass_applies(backend: str, X: Array, mask: Optional[Array]) -> bool:
+    """The shape rule: backend "auto", a TPU, no sample mask, an fp32 node
+    block (n, p) over ``XPASS_MIN_BYTES`` whose p fits the kernel's VMEM."""
+    if not (backend == "auto" and mask is None and _platform() == "tpu"
+            and X.dtype == jnp.float32
+            and X.shape[-2] * X.shape[-1] * 4 > XPASS_MIN_BYTES):
+        return False
+    from repro.kernels import xpass
+    return xpass.supported(X.shape[-1])
+
+
+def node_xtphi(X: Array, y: Optional[Array], v: Array, *, weight: str,
+               h: float = 0.0, kernel: str = "epanechnikov",
+               mask: Optional[Array] = None, backend: str = "jnp") -> Array:
+    """X' phi(X v) for one node (X (n, p), y (n,), v (p,)): the one home of
+    the products over X, in the round (``local_update``), the KKT check
+    (``kkt_residual``) and the power iteration (``power_iteration_lmax``).
+
+    ``weight="loss"``: phi(u)_i = L_h'(y_i u_i) y_i / n, the smoothed-loss
+    gradient; ``weight="linear"``: phi(u) = u / n.  A sample ``mask`` drops
+    its rows and n becomes their count.  Where ``xpass_applies`` the
+    product is one read of X by the ``decsvm_xpass`` kernel (vmapped over
+    nodes by ``pallas_call``'s batching rule), else two HIGHEST ``mm``.
+    """
+    if xpass_applies(backend, X, mask):
+        from repro.kernels import ops
+        y = jnp.zeros(X.shape[:1], X.dtype) if y is None else y
+        return ops.xpass(X[None], y[None], v[None], weight=weight, h=h,
+                         kernel=kernel)[0]
+    u = mm(X, v)
+    if weight == "loss":
+        w = losses.get_kernel(kernel).dloss(y * u, h) * y
+    else:
+        w = u
+    if mask is None:
+        n_eff = X.shape[0]
+    else:
+        w = w * mask
+        n_eff = jnp.maximum(jnp.sum(mask), 1.0)
+    return mm(X.T, w) / n_eff
+
+
+def power_iteration_lmax(X: Array, iters: int = 50,
+                         backend: str = "jnp") -> Array:
     """Largest eigenvalue of X'X/n, matrix-free (X: (n, p)).
 
     The start vector is seeded deterministically from the operand *shape*
@@ -70,44 +129,51 @@ def power_iteration_lmax(X: Array, iters: int = 50) -> Array:
     coordinate sum, where the Rayleigh quotient silently returned ~0 and
     ``compute_rho`` under-regularized).  Iterations guard the normalization
     so a degenerate node shard (all-zero rows, e.g. a fully-masked CV
-    block) yields lmax = 0 instead of NaN.
+    block) yields lmax = 0 instead of NaN.  Each step is one
+    ``node_xtphi`` under ``backend``.
     """
     n, p = X.shape
     key = jax.random.PRNGKey(n * 1000003 + p)
     v = jax.random.normal(key, (p,), jnp.float32).astype(X.dtype)
     v = v / jnp.linalg.norm(v)
+    gram = functools.partial(node_xtphi, X, None, weight="linear",
+                             backend=backend)
 
     def body(v, _):
-        w = mm(X.T, mm(X, v)) / n
+        w = gram(v)
         nrm = jnp.linalg.norm(w)
         safe = jnp.where(nrm > 0.0, nrm, 1.0)
         return jnp.where(nrm > 0.0, w / safe, v), None
 
     v, _ = jax.lax.scan(body, v, None, length=iters)
-    w = mm(X.T, mm(X, v)) / n
+    w = gram(v)
     vv = jnp.vdot(v, v, precision=F32)
     return jnp.where(vv > 0.0, jnp.vdot(v, w, precision=F32)
                      / jnp.where(vv > 0.0, vv, 1.0), 0.0)
 
 
-@functools.partial(jax.jit, static_argnames=("h", "kernel", "safety"))
+@functools.partial(jax.jit, static_argnames=("h", "kernel", "safety",
+                                             "backend"))
 def compute_rho(X: Array, h: float, kernel: str, safety: float = 1.05,
-                mask: Optional[Array] = None) -> Array:
+                mask: Optional[Array] = None, backend: str = "jnp") -> Array:
     """rho_l >= c_h * Lmax(X_l'X_l/n_l) per node.  X: (m, n, p).
 
     With a sample ``mask`` (m, n), masked rows are zeroed and n_l is the
-    per-node mask sum (the uneven-n extension of Section 2.1).
+    per-node mask sum (the uneven-n extension of Section 2.1).  ``backend``
+    is the resolved ``cfg.backend``: under "auto" an unmasked power
+    iteration may take the one-pass kernel (``node_xtphi``).
 
-    Jitted (h/kernel/safety static): the eager vmap-of-scan dispatch used
-    to miss the executable cache and recompile on every host-side call —
-    the sharded/mesh drivers paid one XLA compile per fit even when the
-    lru-cached program builders all hit (caught by the compile-guard
-    trace contract in tests/test_solver.py).
+    Jitted (h/kernel/safety/backend static): the eager vmap-of-scan
+    dispatch used to miss the executable cache and recompile on every
+    host-side call — the sharded/mesh drivers paid one XLA compile per fit
+    even when the lru-cached program builders all hit (caught by the
+    compile-guard trace contract in tests/test_solver.py).
     """
     c_h = losses.get_kernel(kernel).lipschitz(h)
     with jax.named_scope(trace.RHO):
         if mask is None:
-            lmax = jax.vmap(power_iteration_lmax)(X)
+            lmax = jax.vmap(functools.partial(power_iteration_lmax,
+                                              backend=backend))(X)
         else:
             Xm = X * mask[..., None]
 
@@ -145,7 +211,9 @@ class Problem(NamedTuple):
 #   "pallas"          the two-pass fused kernel, vmapped over nodes
 #   "megakernel"      whole-round fused kernel (fp32 compute)
 #   "megakernel_bf16" same, X and MXU operands bf16; accumulators fp32
-# "auto" defers to the legacy ``use_pallas`` flag.
+# "auto" defers to the legacy ``use_pallas`` flag; without it, it is
+# ``local_update`` with each product over X picked by shape
+# (``xpass_applies``): the one-pass kernel for a large block on a TPU.
 MEGAKERNEL_BACKENDS = ("megakernel", "megakernel_bf16")
 BACKENDS = ("auto", "jnp", "pallas") + MEGAKERNEL_BACKENDS
 
@@ -157,7 +225,7 @@ def resolve_backend(cfg, use_pallas: Optional[bool] = None) -> str:
         raise ValueError(f"backend {backend!r} not in {BACKENDS}")
     if backend == "auto":
         pallas = cfg.use_pallas if use_pallas is None else use_pallas
-        return "pallas" if pallas else "jnp"
+        return "pallas" if pallas else "auto"
     return backend
 
 
@@ -180,31 +248,26 @@ def make_problem(X: Array, y: Array, W: Array, cfg,
     """
     deg = jnp.sum(W, axis=1)
     if rho is None:
-        rho = compute_rho(X, cfg.h, cfg.kernel, cfg.rho_safety, mask=mask)
+        rho = compute_rho(X, cfg.h, cfg.kernel, cfg.rho_safety, mask=mask,
+                          backend=resolve_backend(cfg))
     omega = 1.0 / (2.0 * cfg.tau * deg + rho + cfg.lam0)
     return Problem(X.astype(problem_dtype(cfg)), y, deg, rho, omega, mask)
 
 
 def local_update(X: Array, y: Array, beta: Array, p_dual: Array,
                  neigh_term: Array, rho, omega, lam_vec, *, h: float,
-                 kernel: str, mask: Optional[Array] = None) -> Array:
+                 kernel: str, mask: Optional[Array] = None,
+                 backend: str = "jnp") -> Array:
     """THE Algorithm-1 primal update (7a') for a single node.
 
     X: (n, p), y: (n,), beta/p_dual/neigh_term: (p,); rho/omega scalars;
     lam_vec a scalar or (p,) per-coordinate l1 level; ``neigh_term`` is the
     precomputed  tau * (deg_l * beta_l + sum_{k in N(l)} beta_k).
     This function (and the fused Pallas kernel validated against it) is the
-    only place the update's math lives.
+    only place the update's math lives; its gradient is ``node_xtphi``.
     """
-    kern = losses.get_kernel(kernel)
-    margin = y * mm(X, beta)
-    w = kern.dloss(margin, h) * y
-    if mask is None:
-        n_eff = X.shape[0]
-    else:
-        w = w * mask
-        n_eff = jnp.maximum(jnp.sum(mask), 1.0)
-    grad = mm(X.T, w) / n_eff
+    grad = node_xtphi(X, y, beta, weight="loss", h=h, kernel=kernel,
+                      mask=mask, backend=backend)
     z = rho * beta - grad - p_dual + neigh_term
     return soft_threshold(omega * z, lam_vec * omega)
 
@@ -263,7 +326,8 @@ def make_step(cfg, neighbor_sum: Callable[[Array], Array], *,
                 lam_vec)
         if prob.mask is None:
             return jax.vmap(
-                lambda *a: local_update(*a, h=h, kernel=kernel),
+                lambda *a: local_update(*a, h=h, kernel=kernel,
+                                        backend=backend),
                 in_axes=in_axes)(*args)
         return jax.vmap(
             lambda *a: local_update(*a[:-1], h=h, kernel=kernel, mask=a[-1]),
@@ -599,13 +663,9 @@ def kkt_residual(prob: Problem, cfg, B: Array, lam,
                         else jax.lax.pmean(local_mean, axis_name))
 
         def node_grad(Xl, yl, ml):
-            kern = losses.get_kernel(cfg.kernel)
-            margin = yl * mm(Xl, beta_bar)
-            w = kern.dloss(margin, cfg.h) * yl
-            if ml is not None:
-                w = w * ml
-                return mm(Xl.T, w) / jnp.maximum(jnp.sum(ml), 1.0)
-            return mm(Xl.T, w) / Xl.shape[0]
+            return node_xtphi(Xl, yl, beta_bar, weight="loss", h=cfg.h,
+                              kernel=cfg.kernel, mask=ml,
+                              backend=resolve_backend(cfg))
 
         if prob.mask is None:
             grads = jax.vmap(lambda Xl, yl: node_grad(Xl, yl, None))(

@@ -16,6 +16,14 @@ that path as the ``tf_op`` of each device operation.
                                  iteration over each node's X
     BIC        decsvm.bic        ``path.score_path``: path scoring
 
+Kernel name (the ``name`` of a ``pallas_call``, which names its HLO
+custom call and so its device operation):
+
+    decsvm_xpass                 ``kernels/xpass.py``, called by
+                                 ``solver.node_xtphi`` inside the three
+                                 scopes above where ``xpass_applies``;
+                                 read by ``bench/metrics/kernel_share.fit``
+
 Host spans (``jax.profiler.TraceAnnotation``) cost nothing unless a
 profiler session is active:
 

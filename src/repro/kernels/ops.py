@@ -16,6 +16,7 @@ from repro.kernels.csvm_update import (csvm_block_update as
                                        megakernel_vmem_bytes)
 from repro.kernels.flash_attention import flash_attention as _flash_attention
 from repro.kernels.ssd_scan import ssd_scan as _ssd_scan
+from repro.kernels.xpass import xpass as _xpass
 
 
 def _default_interpret() -> bool:
@@ -79,6 +80,15 @@ def csvm_block_update(X, y, B, P, neigh, rho, omega, lam_vec, *, h,
     interpret = _default_interpret() if interpret is None else interpret
     return _csvm_block_update(X, y, B, P, neigh, rho, omega, lam_vec,
                               h=h, kernel=kernel, interpret=interpret)
+
+
+def xpass(X, y, V, *, weight, h=0.0, kernel="epanechnikov", interpret=None,
+          **kw):
+    """One-pass G = X' phi(X V) per node (``decsvm_xpass``): X read from
+    HBM once per product.  X (m, n, p), y (m, n), V (m, p) -> (m, p)."""
+    interpret = _default_interpret() if interpret is None else interpret
+    return _xpass(X, y, V, weight=weight, h=h, kernel=kernel,
+                  interpret=interpret, **kw)
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, sm_scale=None,

@@ -52,7 +52,8 @@ def decsvm_round_block(X: Array, y: Array, B: Array, P: Array, W: Array,
         delta = jnp.max(jnp.abs(B_new - B))
         B = B_new
     if want_kkt:
-        cfg = types.SimpleNamespace(kernel=kernel, h=h, lam0=lam0)
+        cfg = types.SimpleNamespace(kernel=kernel, h=h, lam0=lam0,
+                                    backend="jnp")
         prob = solver.Problem(X, y, deg, rho, omega, None)
         lam_arr = jnp.asarray(lam_vec, jnp.float32).reshape(-1)
         if lam_arr.shape[0] == 1:

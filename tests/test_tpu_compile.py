@@ -123,3 +123,48 @@ def test_decsvm_fit_pallas_backend_holds_the_kernel(chip, mosaic):
     compiled = jax.jit(lambda X, y, W: decsvm_fit(X, y, W, cfg)).lower(
         s(10, 200, 501), s(10, 200), s(10, 10)).compile()
     assert _has_kernel(compiled)
+
+
+def test_one_pass_kernel_compiles_at_the_epsilon_node_shape(chip):
+    """``decsvm_xpass`` at the epsilon cell's (10, 40000, 2001): p=2001 and
+    the ragged last row tile lower through Mosaic, and X reaches the
+    kernel through its transposed view with no copy (the TPU stores this
+    X n-minor, so the view is a bitcast)."""
+    from repro.kernels import xpass
+    m, n, p = 10, 40000, 2001
+    for weight in xpass.WEIGHTS:
+        f = lambda X, y, V, w=weight: xpass.xpass(X, y, V, weight=w, h=0.25,
+                                                  interpret=False)
+        compiled = jax.jit(f).lower(_spec(chip, (m, n, p)),
+                                    _spec(chip, (m, n)),
+                                    _spec(chip, (m, p))).compile()
+        assert _has_kernel(compiled)
+        assert compiled.memory_analysis().temp_size_in_bytes < n * p * 4
+
+
+def test_epsilon_fit_program_reads_x_in_place(chip, mosaic, monkeypatch):
+    """The fit program of the epsilon cell with the rule on (a TPU): every
+    product over X is the one-pass kernel (round, KKT check, power
+    iteration in and after its loop), and no X-shaped copy is left; XLA's
+    own fusions relayout X once per fit."""
+    from repro.core import ADMMConfig, solver
+    from repro.core.admm_adaptive import _fit_tol_jit
+    m, n, p = 10, 40000, 2001
+    cfg = ADMMConfig(lam=0.01, h=0.3, max_iter=300)
+    f = lambda X, y, W: _fit_tol_jit(X, y, W, cfg, tol=1e-3,
+                                     stop_rule="kkt", check_every=4)
+    s = lambda *shape: _spec(chip, shape)
+    copies = {}
+    for platform in ("cpu", "tpu"):
+        monkeypatch.setattr(solver, "_platform", lambda: platform)
+        jax.clear_caches()                  # the rule is read when traced
+        text = jax.jit(f).lower(s(m, n, p), s(m, n), s(m, m)).compile(
+        ).as_text()
+        copies[platform] = sum(
+            1 for line in text.splitlines()
+            if " copy(" in line and f"f32[{m},{n},{p}]" in line.split("=")[1])
+        kernels = sum(1 for line in text.splitlines()
+                      if "custom-call(" in line and "decsvm_xpass" in line)
+        assert kernels == (4 if platform == "tpu" else 0)
+    jax.clear_caches()
+    assert copies == {"cpu": 1, "tpu": 0}
