@@ -9,6 +9,12 @@ the cell's pool for a seed, held to the reference by the cell's own
 - each fault of ``faults.py`` planted in the program, on the first
   ``--faults`` seeds.
 
+The pool is made as a run of the cell makes it (``design.make_pool`` on
+the first ``chips`` devices: whole on one, sharded along the node axis over
+several, m dividing evenly over them), with the configuration's graph
+(``graph``: ``"erdos_renyi"`` with ``graph_p``, or ``"k_regular"`` with
+``graph_k``).
+
     python3 bench/control.py --workload paper41_p500.path \
         --seeds 1 2 3 4 5 6 7 8 9 10 11 12 --control 3 --faults 3
 
@@ -34,13 +40,16 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parent.parent
 
 
-def readings(cell, seed: int, kind: str) -> dict:
+def readings(cell, seed: int, kind: str, require_chip: bool = True) -> dict:
     """The cell's numbers for one seed; kind is "sound", "control" or the
-    name of a fault that is planted already."""
+    name of a fault that is planted already.  Off the accelerator only with
+    ``require_chip`` false (the self-tests)."""
     from bench import design, harness
 
     op = harness.load_module("ops", cell.traffic["op"])
-    pool, W = design.make_pool(cell.config, seed, cell.traffic["pool"])
+    pool, W = design.make_pool(cell.config, seed, cell.traffic["pool"],
+                               harness.check_devices(cell.chips,
+                                                     require_chip))
     if kind == "control":
         answer = lambda X, y: op.control(cell.config, cell.traffic, W, X, y)
     else:

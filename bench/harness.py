@@ -10,6 +10,19 @@ loop, metric or cell lives in a file of its own, found by its name:
     loops/<loop>.py           drive
     metrics/<metric>.py       read(run) -> value or None
     checks/<workload>.json    the limit of each number compared
+
+A cell runs on the first ``chips`` devices of the machine, and not at all
+where it has fewer (``check_devices``).  Its data is
+made on them by ``design.make_pool``: whole on one chip, and sharded along
+the node axis over a 1-D mesh of the cell's chips where it has several (the
+configuration's m has to divide evenly over them).  An op module that runs
+an engine over several chips finds them in ``X.sharding``.  The graph is
+the configuration's ``graph`` (``"erdos_renyi"`` with ``graph_p``, or
+``"k_regular"`` with ``graph_k``).  A traced run hands a metric reader two
+reductions of its trace: ``Run.trace`` (``bench/trace.py``: busy time, op
+time by name, idle gaps by the host) and ``Run.program``
+(``bench/scopes.py``: device time by the program's ``decsvm.`` scopes, and
+idle time by its ``decsvm:`` spans).
 """
 from __future__ import annotations
 
@@ -80,19 +93,23 @@ def load_cell(name: str) -> Cell:
                 end_to_end=e2e, per_layer=per_layer)
 
 
-def check_devices(chips: int):
-    """The devices of the run; raises ``NoChip`` off the accelerator."""
+def check_devices(chips: int, require_chip: bool = True):
+    """The cell's devices: the first ``chips`` of the machine, exactly that
+    many.  Raises ``NoChip`` where there are fewer, and, unless
+    ``require_chip`` is false (the self-tests on CPU devices), off the
+    accelerator."""
     import jax
 
     devices = jax.devices()
-    if devices[0].platform != "tpu":
+    if require_chip and devices[0].platform != "tpu":
         raise NoChip(f"JAX found no TPU (platform {devices[0].platform!r})")
     if len(devices) < chips:
         raise NoChip(f"the cell asks for {chips} chips, JAX sees "
                      f"{len(devices)}")
-    from bench import costs
-    costs.peaks(devices[0].device_kind)
-    return devices
+    if require_chip:
+        from bench import costs
+        costs.peaks(devices[0].device_kind)
+    return devices[:chips]
 
 
 def request_order(seed: int, pool: int):
@@ -113,6 +130,7 @@ class Run:
     records: list                      # (key, sent, done, answer, counters,
     #                                     error) of every request sent
     trace: object = None               # trace.Summary of a traced run
+    program: object = None             # scopes.Summary of a traced run
 
     @property
     def done(self):
@@ -188,16 +206,15 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     import jax
     from jax.profiler import TraceAnnotation
 
-    from bench import design
+    from bench import design, scopes
     from bench import trace as trace_mod
 
-    devices = (check_devices(cell.chips) if require_chip
-               else jax.devices()[:cell.chips])
+    devices = check_devices(cell.chips, require_chip)
     cfg, traffic = cell.config, cell.traffic
     op = load_module("ops", traffic["op"])
     loop = load_module("loops", traffic["loop"])
 
-    pool, W = design.make_pool(cfg, seed, traffic["pool"])
+    pool, W = design.make_pool(cfg, seed, traffic["pool"], devices)
     request = op.build(cfg, traffic, W)
 
     def call(key):
@@ -210,13 +227,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     order = request_order(seed, len(pool))
     drive = lambda **kw: loop.drive(call, order, **kw)
-    summary = None
+    summary = program = None
     if trace:
         log_dir = tempfile.mkdtemp(prefix="bench_trace_")
         try:
             records = _traced(lambda: drive(count=traffic["trace_requests"]),
                               log_dir)
             summary = trace_mod.summarize(log_dir)
+            program = scopes.summarize(log_dir)
         finally:
             shutil.rmtree(log_dir, ignore_errors=True)
     else:
@@ -224,7 +242,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     mem = memory_peak_bytes(devices)
 
     run = Run(cell=cell, device_kind=devices[0].device_kind,
-              setup_s=setup_s, records=records, trace=summary)
+              setup_s=setup_s, records=records, trace=summary,
+              program=program)
     failed = [r for r in records if r[5] is not None]
     for r in failed[:5]:
         print(f"request on data set {r[0]} failed: {r[5]}", file=sys.stderr)
@@ -257,7 +276,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     dev = devices[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(jax.devices()), "memory_peak_bytes": mem}
+              "count": len(devices), "memory_peak_bytes": mem}
     result = {"correct": correct, "attempted": len(records),
               "failed": len(failed), "metrics": metrics, "device": device}
     if summary is not None:

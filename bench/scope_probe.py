@@ -1,80 +1,23 @@
-"""Reduce a cell's traced run by the program's own scopes and spans, or
-record a small trace of them for the tests.
+"""Record a small trace of the program's scopes and spans for the tests.
 
-    python3 bench/scope_probe.py --workload epsilon_m10.fit --seed 7
     python3 bench/scope_probe.py --toy bench/tests/data/v5e_scopes.xplane.pb
 
-With ``--workload`` it runs the cell as ``run.py --trace 1`` does, through
-``harness.run_cell``, and reduces the same trace a second time with
-``bench/scopes.py``: the harness keeps no trace after its own reduction,
-so for the length of the run ``trace.summarize`` is wrapped to do both.
-The last line of stdout is one JSON object: the harness's result, and
-under ``program`` the seconds by scope and span with the numbers read from
-them (shares of leaf time, the rounds' roofline, idle time under the
-program's spans per request).
-
-With ``--toy`` it records a few rounds of ``decsvm_fit_tol`` and one
+It records a few rounds of ``decsvm_fit_tol`` and one
 ``select_lambda_path`` at a toy size, each under a ``bench:request`` span
 and with the harness's profiler options, and writes the ``.xplane.pb`` to
 the path given without its ``/host:metadata`` plane (the programs' HLO,
-which neither reader reads, and most of the file's bytes).  Both need a
-TPU.
+which neither reader reads, and most of the file's bytes).  It needs a
+TPU.  A cell's own readings by scope and span are the metrics of its traced
+run, which read ``Run.program``.
 """
-import time
-
-T0 = time.perf_counter()
-
-import argparse  # noqa: E402
-import json  # noqa: E402
-import shutil  # noqa: E402
-import sys  # noqa: E402
-import tempfile  # noqa: E402
-from pathlib import Path  # noqa: E402
+import argparse
+import json
+import shutil
+import sys
+import tempfile
+from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent.parent
-
-
-def probe(workload: str, seed: int) -> dict:
-    from bench import costs, harness, scopes, trace
-
-    held = {}
-    summarize = trace.summarize
-
-    def both(log_dir):
-        held["program"] = scopes.summarize(log_dir)
-        return summarize(log_dir)
-
-    trace.summarize = both
-    try:
-        cell = harness.load_cell(workload)
-        result, _ = harness.run_cell(cell, seed, 0.0, True, t0=T0)
-    finally:
-        trace.summarize = summarize
-    prog, c = held["program"], cell.config
-    done = result["attempted"] - result["failed"]
-    per_request = (result["metrics"].get("path_rounds")
-                   or result["metrics"]["rounds_per_fit"])
-    rounds = done * per_request["value"]
-    round_s = prog.scope_seconds.get("decsvm.round", 0.0)
-    nbytes = rounds * costs.streaming_bytes_per_round(c["m"], c["n"],
-                                                      c["p"] + 1)
-    hbm = costs.peaks(result["device"]["kind"])["hbm_bytes_per_s"]
-    in_spans = sum(v for k, v in prog.span_idle_seconds.items()
-                   if k != scopes.OUTSIDE)
-    result["program"] = {
-        "scope_seconds": prog.scope_seconds,
-        "span_idle_seconds": prog.span_idle_seconds,
-        "scoped_share": 100.0 * (1.0 - prog.share(scopes.UNSCOPED)),
-        "kkt_share": 100.0 * prog.share("decsvm.kkt_check"),
-        "rho_share": 100.0 * prog.share("decsvm.rho"),
-        "bic_share": 100.0 * prog.share("decsvm.bic"),
-        "round_roofline": (100.0 * nbytes / hbm / round_s if round_s
-                           else None),
-        "program_idle_ms": 1e3 * in_spans / done if done else None,
-        "traced_wall_s": prog.window_s / done if done else None,
-        "rounds": rounds,
-    }
-    return result
 
 
 def _varint(n: int) -> bytes:
@@ -150,19 +93,15 @@ def record_toy(out: str) -> dict:
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--workload")
-    ap.add_argument("--seed", type=int, default=7)
-    ap.add_argument("--toy", help="record the toy trace to this path")
+    ap.add_argument("--toy", required=True,
+                    help="record the toy trace to this path")
     args = ap.parse_args()
-    if bool(args.workload) == bool(args.toy):
-        ap.error("give one of --workload and --toy")
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
     import run                          # bench/run.py, beside this file
     run.enable_compile_cache()
     from bench import harness
     try:
-        out = (record_toy(args.toy) if args.toy
-               else probe(args.workload, args.seed))
+        out = record_toy(args.toy)
     except harness.NoChip as e:
         print(f"scope_probe: {e}; refusing to run", file=sys.stderr)
         return 3
