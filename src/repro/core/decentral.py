@@ -38,6 +38,7 @@ LLA stage-2 re-fit of ``repro.core.penalties`` runs sharded.
 from __future__ import annotations
 
 import functools
+import weakref
 from typing import Optional
 
 import jax
@@ -45,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import sanitize, solver
+from repro.core import sanitize, solver, trace
 from repro.core.admm import ADMMConfig
 
 Array = jax.Array
@@ -89,21 +90,23 @@ def _neighbor_sum_fn(schedule: str, ndev: int, Wl: Optional[Array]):
     if schedule == "ring":
 
         def ring_sum(Bl):
-            up = jnp.roll(Bl, -1, axis=0)    # row i <- row i+1 (local)
-            dn = jnp.roll(Bl, 1, axis=0)     # row i <- row i-1 (local)
-            fwd = [(d, (d + 1) % ndev) for d in range(ndev)]
-            bwd = [(d, (d - 1) % ndev) for d in range(ndev)]
-            first_of_next = jax.lax.ppermute(Bl[:1], "node", bwd)
-            last_of_prev = jax.lax.ppermute(Bl[-1:], "node", fwd)
-            up = up.at[-1:].set(first_of_next)
-            dn = dn.at[:1].set(last_of_prev)
-            return up + dn
+            with jax.named_scope(trace.EXCHANGE):
+                up = jnp.roll(Bl, -1, axis=0)    # row i <- row i+1 (local)
+                dn = jnp.roll(Bl, 1, axis=0)     # row i <- row i-1 (local)
+                fwd = [(d, (d + 1) % ndev) for d in range(ndev)]
+                bwd = [(d, (d - 1) % ndev) for d in range(ndev)]
+                first_of_next = jax.lax.ppermute(Bl[:1], "node", bwd)
+                last_of_prev = jax.lax.ppermute(Bl[-1:], "node", fwd)
+                up = up.at[-1:].set(first_of_next)
+                dn = dn.at[:1].set(last_of_prev)
+                return up + dn
 
         return ring_sum
 
     def gather_sum(Bl):
-        B_all = jax.lax.all_gather(Bl, "node", axis=0, tiled=True)   # (m, p)
-        return solver.mm(Wl, B_all)
+        with jax.named_scope(trace.EXCHANGE):
+            B_all = jax.lax.all_gather(Bl, "node", axis=0, tiled=True)
+            return solver.mm(Wl, B_all)                       # (m_l, p)
 
     return gather_sum
 
@@ -132,14 +135,15 @@ def _block_neighbor_sum_fn(axis: str, ndev: int, Wd_l: Array,
         acc = solver.mm(Wd_l, Bl)
         moving = Bl
         prev = 0
-        for j, k in enumerate(offsets):
-            shift = k - prev
-            # device d receives from device (d + shift) % ndev, so after
-            # the permute ``moving`` on device d holds chunk (d + k)'s B
-            perm = [(s, (s - shift) % ndev) for s in range(ndev)]
-            moving = jax.lax.ppermute(moving, axis, perm)
-            acc = acc + solver.mm(Woff_l[j], moving)
-            prev = k
+        with jax.named_scope(trace.EXCHANGE):
+            for j, k in enumerate(offsets):
+                shift = k - prev
+                # device d receives from device (d + shift) % ndev, so after
+                # the permute ``moving`` on device d holds chunk (d + k)'s B
+                perm = [(s, (s - shift) % ndev) for s in range(ndev)]
+                moving = jax.lax.ppermute(moving, axis, perm)
+                acc = acc + solver.mm(Woff_l[j], moving)
+                prev = k
         return acc
 
     return block_sum
@@ -334,69 +338,119 @@ def _as_topology(W):
     return graph.BlockTopology.from_dense(np.asarray(W))
 
 
-def _chunk_prep(X, y, W, cfg, mesh):
-    """Pad (X, y) with all-zero ghost nodes to m_pad = ceil(m/ndev)*ndev
-    and build the block-sparse neighbour-sum operands, device-placed on
-    the ("node_chunk",) mesh.  Ghost rows (X = 0, y = 0, W rows and
-    columns 0) are exact fixed points of the Algorithm-1 update: deg =
-    rho = 0 and omega = 0 (``_padded_omega``), so their B and P stay
-    identically zero through every round — no sample mask needed, which
-    keeps the pallas/megakernel fast paths available for padded chunks.
+def _chunk_mesh(X, mesh: Optional[Mesh]) -> Mesh:
+    """The ("node_chunk",) mesh of a chunked fit: ``mesh`` where given;
+    else the devices a ``jax.Array`` X is already sharded over along its
+    node axis, in their order, so that X stays where it is; else every
+    device (``make_node_chunk_mesh``)."""
+    if mesh is not None:
+        return mesh
+    sh = getattr(X, "sharding", None)
+    if (isinstance(sh, NamedSharding) and sh.mesh.devices.ndim == 1
+            and tuple(sh.spec)[:1] == sh.mesh.axis_names
+            and not any(tuple(sh.spec)[1:])):
+        return Mesh(sh.mesh.devices, ("node_chunk",))
+    return make_node_chunk_mesh()
+
+
+# topology -> {mesh: its device operands}; an entry lives as long as the
+# caller keeps its topology.
+_CHUNK_OPERANDS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+
+
+def _chunk_operands(top, mesh: Mesh):
+    """The block-sparse neighbour-sum operands of topology ``top`` for
+    ``mesh``'s chunks, device-placed once per (topology, mesh): a caller
+    that keeps its ``graph.BlockTopology`` pays no host work for the graph
+    per fit.  Returns (W_diag, W_off, deg, node mask, offsets)."""
+    per_mesh = _CHUNK_OPERANDS.setdefault(top, {})
+    if mesh not in per_mesh:
+        W_diag, offsets, W_off = top.chunk_operands(mesh.shape["node_chunk"])
+        m_pad = W_diag.shape[0]
+        deg = np.zeros((m_pad,), np.float32)
+        deg[:top.m] = top.degrees()
+        nmask = np.zeros((m_pad,), np.float32)
+        nmask[:top.m] = 1.0
+        cs = NamedSharding(mesh, P("node_chunk"))
+        per_mesh[mesh] = (
+            jax.device_put(W_diag, cs),
+            jax.device_put(W_off, NamedSharding(mesh, P(None, "node_chunk"))),
+            jax.device_put(deg, cs), jax.device_put(nmask, cs), offsets)
+    return per_mesh[mesh]
+
+
+def _chunk_prep(X, y, W, mesh):
+    """(X, y) node-sharded over the ("node_chunk",) mesh, and the
+    topology's operands (``_chunk_operands``).
+
+    Where m divides over the mesh, an fp32 X already sharded so along its
+    node axis is handed on as it is: no pad, no cast, no copy (the
+    ``device_put`` is to an equivalent sharding).  Otherwise (X, y) are
+    padded with all-zero ghost nodes to m_pad = ceil(m/ndev)*ndev.  Ghost
+    rows (X = 0, y = 0, W rows and columns 0) are exact fixed points of
+    the Algorithm-1 update: deg = rho = 0 and omega = 0
+    (``_padded_omega``), so their B and P stay identically zero through
+    every round — no sample mask needed, which keeps the pallas/megakernel
+    fast paths available for padded chunks.
     """
-    ndev = mesh.shape["node_chunk"]
     top = _as_topology(W)
-    m, _, _ = X.shape
-    assert top.m == m, (top.m, m)
-    W_diag, offsets, W_off = top.chunk_operands(ndev)
+    assert top.m == X.shape[0], (top.m, X.shape[0])
+    W_diag, W_off, deg, nmask, offsets = _chunk_operands(top, mesh)
     m_pad = W_diag.shape[0]
-    pad = m_pad - m
-    Xp = jnp.pad(jnp.asarray(X, jnp.float32), ((0, pad), (0, 0), (0, 0)))
-    yp = jnp.pad(jnp.asarray(y, jnp.float32), ((0, pad), (0, 0)))
-    deg = np.zeros((m_pad,), np.float32)
-    deg[:m] = top.degrees()
-    nmask = np.zeros((m_pad,), np.float32)
-    nmask[:m] = 1.0
-    rho = solver.compute_rho(Xp, cfg.h, cfg.kernel, cfg.rho_safety,
-                             backend=solver.resolve_backend(cfg))
     cs = NamedSharding(mesh, P("node_chunk"))
-    ops = dict(
-        X=jax.device_put(Xp.astype(solver.problem_dtype(cfg)), cs),
-        y=jax.device_put(yp, cs),
-        W_diag=jax.device_put(jnp.asarray(W_diag), cs),
-        W_off=jax.device_put(jnp.asarray(W_off),
-                             NamedSharding(mesh, P(None, "node_chunk"))),
-        deg=jax.device_put(jnp.asarray(deg), cs),
-        rho=jax.device_put(rho, cs),
-        nmask=jax.device_put(jnp.asarray(nmask), cs),
-    )
+
+    def place(a):
+        pad = m_pad - a.shape[0]
+        if pad:
+            a = jnp.pad(jnp.asarray(a, jnp.float32),
+                        ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+        elif not isinstance(a, jax.Array):
+            a = np.asarray(a, np.float32)
+        elif a.dtype != jnp.float32:
+            a = a.astype(jnp.float32)
+        return jax.device_put(a, cs)
+
+    ops = dict(X=place(X), y=place(y), W_diag=W_diag, W_off=W_off, deg=deg,
+               nmask=nmask)
     return ops, offsets, m_pad
+
+
+def _chunk_problem(Xl, yl, degl, cfg) -> solver.Problem:
+    """A chunk's ``Problem``: rho by the power iteration on the chunk's own
+    nodes (in X's fp32, as ``solver.make_problem``), then X in the
+    backend's compute dtype."""
+    rhol = solver.compute_rho(Xl, cfg.h, cfg.kernel, cfg.rho_safety,
+                              backend=solver.resolve_backend(cfg))
+    return _padded_problem(Xl.astype(solver.problem_dtype(cfg)), yl, degl,
+                           rhol, cfg)
 
 
 @functools.lru_cache(maxsize=64)
 def build_chunked_admm(m_pad: int, p: int, cfg: ADMMConfig, mesh: Mesh,
                        offsets, tol: Optional[float] = None,
                        stop_rule: str = "kkt", check_every: int = 4):
-    """Jitted chunked ADMM loop: ceil(m/ndev) nodes per device, the
-    round body vmapped over the chunk by ``solver.make_step`` (the
-    megakernel ``csvm_block_update`` path sees the chunk-shaped X, so
-    ``megakernel_supported`` re-budgets VMEM per chunk automatically).
+    """Jitted chunked ADMM loop: ceil(m/ndev) nodes per device, rho and
+    the round body vmapped over the chunk (``solver.compute_rho``,
+    ``solver.make_step``; the megakernel ``csvm_block_update`` path sees
+    the chunk-shaped X, so ``megakernel_supported`` re-budgets VMEM per
+    chunk automatically).
 
     ``tol=None`` runs cfg.max_iter fixed rounds; with a tol the KKT (or
     legacy progress) statistic early-stops, reduced over "node_chunk"
     with the padded ghost rows masked out of the network means.
 
-    Returns a jitted fn (X (m_pad,n,p), y, W_diag (m_pad,mc),
-    W_off (K,m_pad,mc), deg, rho, lam_weights (p,), node_mask (m_pad,))
+    Returns a jitted fn (X (m_pad,n,p) fp32, y, W_diag (m_pad,mc),
+    W_off (K,m_pad,mc), deg, lam_weights (p,), node_mask (m_pad,))
     -> (B (m_pad, p), rounds).
     """
     ndev = mesh.shape["node_chunk"]
     assert m_pad % ndev == 0, (m_pad, ndev)
 
-    def chunk_loop(Xl, yl, Wd, Woff, degl, rhol, lamw, nmask):
+    def chunk_loop(Xl, yl, Wd, Woff, degl, lamw, nmask):
         nbr = _block_neighbor_sum_fn("node_chunk", ndev, Wd, Woff, offsets)
         step = solver.make_step(cfg, nbr)
-        prob = _padded_problem(Xl, yl, degl, rhol, cfg)
-        state = _zero_state((Xl.shape[0], p), Xl.dtype, ("node_chunk",))
+        prob = _chunk_problem(Xl, yl, degl, cfg)
+        state = _zero_state((Xl.shape[0], p), prob.X.dtype, ("node_chunk",))
         if tol is None:
             # cached-neighbour driver: one ppermute chain per round, not two
             final = solver.run_fixed_cached(step, prob, cfg.lam, lamw,
@@ -416,8 +470,8 @@ def build_chunked_admm(m_pad: int, p: int, cfg: ADMMConfig, mesh: Mesh,
     fn = jax.shard_map(
         chunk_loop, mesh=mesh, check_vma=False,
         in_specs=(P("node_chunk"), P("node_chunk"), P("node_chunk"),
-                  P(None, "node_chunk"), P("node_chunk"), P("node_chunk"),
-                  P(), P("node_chunk")),
+                  P(None, "node_chunk"), P("node_chunk"), P(),
+                  P("node_chunk")),
         out_specs=(P("node_chunk"), P()))
     return jax.jit(fn)
 
@@ -428,16 +482,16 @@ def build_chunked_path(m_pad: int, p: int, L: int, cfg: ADMMConfig,
     """Chunked lambda-grid engine: the grid vmapped on top of the node
     chunking (the block-schedule analogue of ``build_sharded_path``).
 
-    Returns a jitted fn (X, y, W_diag, W_off, deg, rho, lams (L,),
+    Returns a jitted fn (X, y, W_diag, W_off, deg, lams (L,),
     lam_weights (p,)) -> path (L, m_pad, p).
     """
     ndev = mesh.shape["node_chunk"]
     assert m_pad % ndev == 0, (m_pad, ndev)
 
-    def chunk_loop(Xl, yl, Wd, Woff, degl, rhol, lams, lamw):
+    def chunk_loop(Xl, yl, Wd, Woff, degl, lams, lamw):
         nbr = _block_neighbor_sum_fn("node_chunk", ndev, Wd, Woff, offsets)
         step = solver.make_step(cfg, nbr)
-        prob = _padded_problem(Xl, yl, degl, rhol, cfg)
+        prob = _chunk_problem(Xl, yl, degl, cfg)
         m_local = Xl.shape[0]
 
         def fit_one(lam, B0, P0, prog0):
@@ -447,17 +501,16 @@ def build_chunked_path(m_pad: int, p: int, L: int, cfg: ADMMConfig,
                                            num_iters=cfg.max_iter,
                                            state=state).B
 
-        sdt = jnp.promote_types(Xl.dtype, jnp.float32)
+        sdt = jnp.promote_types(prob.X.dtype, jnp.float32)
         B0 = _pvary(jnp.zeros((L, m_local, p), sdt), ("node_chunk",))
         P0 = _pvary(jnp.zeros((L, m_local, p), sdt), ("node_chunk",))
         prog0 = _pvary(jnp.full((L,), jnp.inf, sdt), ("node_chunk",))
         return jax.vmap(fit_one)(lams, B0, P0, prog0)
 
     fn = jax.shard_map(
-        chunk_loop, mesh=mesh,
+        chunk_loop, mesh=mesh, check_vma=False,
         in_specs=(P("node_chunk"), P("node_chunk"), P("node_chunk"),
-                  P(None, "node_chunk"), P("node_chunk"), P("node_chunk"),
-                  P(), P()),
+                  P(None, "node_chunk"), P("node_chunk"), P(), P()),
         out_specs=P(None, "node_chunk"))
     return jax.jit(fn)
 
@@ -472,22 +525,25 @@ def decsvm_fit_chunked(X: Array, y: Array, W, cfg: ADMMConfig,
     ceil(m/ndev) nodes — m is no longer capped by the device count.
 
     ``W`` may be a dense (m, m) adjacency or a ``graph.BlockTopology``
-    (preferred at large m: no O(m^2) host array is ever built).  m need
-    not divide the device count: the tail chunk is padded with all-zero
-    ghost nodes that stay exact no-ops (see ``_chunk_prep``).
+    (preferred at large m: no O(m^2) host array is ever built, and its
+    device operands are made once, ``_chunk_operands``).  m need not
+    divide the device count: the tail chunk is padded with all-zero ghost
+    nodes that stay exact no-ops (see ``_chunk_prep``).  Without ``mesh``
+    an X sharded along its node axis keeps its devices (``_chunk_mesh``):
+    it is read where it lies, and never gathered.
 
     Returns B (m, p); with ``tol`` returns (B (m, p), rounds).
     """
     sanitize.reject_unsupported(cfg, "decsvm_fit_chunked")
-    mesh = mesh or make_node_chunk_mesh()
+    mesh = _chunk_mesh(X, mesh)
     m, _, p = X.shape
-    ops, offsets, m_pad = _chunk_prep(X, y, W, cfg, mesh)
+    ops, offsets, m_pad = _chunk_prep(X, y, W, mesh)
     fitted = build_chunked_admm(m_pad, p, cfg, mesh, offsets, tol=tol,
                                 stop_rule=stop_rule,
                                 check_every=check_every)
     B, t = fitted(ops["X"], ops["y"], ops["W_diag"], ops["W_off"],
-                  ops["deg"], ops["rho"],
-                  _lamw(lam_weights, p, jnp.float32), ops["nmask"])
+                  ops["deg"], _lamw(lam_weights, p, jnp.float32),
+                  ops["nmask"])
     B = _replicated(mesh, B)[:m]
     return (B, t) if tol is not None else B
 
@@ -502,15 +558,14 @@ def decsvm_path_chunked(X: Array, y: Array, W, lams, cfg: ADMMConfig,
     ``schedule="block"`` for fused in-program selection.
     """
     sanitize.reject_unsupported(cfg, "decsvm_path_chunked")
-    mesh = mesh or make_node_chunk_mesh()
+    mesh = _chunk_mesh(X, mesh)
     m, _, p = X.shape
     lams = jnp.asarray(lams, jnp.float32)
-    ops, offsets, m_pad = _chunk_prep(X, y, W, cfg, mesh)
+    ops, offsets, m_pad = _chunk_prep(X, y, W, mesh)
     fitted = build_chunked_path(m_pad, p, int(lams.shape[0]), cfg, mesh,
                                 offsets)
     path = fitted(ops["X"], ops["y"], ops["W_diag"], ops["W_off"],
-                  ops["deg"], ops["rho"], lams,
-                  _lamw(lam_weights, p, jnp.float32))
+                  ops["deg"], lams, _lamw(lam_weights, p, jnp.float32))
     return _replicated(mesh, path)[:, :m]
 
 

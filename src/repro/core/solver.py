@@ -79,13 +79,14 @@ def _platform() -> str:
 
 def xpass_applies(backend: str, X: Array, mask: Optional[Array]) -> bool:
     """The shape rule: backend "auto", a TPU, no sample mask, an fp32 node
-    block (n, p) over ``XPASS_MIN_BYTES`` whose p fits the kernel's VMEM."""
+    block (n, p) over ``XPASS_MIN_BYTES`` that fits the VMEM of the
+    kernel's orientation for its shape."""
     if not (backend == "auto" and mask is None and _platform() == "tpu"
             and X.dtype == jnp.float32
             and X.shape[-2] * X.shape[-1] * 4 > XPASS_MIN_BYTES):
         return False
     from repro.kernels import xpass
-    return xpass.supported(X.shape[-1])
+    return xpass.supported(*X.shape[-2:])
 
 
 def node_xtphi(X: Array, y: Optional[Array], v: Array, *, weight: str,
@@ -98,14 +99,17 @@ def node_xtphi(X: Array, y: Optional[Array], v: Array, *, weight: str,
     ``weight="loss"``: phi(u)_i = L_h'(y_i u_i) y_i / n, the smoothed-loss
     gradient; ``weight="linear"``: phi(u) = u / n.  A sample ``mask`` drops
     its rows and n becomes their count.  Where ``xpass_applies`` the
-    product is one read of X by the ``decsvm_xpass`` kernel (vmapped over
+    product is one read of X by a ``decsvm_xpass`` kernel in the
+    orientation of the block's shape (``xpass.rows_on_lanes``; vmapped over
     nodes by ``pallas_call``'s batching rule), else two HIGHEST ``mm``.
     """
     if xpass_applies(backend, X, mask):
-        from repro.kernels import ops
+        from repro.kernels import ops, xpass
         y = jnp.zeros(X.shape[:1], X.dtype) if y is None else y
-        return ops.xpass(X[None], y[None], v[None], weight=weight, h=h,
-                         kernel=kernel)[0]
+        product = (ops.xpass if xpass.rows_on_lanes(*X.shape)
+                   else ops.xpass_rows)
+        return product(X[None], y[None], v[None], weight=weight, h=h,
+                       kernel=kernel)[0]
     u = mm(X, v)
     if weight == "loss":
         w = losses.get_kernel(kernel).dloss(y * u, h) * y

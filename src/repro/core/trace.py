@@ -15,14 +15,22 @@ that path as the ``tf_op`` of each device operation.
     RHO        decsvm.rho        ``solver.compute_rho``: the power
                                  iteration over each node's X
     BIC        decsvm.bic        ``path.score_path``: path scoring
+    EXCHANGE   decsvm.exchange   ``decentral``'s neighbour sums across
+                                 devices: the ``ppermute`` chain and the
+                                 off-diagonal block dots of the chunked
+                                 engine, the gather or ring of the
+                                 sharded one (inside ``decsvm.round``)
 
 Kernel name (the ``name`` of a ``pallas_call``, which names its HLO
 custom call and so its device operation):
 
-    decsvm_xpass                 ``kernels/xpass.py``, called by
-                                 ``solver.node_xtphi`` inside the three
-                                 scopes above where ``xpass_applies``;
-                                 read by ``bench/metrics/kernel_share.fit``
+    decsvm_xpass                 ``kernels/xpass.py``, its two
+    decsvm_xpass_rows            orientations, called by
+                                 ``solver.node_xtphi`` inside the round,
+                                 KKT and rho scopes where
+                                 ``xpass_applies``; read by
+                                 ``bench/metrics/kernel_share.fit`` and
+                                 ``xpass_roofline.p2p``
 
 Host spans (``jax.profiler.TraceAnnotation``) cost nothing unless a
 profiler session is active:
@@ -52,6 +60,7 @@ ROUND = "decsvm.round"
 KKT_CHECK = "decsvm.kkt_check"
 RHO = "decsvm.rho"
 BIC = "decsvm.bic"
+EXCHANGE = "decsvm.exchange"
 
 SPAN_LAMBDA_GRID = "decsvm:lambda_grid"
 SPAN_PATH_PROGRAM = "decsvm:path_program"

@@ -17,6 +17,7 @@ from repro.kernels.csvm_update import (csvm_block_update as
 from repro.kernels.flash_attention import flash_attention as _flash_attention
 from repro.kernels.ssd_scan import ssd_scan as _ssd_scan
 from repro.kernels.xpass import xpass as _xpass
+from repro.kernels.xpass import xpass_rows as _xpass_rows
 
 
 def _default_interpret() -> bool:
@@ -89,6 +90,15 @@ def xpass(X, y, V, *, weight, h=0.0, kernel="epanechnikov", interpret=None,
     interpret = _default_interpret() if interpret is None else interpret
     return _xpass(X, y, V, weight=weight, h=h, kernel=kernel,
                   interpret=interpret, **kw)
+
+
+def xpass_rows(X, y, V, *, weight, h=0.0, kernel="epanechnikov",
+               interpret=None, **kw):
+    """``xpass`` reading X as it is stored, rows on the sublanes
+    (``decsvm_xpass_rows``), for a block whose p pads less than its n."""
+    interpret = _default_interpret() if interpret is None else interpret
+    return _xpass_rows(X, y, V, weight=weight, h=h, kernel=kernel,
+                       interpret=interpret, **kw)
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, sm_scale=None,

@@ -172,10 +172,10 @@ def test_uneven_final_chunk_padding_rows_are_exact_noops():
         # raw padded state: ghost rows bit-zero after 40 rounds
         mesh = decentral.make_node_chunk_mesh()
         ops, offsets, m_pad = decentral._chunk_prep(
-            jnp.asarray(X), jnp.asarray(y), top, cfg, mesh)
+            jnp.asarray(X), jnp.asarray(y), top, mesh)
         fitted = decentral.build_chunked_admm(m_pad, p, cfg, mesh, offsets)
         Bp, _ = fitted(ops["X"], ops["y"], ops["W_diag"], ops["W_off"],
-                       ops["deg"], ops["rho"], jnp.ones((p,), jnp.float32),
+                       ops["deg"], jnp.ones((p,), jnp.float32),
                        ops["nmask"])
         ghost = np.asarray(Bp)[m:]
         print("ghost", np.abs(ghost).max(), m_pad - m)

@@ -191,3 +191,52 @@ def test_compile_counter_counts_inside_a_window():
     t2 = time.perf_counter()
     assert trace.compiles.backend_between(t0, t1) >= 1
     assert trace.compiles.backend_between(t1, t2) == 0
+
+
+EXCHANGE_SCRIPT = """
+import re
+import numpy as np, jax, jax.numpy as jnp
+from repro.core import ADMMConfig, decentral, graph
+m, n, p = 16, 12, 8
+top = graph.k_regular(m, 4)
+mesh = decentral.make_node_chunk_mesh(4)
+rng = np.random.default_rng(0)
+X = jnp.asarray(rng.normal(size=(m, n, p)), jnp.float32)
+y = jnp.asarray(np.where(rng.normal(size=(m, n)) > 0, 1.0, -1.0), jnp.float32)
+cfg = ADMMConfig(lam=0.05, h=0.5, max_iter=8)
+ops, offsets, m_pad = decentral._chunk_prep(X, y, top, mesh)
+fitted = decentral.build_chunked_admm(m_pad, p, cfg, mesh, offsets,
+                                      tol=1e-3, check_every=4)
+text = fitted.lower(ops["X"], ops["y"], ops["W_diag"], ops["W_off"],
+                    ops["deg"], jnp.ones((p,), jnp.float32),
+                    ops["nmask"]).compile().as_text()
+permutes = [l for l in text.splitlines()
+            if re.search(r"= .*collective-permute(-start)?\\(", l)]
+print("PERMUTES", len(permutes))
+print("SCOPED", sum("decsvm.exchange" in l for l in permutes))
+"""
+
+
+def test_chunked_programs_collective_permutes_carry_the_exchange_scope():
+    """The chunked engine on 4 CPU devices: every ``collective-permute`` of
+    its optimized HLO (the neighbour exchange between node chunks) lies
+    under ``decsvm.exchange``."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parent.parent
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=str(root / "src"))
+    run = subprocess.run([sys.executable, "-c",
+                          textwrap.dedent(EXCHANGE_SCRIPT)],
+                         capture_output=True, text=True, env=env,
+                         timeout=600)
+    assert run.returncode == 0, run.stderr[-3000:]
+    counts = dict(line.split() for line in run.stdout.splitlines()
+                  if line.startswith(("PERMUTES", "SCOPED")))
+    assert int(counts["PERMUTES"]) > 0
+    assert counts["SCOPED"] == counts["PERMUTES"]

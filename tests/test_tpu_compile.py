@@ -21,24 +21,30 @@ from repro.kernels import csvm_update, ops
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One described v5e chip; the persistent compilation cache is off
+def topo():
+    """A described v5e:2x2 host; the persistent compilation cache is off
     around these compiles (an entry written without a chip cannot be read
     back, and would warn on every later run)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
+        described = topologies.get_topology_desc(platform="tpu",
+                                                 topology_name="v5e:2x2")
     except Exception as e:                      # no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield described
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One described v5e chip."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture
@@ -142,6 +148,22 @@ def test_one_pass_kernel_compiles_at_the_epsilon_node_shape(chip):
         assert compiled.memory_analysis().temp_size_in_bytes < n * p * 4
 
 
+def test_row_kernel_compiles_at_the_peer_node_shape(chip):
+    """``decsvm_xpass_rows`` at a peer network's node block (200, 20001):
+    p=20001 and the masked last row group lower through Mosaic, and X
+    (stored p-minor at this shape) reaches the kernel with no copy."""
+    from repro.kernels import xpass
+    m, n, p = 4, 200, 20001
+    for weight in xpass.WEIGHTS:
+        f = lambda X, y, V, w=weight: xpass.xpass_rows(
+            X, y, V, weight=w, h=0.25, interpret=False)
+        compiled = jax.jit(f).lower(_spec(chip, (m, n, p)),
+                                    _spec(chip, (m, n)),
+                                    _spec(chip, (m, p))).compile()
+        assert _has_kernel(compiled)
+        assert compiled.memory_analysis().temp_size_in_bytes < n * p * 4
+
+
 def test_epsilon_fit_program_reads_x_in_place(chip, mosaic, monkeypatch):
     """The fit program of the epsilon cell with the rule on (a TPU): every
     product over X is the one-pass kernel (round, KKT check, power
@@ -168,3 +190,46 @@ def test_epsilon_fit_program_reads_x_in_place(chip, mosaic, monkeypatch):
         assert kernels == (4 if platform == "tpu" else 0)
     jax.clear_caches()
     assert copies == {"cpu": 1, "tpu": 0}
+
+
+def test_p2p_fit_program_compiles_for_four_chips(topo, mosaic, monkeypatch):
+    """The chunked fit program of the ``p2p_m1024.fit`` cell, X (1024, 200,
+    20001) sharded by node over a described v5e:2x2: every product over X
+    (round, KKT check, power iteration in and after its loop) is the row
+    kernel, the neighbour exchange is collective-permutes, and beside the
+    argument the program holds at most one X-sized temporary (a relayout
+    of the chip's X, where the compiler plans X node-minor), so that with
+    the pool's second data set it fits a chip's 16 GB."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import ADMMConfig, decentral, graph, solver
+    from repro.kernels import xpass
+    m, n, p = 1024, 200, 20001
+    monkeypatch.setattr(solver, "_platform", lambda: "tpu")
+    jax.clear_caches()
+    mesh = Mesh(np.array(topo.devices), ("node_chunk",))
+    W_diag, offsets, W_off = graph.k_regular(m, 4).chunk_operands(4)
+    cfg = ADMMConfig(lam=0.0035, h=0.14, max_iter=300)
+    fitted = decentral.build_chunked_admm(m, p, cfg, mesh, offsets,
+                                          tol=1e-3, check_every=4)
+    rows, rep = (NamedSharding(mesh, P("node_chunk")),
+                 NamedSharding(mesh, P()))
+    s = lambda sharding, *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                                      sharding=sharding)
+    compiled = fitted.lower(
+        s(rows, m, n, p), s(rows, m, n), s(rows, *W_diag.shape),
+        s(NamedSharding(mesh, P(None, "node_chunk")), *W_off.shape),
+        s(rows, m), s(rep, p), s(rows, m)).compile()
+    jax.clear_caches()
+    text = compiled.as_text()
+    kernels = re.findall(r"(decsvm_xpass\w*)\.\d+ = \S+ custom-call\(",
+                         text)
+    assert sorted(set(kernels)) == [xpass.ROWS_NAME] and len(kernels) == 4
+    assert "collective-permute" in text
+    share = 4 * (m // 4) * n * p
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.1 * share
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + share < 16e9

@@ -5,6 +5,8 @@ The kernel is held to the two HIGHEST contractions it replaces; the rule
 is steered here by patching ``solver._platform`` (JAX reports the CPU) and,
 where a small problem must take the kernel, ``solver.XPASS_MIN_BYTES``.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -78,6 +80,44 @@ def test_xpass_rejects_an_unknown_map_and_a_ragged_tile():
         ops.xpass(X, y, V, weight="linear", tile=100)
 
 
+@pytest.mark.parametrize("m,n,p,tile", [(2, 37, 130, 16),
+                                        (3, 203, 2001, None),
+                                        (1, 5, 7, 8)],
+                         ids=["n37-p130-3-tiles", "p2001-one-tile",
+                              "n-under-a-group"])
+@pytest.mark.parametrize("weight", xpass.WEIGHTS)
+def test_xpass_rows_matches_the_two_highest_products(m, n, p, tile, weight):
+    """The row-major orientation: n not a multiple of 8 (the last group of
+    the last tile part empty), p not a multiple of 128, labels with zero
+    rows."""
+    X, y, V = _inputs(m, n, p, zero_rows=3)
+    got = ops.xpass_rows(X, y, V, weight=weight, h=H, tile=tile)
+    assert got.shape == (m, p) and got.dtype == jnp.float32
+    _close(got, _pair(X, y, V, weight))
+
+
+def test_xpass_rows_rejects_a_ragged_tile():
+    X, y, V = _inputs(1, 16, 8)
+    with pytest.raises(ValueError, match="multiple of 8"):
+        ops.xpass_rows(X, y, V, weight="linear", tile=12)
+
+
+@pytest.mark.parametrize("n,p,lanes", [(40000, 2001, True),
+                                       (200, 20001, False),
+                                       (150, 40, True), (300, 2001, False)],
+                         ids=["epsilon", "peer", "narrow", "short-wide"])
+def test_the_orientation_follows_the_lane_padding_of_the_block(n, p, lanes):
+    assert xpass.rows_on_lanes(n, p) is lanes
+
+
+def test_vmem_bounds_p_for_rows_on_lanes_only():
+    """At p=30,001 a 128-lane tile of rows on the lanes passes the VMEM
+    budget; rows on the sublanes take it in 8-row groups."""
+    assert not xpass.supported(40000, 30001)
+    assert xpass.supported(200, 30001)
+    assert xpass.supported(200, 200001)
+
+
 # -- the rule ----------------------------------------------------------------
 
 P500 = jax.ShapeDtypeStruct((200, 501), jnp.float32)        # 0.4 MB
@@ -108,6 +148,19 @@ def _fit_jaxpr(m, n, p, backend):
                                      stop_rule="kkt", check_every=4)
     s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
     return str(jax.make_jaxpr(f)(s(m, n, p), s(m, n), s(m, m)))
+
+
+@pytest.mark.parametrize("n,p,kernel", [(40000, 2001, xpass.NAME),
+                                        (200, 20001, xpass.ROWS_NAME)],
+                         ids=["epsilon", "peer"])
+def test_node_xtphi_takes_the_orientation_of_the_block(monkeypatch, n, p,
+                                                       kernel):
+    monkeypatch.setattr(solver, "_platform", lambda: "tpu")
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    jaxpr = str(jax.make_jaxpr(lambda X, y, v: solver.node_xtphi(
+        X, y, v, weight="loss", h=H, backend="auto"))(s(n, p), s(n), s(p)))
+    names = set(re.findall(r"name=(decsvm_xpass\w*)", jaxpr))
+    assert names == {kernel}
 
 
 @pytest.mark.parametrize("n,p,backend,kernels", [
