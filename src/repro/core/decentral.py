@@ -16,8 +16,9 @@ collective.  Each device owns m/ndev nodes; two exchange schedules:
     device owns a contiguous chunk of ceil(m/ndev) nodes on the
     "node_chunk" axis, the W B neighbour sum is computed block-wise
     (diagonal blocks as local dense dots, cross-chunk block diagonals
-    rotated in via ppermute, all-zero block diagonals skipped statically
-    from the topology's block-sparsity pattern), and m that doesn't
+    fed by a ppermute of only the rows they read, all-zero block
+    diagonals skipped statically from the topology's block-sparsity
+    pattern), and m that doesn't
     divide the chunk count pads with exact-no-op ghost nodes.  This is
     the m >> devices path: m = 1024 networks run on 8 devices.
 
@@ -116,35 +117,52 @@ def _local_problem(Xl, yl, degl, rhol, cfg, mask=None) -> solver.Problem:
     return solver.Problem(Xl, yl, degl, rhol, omega, mask)
 
 
+def _halo_rows(Bl: Array, rows) -> Array:
+    """Rows ``rows`` (sorted, static) of a chunk's B: a static slice where
+    they are contiguous (the chunk itself, with no op, where they are all
+    of it), else a static gather."""
+    lo, hi = rows[0], rows[-1] + 1
+    if hi - lo == len(rows):
+        return Bl[lo:hi]
+    return jnp.take(Bl, np.asarray(rows), axis=0, mode="clip",
+                    indices_are_sorted=True, unique_indices=True)
+
+
 def _block_neighbor_sum_fn(axis: str, ndev: int, Wd_l: Array,
-                           Woff_l: Array, offsets):
+                           Woff_l: Array, halos):
     """Block-sparse chunked neighbour sum: (W B)_l with W viewed as an
     ndev x ndev grid of (mc, mc) blocks.
 
     The diagonal block is a local dense dot.  Cross-chunk blocks live on
-    the statically-kept ring offsets only (``offsets``, from the
+    the statically-kept ring offsets only (``halos``, from the
     topology's block-sparsity pattern — all-zero block diagonals are
-    skipped at trace time): a moving copy of B rotates offset-to-offset
-    via ``ppermute`` (delta shifts, so k offsets cost k hops total) and
-    each kept offset contributes one (mc, mc) x (mc, p) dot.
+    skipped at trace time).  A halo exchange: for each kept offset k
+    every device sends only the rows of its B that some block (d, d+k)
+    reads (``halos``' rows, ``graph.BlockTopology.chunk_operands``),
+    straight to the device k chunks back in one ``ppermute``; the halos
+    received then take one (mc, R) x (R, p) dot.  Where a halo is the
+    whole chunk, the chunk goes as it is.  The rows carried are recorded
+    in ``trace.exchange`` as the sum is built.
 
-    Wd_l: (mc, mc) local diagonal block rows; Woff_l: (K, mc, mc) local
-    rows of the K kept off-diagonal block diagonals.
+    Wd_l: (mc, mc) local diagonal block rows; Woff_l: (mc, R) local rows
+    of the kept off-diagonal blocks in their halo columns, side by side.
     """
+    mc = Wd_l.shape[1]
+    for _, rows in halos:
+        trace.exchange.record(len(rows), mc)
+
     def block_sum(Bl):
         acc = solver.mm(Wd_l, Bl)
-        moving = Bl
-        prev = 0
+        if not halos:
+            return acc
         with jax.named_scope(trace.EXCHANGE):
-            for j, k in enumerate(offsets):
-                shift = k - prev
-                # device d receives from device (d + shift) % ndev, so after
-                # the permute ``moving`` on device d holds chunk (d + k)'s B
-                perm = [(s, (s - shift) % ndev) for s in range(ndev)]
-                moving = jax.lax.ppermute(moving, axis, perm)
-                acc = acc + solver.mm(Woff_l[j], moving)
-                prev = k
-        return acc
+            # device d receives from device (d + k) % ndev, so piece k on
+            # device d holds chunk (d + k)'s halo rows of B
+            recv = [jax.lax.ppermute(
+                        _halo_rows(Bl, rows), axis,
+                        [(s, (s - k) % ndev) for s in range(ndev)])
+                    for k, rows in halos]
+            return acc + solver.mm(Woff_l, jnp.concatenate(recv))
 
     return block_sum
 
@@ -362,10 +380,10 @@ def _chunk_operands(top, mesh: Mesh):
     """The block-sparse neighbour-sum operands of topology ``top`` for
     ``mesh``'s chunks, device-placed once per (topology, mesh): a caller
     that keeps its ``graph.BlockTopology`` pays no host work for the graph
-    per fit.  Returns (W_diag, W_off, deg, node mask, offsets)."""
+    per fit.  Returns (W_diag, W_off, deg, node mask, halos)."""
     per_mesh = _CHUNK_OPERANDS.setdefault(top, {})
     if mesh not in per_mesh:
-        W_diag, offsets, W_off = top.chunk_operands(mesh.shape["node_chunk"])
+        W_diag, halos, W_off = top.chunk_operands(mesh.shape["node_chunk"])
         m_pad = W_diag.shape[0]
         deg = np.zeros((m_pad,), np.float32)
         deg[:top.m] = top.degrees()
@@ -374,8 +392,8 @@ def _chunk_operands(top, mesh: Mesh):
         cs = NamedSharding(mesh, P("node_chunk"))
         per_mesh[mesh] = (
             jax.device_put(W_diag, cs),
-            jax.device_put(W_off, NamedSharding(mesh, P(None, "node_chunk"))),
-            jax.device_put(deg, cs), jax.device_put(nmask, cs), offsets)
+            jax.device_put(W_off, cs),
+            jax.device_put(deg, cs), jax.device_put(nmask, cs), halos)
     return per_mesh[mesh]
 
 
@@ -395,7 +413,7 @@ def _chunk_prep(X, y, W, mesh):
     """
     top = _as_topology(W)
     assert top.m == X.shape[0], (top.m, X.shape[0])
-    W_diag, W_off, deg, nmask, offsets = _chunk_operands(top, mesh)
+    W_diag, W_off, deg, nmask, halos = _chunk_operands(top, mesh)
     m_pad = W_diag.shape[0]
     cs = NamedSharding(mesh, P("node_chunk"))
 
@@ -412,7 +430,7 @@ def _chunk_prep(X, y, W, mesh):
 
     ops = dict(X=place(X), y=place(y), W_diag=W_diag, W_off=W_off, deg=deg,
                nmask=nmask)
-    return ops, offsets, m_pad
+    return ops, halos, m_pad
 
 
 def _chunk_problem(Xl, yl, degl, cfg) -> solver.Problem:
@@ -427,7 +445,7 @@ def _chunk_problem(Xl, yl, degl, cfg) -> solver.Problem:
 
 @functools.lru_cache(maxsize=64)
 def build_chunked_admm(m_pad: int, p: int, cfg: ADMMConfig, mesh: Mesh,
-                       offsets, tol: Optional[float] = None,
+                       halos, tol: Optional[float] = None,
                        stop_rule: str = "kkt", check_every: int = 4):
     """Jitted chunked ADMM loop: ceil(m/ndev) nodes per device, rho and
     the round body vmapped over the chunk (``solver.compute_rho``,
@@ -440,19 +458,20 @@ def build_chunked_admm(m_pad: int, p: int, cfg: ADMMConfig, mesh: Mesh,
     with the padded ghost rows masked out of the network means.
 
     Returns a jitted fn (X (m_pad,n,p) fp32, y, W_diag (m_pad,mc),
-    W_off (K,m_pad,mc), deg, lam_weights (p,), node_mask (m_pad,))
-    -> (B (m_pad, p), rounds).
+    W_off (m_pad,R), deg, lam_weights (p,), node_mask (m_pad,))
+    -> (B (m_pad, p), rounds).  ``halos`` are the kept block diagonals
+    and their halo rows (``graph.BlockTopology.chunk_operands``).
     """
     ndev = mesh.shape["node_chunk"]
     assert m_pad % ndev == 0, (m_pad, ndev)
 
     def chunk_loop(Xl, yl, Wd, Woff, degl, lamw, nmask):
-        nbr = _block_neighbor_sum_fn("node_chunk", ndev, Wd, Woff, offsets)
+        nbr = _block_neighbor_sum_fn("node_chunk", ndev, Wd, Woff, halos)
         step = solver.make_step(cfg, nbr)
         prob = _chunk_problem(Xl, yl, degl, cfg)
         state = _zero_state((Xl.shape[0], p), prob.X.dtype, ("node_chunk",))
         if tol is None:
-            # cached-neighbour driver: one ppermute chain per round, not two
+            # cached-neighbour driver: one halo exchange per round, not two
             final = solver.run_fixed_cached(step, prob, cfg.lam, lamw,
                                             num_iters=cfg.max_iter,
                                             state=state)
@@ -470,7 +489,7 @@ def build_chunked_admm(m_pad: int, p: int, cfg: ADMMConfig, mesh: Mesh,
     fn = jax.shard_map(
         chunk_loop, mesh=mesh, check_vma=False,
         in_specs=(P("node_chunk"), P("node_chunk"), P("node_chunk"),
-                  P(None, "node_chunk"), P("node_chunk"), P(),
+                  P("node_chunk"), P("node_chunk"), P(),
                   P("node_chunk")),
         out_specs=(P("node_chunk"), P()))
     return jax.jit(fn)
@@ -478,7 +497,7 @@ def build_chunked_admm(m_pad: int, p: int, cfg: ADMMConfig, mesh: Mesh,
 
 @functools.lru_cache(maxsize=64)
 def build_chunked_path(m_pad: int, p: int, L: int, cfg: ADMMConfig,
-                       mesh: Mesh, offsets):
+                       mesh: Mesh, halos):
     """Chunked lambda-grid engine: the grid vmapped on top of the node
     chunking (the block-schedule analogue of ``build_sharded_path``).
 
@@ -489,7 +508,7 @@ def build_chunked_path(m_pad: int, p: int, L: int, cfg: ADMMConfig,
     assert m_pad % ndev == 0, (m_pad, ndev)
 
     def chunk_loop(Xl, yl, Wd, Woff, degl, lams, lamw):
-        nbr = _block_neighbor_sum_fn("node_chunk", ndev, Wd, Woff, offsets)
+        nbr = _block_neighbor_sum_fn("node_chunk", ndev, Wd, Woff, halos)
         step = solver.make_step(cfg, nbr)
         prob = _chunk_problem(Xl, yl, degl, cfg)
         m_local = Xl.shape[0]
@@ -510,7 +529,7 @@ def build_chunked_path(m_pad: int, p: int, L: int, cfg: ADMMConfig,
     fn = jax.shard_map(
         chunk_loop, mesh=mesh, check_vma=False,
         in_specs=(P("node_chunk"), P("node_chunk"), P("node_chunk"),
-                  P(None, "node_chunk"), P("node_chunk"), P(), P()),
+                  P("node_chunk"), P("node_chunk"), P(), P()),
         out_specs=P(None, "node_chunk"))
     return jax.jit(fn)
 
@@ -537,8 +556,8 @@ def decsvm_fit_chunked(X: Array, y: Array, W, cfg: ADMMConfig,
     sanitize.reject_unsupported(cfg, "decsvm_fit_chunked")
     mesh = _chunk_mesh(X, mesh)
     m, _, p = X.shape
-    ops, offsets, m_pad = _chunk_prep(X, y, W, mesh)
-    fitted = build_chunked_admm(m_pad, p, cfg, mesh, offsets, tol=tol,
+    ops, halos, m_pad = _chunk_prep(X, y, W, mesh)
+    fitted = build_chunked_admm(m_pad, p, cfg, mesh, halos, tol=tol,
                                 stop_rule=stop_rule,
                                 check_every=check_every)
     B, t = fitted(ops["X"], ops["y"], ops["W_diag"], ops["W_off"],
@@ -561,9 +580,9 @@ def decsvm_path_chunked(X: Array, y: Array, W, lams, cfg: ADMMConfig,
     mesh = _chunk_mesh(X, mesh)
     m, _, p = X.shape
     lams = jnp.asarray(lams, jnp.float32)
-    ops, offsets, m_pad = _chunk_prep(X, y, W, mesh)
+    ops, halos, m_pad = _chunk_prep(X, y, W, mesh)
     fitted = build_chunked_path(m_pad, p, int(lams.shape[0]), cfg, mesh,
-                                offsets)
+                                halos)
     path = fitted(ops["X"], ops["y"], ops["W_diag"], ops["W_off"],
                   ops["deg"], lams, _lamw(lam_weights, p, jnp.float32))
     return _replicated(mesh, path)[:, :m]
@@ -585,7 +604,7 @@ def build_mesh_path(m: int, p: int, C: int, cfg: ADMMConfig, mesh: Mesh,
                     schedule: str = "gather", mode: str = "batched",
                     tol: float = 1e-6, stop_rule: str = "kkt",
                     with_masks: bool = False, check_every: int = 4,
-                    handoff: bool = True, offsets=(),
+                    handoff: bool = True, halos=(),
                     m_real: Optional[int] = None):
     """Build the 2-D (node, lam) shard_map program.  Cached on all
     arguments (jit caches by function identity — a fresh closure per call
@@ -625,9 +644,9 @@ def build_mesh_path(m: int, p: int, C: int, cfg: ADMMConfig, mesh: Mesh,
     ``schedule="block"`` runs the chunked node-megabatch layout: the
     node mesh axis is "node_chunk", m is the *padded* node count, the W
     operand is the ``(W_diag, W_off, node_mask)`` triple from
-    ``_chunk_prep``-style block operands (``offsets`` holds the kept
-    block diagonals), and ``m_real`` (< m when padded) corrects every
-    scoring mean for the all-zero ghost rows.
+    ``_chunk_prep``-style block operands (``halos`` holds the kept
+    block diagonals and their halo rows), and ``m_real`` (< m when
+    padded) corrects every scoring mean for the all-zero ghost rows.
     """
     if mode not in ("warm", "batched"):
         raise ValueError(f"mode {mode!r} not in ('warm', 'batched')")
@@ -643,7 +662,7 @@ def build_mesh_path(m: int, p: int, C: int, cfg: ADMMConfig, mesh: Mesh,
     def prog(Xl, yl, Wop, degl, cell_lams, cell_rho, lamw, cell_masks=None):
         if schedule == "block":
             Wd, Woff, nmask = Wop
-            nbr = _block_neighbor_sum_fn(nax, nn, Wd, Woff, offsets)
+            nbr = _block_neighbor_sum_fn(nax, nn, Wd, Woff, halos)
         else:
             nmask = None
             nbr = _neighbor_sum_fn(schedule, nn, Wop)
@@ -797,7 +816,7 @@ def build_mesh_path(m: int, p: int, C: int, cfg: ADMMConfig, mesh: Mesh,
         scores = jnp.stack([bic, val_hinge], axis=-1)        # (C_local, 2)
         return path, scores, iters
 
-    wspec = ((P(nax), P(None, nax), P(nax)) if schedule == "block"
+    wspec = ((P(nax), P(nax), P(nax)) if schedule == "block"
              else P(nax))
     base_specs = (P(nax), P(nax), wspec, P(nax),
                   P("lam"), P("lam", nax), P())
@@ -862,7 +881,7 @@ def decsvm_path_mesh(X: Array, y: Array, W: np.ndarray, lams,
     if chunked:
         top = _as_topology(W)
         assert top.m == m, (top.m, m)
-        W_diag, offsets, W_off = top.chunk_operands(nn)
+        W_diag, halos, W_off = top.chunk_operands(nn)
         m_work = W_diag.shape[0]
         pad = m_work - m
         X = jnp.pad(jnp.asarray(X, jnp.float32),
@@ -876,7 +895,7 @@ def decsvm_path_mesh(X: Array, y: Array, W: np.ndarray, lams,
     else:
         if schedule == "ring":
             _assert_ring(W)
-        offsets, m_work = (), m
+        halos, m_work = (), m
         row_valid = np.ones((m,), np.float32)
 
     rho_full = solver.compute_rho(X, cfg.h, cfg.kernel, cfg.rho_safety,
@@ -906,8 +925,7 @@ def decsvm_path_mesh(X: Array, y: Array, W: np.ndarray, lams,
     node_s = NamedSharding(mesh, P(nax))
     if chunked:
         Wop = (jax.device_put(jnp.asarray(W_diag), node_s),
-               jax.device_put(jnp.asarray(W_off),
-                              NamedSharding(mesh, P(None, nax))),
+               jax.device_put(jnp.asarray(W_off), node_s),
                jax.device_put(jnp.asarray(nmask_np), node_s))
         deg = jax.device_put(jnp.asarray(deg_np), node_s)
     else:
@@ -931,7 +949,7 @@ def decsvm_path_mesh(X: Array, y: Array, W: np.ndarray, lams,
     fitted = build_mesh_path(m_work, p, C, cfg, mesh, schedule, mode, tol,
                              stop_rule, with_masks=cell_masks is not None,
                              check_every=check_every, handoff=handoff,
-                             offsets=offsets, m_real=m)
+                             halos=halos, m_real=m)
     path_cells, scores, iters = (_replicated(mesh, a)
                                  for a in fitted(*operands))
 

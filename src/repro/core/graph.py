@@ -198,17 +198,23 @@ class BlockTopology:
     def chunk_operands(self, n_chunks: int):
         """Block operands for the chunked neighbour sum.
 
-        Returns ``(W_diag, offsets, W_off)`` for ``mc``-row chunks
+        Returns ``(W_diag, halos, W_off)`` for ``mc``-row chunks
         (``m_pad = mc * n_chunks`` rows total, tail padded with zeros):
 
         - ``W_diag``: (m_pad, mc) — row i holds W[i, own-chunk columns];
           the per-device diagonal block, applied as a local dense dot.
-        - ``offsets``: sorted tuple of ring shifts k in [1, n_chunks)
-          with at least one nonzero block (d, (d+k) % n_chunks) — the
-          statically-kept cross-chunk block diagonals.
-        - ``W_off``: (len(offsets), m_pad, mc) — entry [o, i] holds
-          W[i, columns of chunk (chunk(i)+offsets[o]) % n_chunks],
-          applied after rotating B by ``offsets[o]`` chunks.
+        - ``halos``: sorted tuple of ``(k, rows)``, one per ring shift k
+          in [1, n_chunks) with at least one nonzero block
+          (d, (d+k) % n_chunks) — the statically-kept cross-chunk block
+          diagonals.  ``rows`` (sorted) are the rows of the source chunk
+          that any device's block (d, d+k) touches: the union over d,
+          since every device sends the same shape.  Only these rows of B
+          cross chips.
+        - ``W_off``: (m_pad, R), R the halos' rows together — row i
+          holds W[i, the halo rows of the source chunks], the halos'
+          columns side by side in the order of ``halos``: for
+          halos[o] = (k, rows), column c of its group is node rows[c] of
+          chunk (chunk(i) + k) % n_chunks.
         """
         mc = -(-self.m // n_chunks)
         m_pad = mc * n_chunks
@@ -217,12 +223,17 @@ class BlockTopology:
         W_diag = np.zeros((m_pad, mc), dtype=np.float32)
         loc = k == 0
         W_diag[I[loc], J[loc] % mc] = 1.0
-        offsets = tuple(int(o) for o in sorted(np.unique(k[~loc])))
-        W_off = np.zeros((len(offsets), m_pad, mc), dtype=np.float32)
-        for o, shift in enumerate(offsets):
+        halos = tuple(
+            (int(shift), tuple(int(r) for r in np.unique(J[k == shift] % mc)))
+            for shift in np.unique(k[~loc]))
+        W_off = np.zeros((m_pad, sum(len(rows) for _, rows in halos)),
+                         dtype=np.float32)
+        base = 0
+        for shift, rows in halos:
             sel = k == shift
-            W_off[o, I[sel], J[sel] % mc] = 1.0
-        return W_diag, offsets, W_off
+            W_off[I[sel], base + np.searchsorted(rows, J[sel] % mc)] = 1.0
+            base += len(rows)
+        return W_diag, halos, W_off
 
 
 def ring_of_cliques(cliques: int, size: int) -> BlockTopology:

@@ -493,7 +493,7 @@ def run_fixed_cached(step, prob: Problem, lam, lam_weights=None, *,
     neighbour exchange instead of two.  Bit-identical to ``run_fixed``
     (the cached value is exactly what the second exchange would
     recompute); the win is the halved collective count in the
-    sharded/chunked engines, where an exchange is a ``ppermute`` chain.
+    sharded/chunked engines, where an exchange is a set of ``ppermute``s.
     Falls back to ``run_fixed`` when ``step`` carries no ``cached_round``
     (e.g. the sanitizer-wrapped step)."""
     cached = getattr(step, "cached_round", None)

@@ -16,7 +16,7 @@ that path as the ``tf_op`` of each device operation.
                                  iteration over each node's X
     BIC        decsvm.bic        ``path.score_path``: path scoring
     EXCHANGE   decsvm.exchange   ``decentral``'s neighbour sums across
-                                 devices: the ``ppermute`` chain and the
+                                 devices: the halo ``ppermute``s and the
                                  off-diagonal block dots of the chunked
                                  engine, the gather or ring of the
                                  sharded one (inside ``decsvm.round``)
@@ -42,6 +42,12 @@ profiler session is active:
     SPAN_BIC_TABLE     decsvm:bic_table     its table and copy of best_B:
                                             the host's wait for the path
 
+``exchange`` counts the rows of B that the chunked engine's cross-chip
+exchange carries (``decentral._block_neighbor_sum_fn``), against the rows
+that whole chunks would carry, summed over every kept block diagonal of
+every neighbour sum the process has built.  It counts when a program is
+traced, never when it runs; read by ``bench/metrics/halo_share.p2p``.
+
 ``compiles`` is the process's one ``jax.monitoring`` listener, installed
 on import.  It keeps one monotone count, ``backend``: every backend
 compile.  JAX records that event around the persistent-cache lookup, so a
@@ -53,6 +59,7 @@ from __future__ import annotations
 import bisect
 import threading
 import time
+from typing import Optional
 
 import jax.monitoring
 
@@ -94,5 +101,31 @@ class CompileCounter:
                     - bisect.bisect_left(self._backend_at, t0))
 
 
+class ExchangeCounter:
+    """Rows sent by the cross-chip halo exchanges built so far, and the
+    rows that whole chunks would have sent in their place."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self.sent_rows = 0
+        self.chunk_rows = 0
+
+    def record(self, sent: int, chunk: int) -> None:
+        """One kept block diagonal: ``sent`` halo rows of a ``chunk``-row
+        chunk cross the chips."""
+        with self._lock:
+            self.sent_rows += sent
+            self.chunk_rows += chunk
+
+    def share(self) -> Optional[float]:
+        """Rows sent as a percentage of whole chunks' rows; None before
+        any exchange was built."""
+        with self._lock:
+            if not self.chunk_rows:
+                return None
+            return 100.0 * self.sent_rows / self.chunk_rows
+
+
+exchange = ExchangeCounter()
 compiles = CompileCounter()
 jax.monitoring.register_event_duration_secs_listener(compiles._on_duration)

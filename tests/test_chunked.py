@@ -64,25 +64,31 @@ def test_generators_connected_symmetric_no_self_loops(make, kwargs, m):
 
 
 def test_chunk_operands_reconstruct_dense_adjacency():
-    """W_diag + the kept off-diagonal block diagonals ARE the adjacency:
-    scatter them back into an (m_pad, m_pad) matrix and compare."""
+    """W_diag + the kept off-diagonal blocks, each in its halo columns of
+    W_off, ARE the adjacency: scatter them back into an (m_pad, m_pad)
+    matrix and compare."""
     top = graph.ring_of_cliques(cliques=3, size=5)   # m=15, uneven over 4
     n_chunks = 4
-    W_diag, offsets, W_off = top.chunk_operands(n_chunks)
+    W_diag, halos, W_off = top.chunk_operands(n_chunks)
     mc = -(-top.m // n_chunks)
     m_pad = mc * n_chunks
     assert W_diag.shape == (m_pad, mc)
-    assert W_off.shape == (len(offsets), m_pad, mc)
+    assert W_off.shape == (m_pad, sum(len(rows) for _, rows in halos))
     dense = np.zeros((m_pad, m_pad), np.float32)
     for c in range(n_chunks):
         rows = slice(c * mc, (c + 1) * mc)
         dense[rows, rows] = W_diag[rows]
-        for j, k in enumerate(offsets):
+        base = 0
+        for k, halo in halos:
+            assert list(halo) == sorted(set(halo))
             tgt = (c + k) % n_chunks
-            dense[rows, tgt * mc:(tgt + 1) * mc] = W_off[j, rows]
+            dense[rows, [tgt * mc + r for r in halo]] = \
+                W_off[rows, base:base + len(halo)]
+            base += len(halo)
     np.testing.assert_array_equal(dense[:top.m, :top.m], top.to_dense())
     assert np.all(dense[top.m:] == 0) and np.all(dense[:, top.m:] == 0)
     # block_mask agrees with the offsets actually kept
+    offsets = [k for k, _ in halos]
     mask = top.block_mask(n_chunks)
     for c in range(n_chunks):
         for t in range(n_chunks):
@@ -97,8 +103,152 @@ def test_block_mask_skips_absent_ring_offsets():
     """A ring keeps only the +-1 block offsets at mc=1 — distant blocks
     are statically absent from the chunked operands."""
     top = graph.BlockTopology.from_dense(graph.ring(8))
-    _, offsets, _ = top.chunk_operands(8)
-    assert set(offsets) == {1, 7}
+    _, halos, _ = top.chunk_operands(8)
+    assert {k for k, _ in halos} == {1, 7}
+
+
+def test_lattice_halo_is_two_rows_per_offset():
+    """The p2p cell's graph, k_regular(1024, 4) over 4 chunks of 256:
+    block (d, d+1) reads rows 0-1 of chunk d+1, block (d, d+3) rows
+    254-255 of chunk d-1, so each offset sends 2 rows."""
+    _, halos, W_off = graph.k_regular(1024, 4).chunk_operands(4)
+    assert halos == ((1, (0, 1)), (3, (254, 255)))
+    assert W_off.shape == (1024, 4)
+
+
+def test_whole_chunk_halo_where_every_row_is_read():
+    """A dense Erdos-Renyi graph reads every row of every other chunk:
+    each halo is the whole chunk, and W_off holds the full off-diagonal
+    blocks."""
+    top = graph.BlockTopology.from_dense(graph.erdos_renyi(16, 0.9, seed=0))
+    _, halos, W_off = top.chunk_operands(4)
+    assert [k for k, _ in halos] == [1, 2, 3]
+    assert all(rows == (0, 1, 2, 3) for _, rows in halos)
+    assert W_off.shape == (16, 12)
+
+
+def test_exchange_counter_reads_the_halo_share(monkeypatch):
+    """``trace.exchange`` counts the rows each kept offset sends as the
+    neighbour sum is built: 2 of 256 per offset on the p2p lattice
+    (0.78%), all of them where the halo is the whole chunk (100%)."""
+    from repro.core import decentral, trace
+    for top, chunks, share in (
+            (graph.k_regular(1024, 4), 4, 100.0 * 4 / 512),
+            (graph.BlockTopology.from_dense(
+                graph.erdos_renyi(16, 0.9, seed=0)), 4, 100.0)):
+        counter = trace.ExchangeCounter()
+        monkeypatch.setattr(trace, "exchange", counter)
+        assert counter.share() is None
+        W_diag, halos, W_off = top.chunk_operands(chunks)
+        mc = W_diag.shape[1]
+        decentral._block_neighbor_sum_fn("node_chunk", chunks, W_diag[:mc],
+                                         W_off[:mc], halos)
+        assert counter.share() == pytest.approx(share)
+
+
+# -- halo exchange: the neighbour sum on 4 devices --------------------------
+
+HALO_CASES = {
+    # (topology, chunks): lattice halos of 2 rows, contiguous; on two
+    # chunks offset 1 reads both ends of a chunk (a gather); cliques
+    # bridged across chunks; a dense Erdos-Renyi graph whose halo is the
+    # whole chunk; m = 13 padding the tail chunk with ghost rows
+    "lattice": "graph.k_regular(32, 4), 4",
+    "lattice_two_chunks": "graph.k_regular(32, 4), 2",
+    "ring_of_cliques": "graph.ring_of_cliques(4, 5), 4",
+    "erdos_renyi_whole_chunk": (
+        "graph.BlockTopology.from_dense(graph.erdos_renyi(16, 0.9, seed=0)), 4"),
+    "padded_tail": "graph.k_regular(13, 4), 4",
+}
+
+HALO_SCRIPT = """
+import numpy as np, jax
+from jax.sharding import Mesh, PartitionSpec as P
+from repro.core import decentral, graph
+for name, (top, chunks) in {cases}.items():
+    W_diag, halos, W_off = top.chunk_operands(chunks)
+    m_pad = W_diag.shape[0]
+    B = np.zeros((m_pad, 7), np.float32)
+    B[:top.m] = np.random.default_rng(0).normal(size=(top.m, 7))
+    mesh = Mesh(np.array(jax.devices()[:chunks]), ("node_chunk",))
+    body = lambda Bl, Wd, Woff: decentral._block_neighbor_sum_fn(
+        "node_chunk", chunks, Wd, Woff, halos)(Bl)
+    S = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(P("node_chunk"),) * 3,
+        out_specs=P("node_chunk")))(B, W_diag, W_off)
+    S = np.asarray(S)
+    ref = top.to_dense().astype(np.float64) @ B[:top.m]
+    err = np.abs(S[:top.m] - ref).max() / np.abs(ref).max()
+    ghost = np.abs(S[top.m:]).max() if m_pad > top.m else 0.0
+    rows = max(len(r) for _, r in halos)
+    print("HALO", name, err, ghost, rows, m_pad // chunks)
+"""
+
+
+@pytest.fixture(scope="module")
+def halo_sums():
+    cases = "{" + ", ".join(f"{k!r}: ({v})" for k, v in HALO_CASES.items()) + "}"
+    out = run_py(HALO_SCRIPT.format(cases=cases), devices=4)
+    return {f[1]: tuple(float(x) for x in f[2:])
+            for f in (l.split() for l in out.splitlines())
+            if f and f[0] == "HALO"}
+
+
+@pytest.mark.parametrize("case", list(HALO_CASES))
+def test_halo_neighbor_sum_equals_dense(case, halo_sums):
+    """The halo exchange's (W B)_l on 4 CPU devices equals the dense
+    W @ B to fp32 rounding, ghost rows stay zero, and only the whole-chunk
+    case sends whole chunks."""
+    err, ghost, rows, mc = halo_sums[case]
+    assert err <= 1e-6 and ghost == 0.0, (err, ghost)
+    assert (rows == mc) == (case == "erdos_renyi_whole_chunk"), (rows, mc)
+
+
+def test_halo_fit_equals_whole_chunk_exchange():
+    """A tol fit of a 16-node lattice on 4 devices gives the same B, bit
+    for bit, and the same rounds, whether the exchange sends each
+    offset's halo rows or, as the full-block formulas did, whole chunks
+    into (mc, mc) blocks."""
+    out = run_py("""
+        import numpy as np, jax.numpy as jnp
+        from repro.core import ADMMConfig, decentral, graph
+        rng = np.random.default_rng(11)
+        m, n, p = 16, 10, 6
+        X = rng.normal(size=(m, n, p)).astype(np.float32)
+        b = np.zeros(p, np.float32); b[:2] = 1.0
+        y = np.sign(X @ b + 0.1*rng.normal(size=(m, n))).astype(np.float32)
+        cfg = ADMMConfig(lam=0.1, max_iter=120)
+        mesh = decentral.make_node_chunk_mesh(4)
+
+        def fit(top):
+            return decentral.decsvm_fit_chunked(
+                jnp.asarray(X), jnp.asarray(y), top, cfg, mesh=mesh,
+                tol=1e-6)
+
+        halo = graph.k_regular(m, 4)
+        assert [len(r) for _, r in halo.chunk_operands(4)[1]] == [2, 2]
+
+        class WholeChunks(graph.BlockTopology):
+            def chunk_operands(self, n_chunks):
+                W_diag, halos, W_off = super().chunk_operands(n_chunks)
+                mc = W_diag.shape[1]
+                full = np.zeros((W_off.shape[0], mc * len(halos)),
+                                np.float32)
+                base = 0
+                for j, (_, rows) in enumerate(halos):
+                    full[:, [j * mc + r for r in rows]] = \
+                        W_off[:, base:base + len(rows)]
+                    base += len(rows)
+                return (W_diag, tuple((k, tuple(range(mc)))
+                                      for k, _ in halos), full)
+
+        B1, t1 = fit(halo)
+        B2, t2 = fit(WholeChunks(halo.neighbors))
+        print("FIT", int(t1), int(t2), np.abs(np.asarray(B1)).max())
+        assert int(t1) == int(t2)
+        np.testing.assert_array_equal(np.asarray(B1), np.asarray(B2))
+    """, devices=4)
+    assert "FIT" in out
 
 
 # -- 8-device parity: chunked vs dense --------------------------------------

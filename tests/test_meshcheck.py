@@ -232,16 +232,18 @@ def test_partial_injection_is_legal():
 
 
 def test_block_delta_shift_chain_is_bijective_and_clean():
-    """decentral._block_neighbor_sum_fn's delta-shift perms, verified on
+    """decentral._block_neighbor_sum_fn's halo shift perms, verified on
     the real helper (full-cycle shifts are bijections by construction)."""
     from repro.core.decentral import _block_neighbor_sum_fn
     mesh = _mesh("node_chunk")
     Wd = jnp.zeros((4, 4), jnp.float32)
-    Woff = jnp.zeros((2, 4, 4), jnp.float32)
+    Woff = jnp.zeros((4, 6), jnp.float32)
 
     def f(B):
         def inner(Bl):
-            nbr = _block_neighbor_sum_fn("node_chunk", 1, Wd, Woff, (1, 3))
+            nbr = _block_neighbor_sum_fn(
+                "node_chunk", 1, Wd, Woff,
+                ((1, (0, 1, 2, 3)), (3, (2, 3))))
             return nbr(Bl)
         return _smap(inner, mesh, P("node_chunk"), P("node_chunk"))(B)
 

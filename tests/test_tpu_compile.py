@@ -199,7 +199,8 @@ def test_p2p_fit_program_compiles_for_four_chips(topo, mosaic, monkeypatch):
     kernel, the neighbour exchange is collective-permutes, and beside the
     argument the program holds at most one X-sized temporary (a relayout
     of the chip's X, where the compiler plans X node-minor), so that with
-    the pool's second data set it fits a chip's 16 GB."""
+    the pool's second data set it fits a chip's 16 GB.  The exchange sends
+    each offset's 2 halo rows of B, f32[2,20001], never a whole chunk."""
     import re
 
     import numpy as np
@@ -211,9 +212,9 @@ def test_p2p_fit_program_compiles_for_four_chips(topo, mosaic, monkeypatch):
     monkeypatch.setattr(solver, "_platform", lambda: "tpu")
     jax.clear_caches()
     mesh = Mesh(np.array(topo.devices), ("node_chunk",))
-    W_diag, offsets, W_off = graph.k_regular(m, 4).chunk_operands(4)
+    W_diag, halos, W_off = graph.k_regular(m, 4).chunk_operands(4)
     cfg = ADMMConfig(lam=0.0035, h=0.14, max_iter=300)
-    fitted = decentral.build_chunked_admm(m, p, cfg, mesh, offsets,
+    fitted = decentral.build_chunked_admm(m, p, cfg, mesh, halos,
                                           tol=1e-3, check_every=4)
     rows, rep = (NamedSharding(mesh, P("node_chunk")),
                  NamedSharding(mesh, P()))
@@ -221,14 +222,18 @@ def test_p2p_fit_program_compiles_for_four_chips(topo, mosaic, monkeypatch):
                                                       sharding=sharding)
     compiled = fitted.lower(
         s(rows, m, n, p), s(rows, m, n), s(rows, *W_diag.shape),
-        s(NamedSharding(mesh, P(None, "node_chunk")), *W_off.shape),
+        s(rows, *W_off.shape),
         s(rows, m), s(rep, p), s(rows, m)).compile()
     jax.clear_caches()
     text = compiled.as_text()
     kernels = re.findall(r"(decsvm_xpass\w*)\.\d+ = \S+ custom-call\(",
                          text)
     assert sorted(set(kernels)) == [xpass.ROWS_NAME] and len(kernels) == 4
-    assert "collective-permute" in text
+    sent = {shape for line in text.splitlines()
+            if re.search(r" collective-permute(-start)?\(", line)
+            for shape in re.findall(r"f32\[[\d,]+\]",
+                                    line.split(" collective-permute")[0])}
+    assert sent == {f"f32[2,{p}]"}, sent
     share = 4 * (m // 4) * n * p
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1.1 * share

@@ -146,7 +146,7 @@ def build_registry() -> Dict[str, Driver]:
                    (vals,), False),
         # chunked node-megabatch INSIDE the lambda mesh: warm mode on the
         # (node_chunk, lam) mesh at odd m, so the trace carries the
-        # block-sparse delta-shift ppermute chain, ghost padding, AND the
+        # block-sparse halo ppermutes, ghost padding, AND the
         # two-axis pmax-agreed stop (the PR 9 deadlock surface)
         "mesh-2d-block": (lambda Xb, yb: decentral.decsvm_path_mesh(
             Xb, yb, Wblk, lams_host, pz, schedule="block", mode="warm",

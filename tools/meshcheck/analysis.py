@@ -42,7 +42,7 @@ Checks:
   injections are legal and intentional — jax zero-fills unaddressed
   destinations, which the mesh warm hand-off relies on — so this is an
   injectivity check, not a full-bijection check.  The block-sparse
-  delta-shift chains of `decentral._block_neighbor_sum_fn` are full
+  halo shifts of `decentral._block_neighbor_sum_fn` are full
   bijections and pass trivially.
 - **AXIS_UNBOUND**: every collective axis name must be bound by an
   enclosing `shard_map` mesh at the collective's depth.
