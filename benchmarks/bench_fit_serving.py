@@ -9,7 +9,7 @@
               scoring, and each argmin in a single vmapped execution
 
 Emits ``BENCH_fit_serving.json`` at the repo root with the same field
-conventions as ``BENCH_mesh_path.json`` (end-to-end = compile + run,
+conventions as ``BENCH_lambda_path.json`` (end-to-end = compile + run,
 steady-state = post-compile min over reps).  Headline criteria: batched
 steady-state >= 3x serial on the 16-request queue, with batched-vs-serial
 max abs deviation <= 1e-5.

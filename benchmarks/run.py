@@ -7,7 +7,7 @@ import time
 
 # Named single benches runnable via ``--bench`` (JSON emitters included).
 BENCHES = ("megakernel", "kernels", "iterations", "sample_size", "topology",
-           "flips", "realworld", "theory", "mesh_path", "lambda_path",
+           "flips", "realworld", "theory", "lambda_path",
            "fit_serving", "node_virtual")
 
 

@@ -21,7 +21,7 @@ One chip (the default):
      made on the device), jnp and pallas, 50 rounds each.
 
 Four chips (``--chips 4``): ``decsvm_fit_chunked`` and
-``decsvm_path_mesh(schedule="block", mode="warm")`` on a 4-device mesh at
+``decsvm_path_mesh(mode="warm")`` on a 4-device mesh at
 m=64, n=500, p=2000, each against the dense single-device fit and path.
 
 Every phase prints one line: name, shape, backend, wall seconds of a
@@ -299,7 +299,7 @@ def phase_mesh(m=64, n=500, p=2000, fit_rounds=100, tol=1e-4):
 
     pcfg = dataclasses.replace(cfg, lam=0.0, max_iter=300)
     path = lambda: decentral.decsvm_path_mesh(
-        X, y, Wn, lams, pcfg, schedule="block", mode="warm", tol=tol)
+        X, y, Wn, lams, pcfg, mode="warm", tol=tol)
     path()
     res, wall = timed(path)
     devs = sorted(d.id for d in res.path.sharding.device_set)

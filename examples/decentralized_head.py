@@ -15,7 +15,7 @@ import numpy as np
 
 import repro.configs as configs
 from repro.core import ADMMConfig, metrics
-from repro.core.decentral import decsvm_fit_sharded, make_node_mesh
+from repro.core.decentral import decsvm_fit_chunked, make_node_chunk_mesh
 from repro.core.graph import ring
 from repro.models import model
 from repro.optim.decsvm_head import extract_features
@@ -43,15 +43,12 @@ def main():
     X = np.concatenate([np.ones((m, n, 1), np.float32),
                         ((feats - mu) / sd).astype(np.float32)], axis=-1)
 
-    W = ring(m)   # ring graph == TPU-ICI-native one-hop schedule
+    W = ring(m)   # ring graph: one boundary row each way per round
     acfg = ADMMConfig(lam=0.02, h=0.3, max_iter=400)
-    mesh = make_node_mesh()
-    ndev = mesh.shape["node"]
-    schedule = "ring" if (ndev == m) else "gather"
-    print(f"devices={ndev} nodes={m} schedule={schedule}")
-    B = np.asarray(decsvm_fit_sharded(
-        jnp.asarray(X), jnp.asarray(yl), W, acfg, mesh=mesh,
-        schedule=schedule))
+    mesh = make_node_chunk_mesh()
+    print(f"devices={mesh.shape['node_chunk']} nodes={m}")
+    B = np.asarray(decsvm_fit_chunked(
+        jnp.asarray(X), jnp.asarray(yl), W, acfg, mesh=mesh))
 
     margins = np.einsum("mnp,mp->mn", X, B)
     acc = metrics.margin_accuracy(margins, yl)

@@ -3,35 +3,30 @@ unified Algorithm-1 step of ``repro.core.solver``).
 
 Semantics are identical to ``repro.core.admm`` *by construction*: the same
 ``solver.make_step`` runs here with the neighbour sum swapped for a real
-collective.  Each device owns m/ndev nodes; two exchange schedules:
+collective.  There is one layout and one exchange:
 
-  - "gather" (any graph): all_gather the (m_local, p) primal block then apply
-    the local adjacency rows.  Correct for arbitrary W; collective volume
-    O(m p) per round.
-  - "ring" (ring graphs, device-aligned): lax.ppermute of only the two shard
-    boundary rows; volume O(p) per round.  This is the beyond-paper,
-    ICI-native schedule — on a TPU torus a ring of nodes maps onto physical
-    one-hop links, exactly matching the paper's communication model.
-  - "block" (any graph, any m): the chunked node-megabatch layout — each
-    device owns a contiguous chunk of ceil(m/ndev) nodes on the
-    "node_chunk" axis, the W B neighbour sum is computed block-wise
-    (diagonal blocks as local dense dots, cross-chunk block diagonals
-    fed by a ppermute of only the rows they read, all-zero block
-    diagonals skipped statically from the topology's block-sparsity
-    pattern), and m that doesn't
-    divide the chunk count pads with exact-no-op ghost nodes.  This is
-    the m >> devices path: m = 1024 networks run on 8 devices.
+  - Layout: each device owns a contiguous chunk of ceil(m/ndev) nodes on
+    the "node_chunk" mesh axis.  m that doesn't divide the chunk count
+    pads with all-zero ghost nodes, exact no-ops of the update, so any m
+    runs on any device count (m >> devices: 1,024 nodes on 4 chips).
+  - Exchange: the W B neighbour sum is computed block-wise
+    (``_block_neighbor_sum_fn``).  The diagonal block is a local dense
+    dot; for each cross-chunk block diagonal the topology keeps, a
+    ppermute carries only the rows of B its blocks read
+    (``graph.BlockTopology.chunk_operands``).  The topology decides the
+    traffic: a ring at one node a device sends one boundary row each
+    way, a dense graph sends whole chunks (the rows an all_gather moves),
+    and all-zero block diagonals send nothing.
 
-Three engines, in increasing parallelism:
+Engines:
 
-  - ``decsvm_fit_sharded``: one fit, node state sharded over the "node" axis.
-  - ``decsvm_path_sharded``: the lambda grid vmapped on top of the node
-    sharding — one program fits all L grid points, but every device carries
-    all L (lambda multiplies per-device memory and compute).
-  - ``decsvm_path_mesh``: the true 2-D (node, lam) device mesh — grid
-    points live on their own mesh axis, with warm-start continuation and
-    fused modified-BIC / k-fold-CV scoring inside the same shard_map
-    program.  Per-device cost scales with L / (lam-axis size).
+  - ``decsvm_fit_chunked``: one fit, fixed rounds or the KKT stop.
+  - ``decsvm_path_chunked``: the lambda grid vmapped on top of the node
+    chunks — every device carries all L grid points.
+  - ``decsvm_path_mesh``: the 2-D (node_chunk, lam) mesh — grid points
+    live on their own mesh axis, with warm-start continuation and fused
+    modified-BIC / k-fold-CV scoring inside the same shard_map program.
+    Per-device cost scales with L / (lam-axis size).
 
 All engines accept ``lam_weights`` (per-coordinate l1 multipliers), so the
 LLA stage-2 re-fit of ``repro.core.penalties`` runs sharded.
@@ -39,6 +34,7 @@ LLA stage-2 re-fit of ``repro.core.penalties`` runs sharded.
 from __future__ import annotations
 
 import functools
+import math
 import weakref
 from typing import Optional
 
@@ -65,56 +61,14 @@ def _replicated(mesh: Mesh, x: Array) -> Array:
     mesh with explicit axis types (``jax.make_mesh``'s default) slicing or
     indexing such an axis outside the program -- the ghost-row trim, the
     selected path row -- is a sharding type error, so every engine hands its
-    result back replicated, as the sharded engines always documented."""
+    result back replicated."""
     return jax.device_put(x, NamedSharding(mesh, P()))
-
-
-def make_node_mesh(n_devices: Optional[int] = None) -> Mesh:
-    n = n_devices or len(jax.devices())
-    return jax.make_mesh((n,), ("node",))
 
 
 def make_node_chunk_mesh(n_devices: Optional[int] = None) -> Mesh:
     """1-D ("node_chunk",) mesh for the chunked engines (m >> devices)."""
     from repro.launch.mesh import make_node_chunk_mesh as _make
     return _make(n_devices)
-
-
-def _neighbor_sum_fn(schedule: str, ndev: int, Wl: Optional[Array]):
-    """Neighbour-sum backend for ``solver.make_step`` inside shard_map.
-
-    ``gather``: (W B)_l via all_gather + the local adjacency rows Wl.
-    ``ring``: left+right neighbours via jnp.roll locally, shard boundaries
-    fixed with point-to-point permutes (ndev is static: JAX<0.7 has no
-    jax.lax.axis_size to recover it inside the mapped function).
-    """
-    if schedule == "ring":
-
-        def ring_sum(Bl):
-            with jax.named_scope(trace.EXCHANGE):
-                up = jnp.roll(Bl, -1, axis=0)    # row i <- row i+1 (local)
-                dn = jnp.roll(Bl, 1, axis=0)     # row i <- row i-1 (local)
-                fwd = [(d, (d + 1) % ndev) for d in range(ndev)]
-                bwd = [(d, (d - 1) % ndev) for d in range(ndev)]
-                first_of_next = jax.lax.ppermute(Bl[:1], "node", bwd)
-                last_of_prev = jax.lax.ppermute(Bl[-1:], "node", fwd)
-                up = up.at[-1:].set(first_of_next)
-                dn = dn.at[:1].set(last_of_prev)
-                return up + dn
-
-        return ring_sum
-
-    def gather_sum(Bl):
-        with jax.named_scope(trace.EXCHANGE):
-            B_all = jax.lax.all_gather(Bl, "node", axis=0, tiled=True)
-            return solver.mm(Wl, B_all)                       # (m_l, p)
-
-    return gather_sum
-
-
-def _local_problem(Xl, yl, degl, rhol, cfg, mask=None) -> solver.Problem:
-    omega = 1.0 / (2.0 * cfg.tau * degl + rhol + cfg.lam0)
-    return solver.Problem(Xl, yl, degl, rhol, omega, mask)
 
 
 def _halo_rows(Bl: Array, rows) -> Array:
@@ -171,7 +125,7 @@ def _padded_omega(degl, rhol, cfg):
     """omega = 1/(2 tau deg + rho + lam0), but 0 on all-zero padded ghost
     rows (deg = rho = 0), where the dense formula divides by lam0 — inf
     omega turns the ghost rows' 0 * inf update into NaN.  Real rows have
-    denom > 0, so this is bit-identical to ``_local_problem`` there."""
+    denom > 0, so this is bit-identical to the dense formula there."""
     denom = 2.0 * cfg.tau * degl + rhol + cfg.lam0
     safe = jnp.where(denom > 0, denom, 1.0)
     return jnp.where(denom > 0, 1.0 / safe, jnp.zeros_like(denom))
@@ -194,84 +148,6 @@ def _zero_state(shape, dtype, axes) -> solver.SolverState:
     return solver.SolverState(B, Pd, jnp.zeros((), jnp.int32), prog)
 
 
-@functools.lru_cache(maxsize=64)
-def build_sharded_admm(m: int, p: int, cfg: ADMMConfig, mesh: Mesh,
-                       schedule: str = "gather"):
-    """Build the jitted sharded ADMM loop (lowerable against structs).
-
-    Cached on (m, p, cfg, mesh, schedule) — ``jax.jit`` caches by function
-    identity, so without this every driver call would rebuild the closure
-    and retrace/recompile from scratch.
-
-    Returns a jitted fn (X (m,n,p), y (m,n), W (m,m), deg (m,), rho (m,),
-    lam_weights (p,)) -> B (m, p), node state sharded over "node".
-    """
-    ndev = mesh.shape["node"]
-    assert m % ndev == 0, f"m={m} must be divisible by #devices={ndev}"
-
-    def sharded_loop(Xl, yl, Wl, degl, rhol, lamw):
-        step = solver.make_step(cfg, _neighbor_sum_fn(schedule, ndev, Wl))
-        prob = _local_problem(Xl, yl, degl, rhol, cfg)
-        state = _zero_state((Xl.shape[0], p), Xl.dtype, ("node",))
-        return solver.run_fixed(step, prob, cfg.lam, lamw,
-                                num_iters=cfg.max_iter, state=state).B
-
-    fn = jax.shard_map(
-        sharded_loop, mesh=mesh,
-        in_specs=(P("node"), P("node"), P("node"), P("node"), P("node"),
-                  P()),
-        out_specs=P("node"))
-    return jax.jit(fn)
-
-
-@functools.lru_cache(maxsize=64)
-def build_sharded_path(m: int, p: int, L: int, cfg: ADMMConfig, mesh: Mesh,
-                       schedule: str = "gather"):
-    """Sharded node x lambda engine: node state sharded over devices, the
-    lambda grid vmapped on top — one compiled program fits all L grid
-    points, each with the same collective schedule as the single fit.
-
-    Returns a jitted fn (X, y, W, deg, rho, lams (L,), lam_weights (p,))
-    -> path (L, m, p).
-    """
-    ndev = mesh.shape["node"]
-    assert m % ndev == 0, f"m={m} must be divisible by #devices={ndev}"
-
-    def sharded_loop(Xl, yl, Wl, degl, rhol, lams, lamw):
-        step = solver.make_step(cfg, _neighbor_sum_fn(schedule, ndev, Wl))
-        prob = _local_problem(Xl, yl, degl, rhol, cfg)
-        m_local = Xl.shape[0]
-
-        def fit_one(lam, B0, P0, prog0):
-            state = solver.SolverState(B0, P0, jnp.zeros((), jnp.int32),
-                                       prog0)
-            return solver.run_fixed(step, prob, lam, lamw,
-                                    num_iters=cfg.max_iter, state=state).B
-
-        sdt = jnp.promote_types(Xl.dtype, jnp.float32)
-        B0 = _pvary(jnp.zeros((L, m_local, p), sdt), ("node",))
-        P0 = _pvary(jnp.zeros((L, m_local, p), sdt), ("node",))
-        prog0 = _pvary(jnp.full((L,), jnp.inf, sdt), ("node",))
-        return jax.vmap(fit_one)(lams, B0, P0, prog0)
-
-    fn = jax.shard_map(
-        sharded_loop, mesh=mesh,
-        in_specs=(P("node"), P("node"), P("node"), P("node"), P("node"),
-                  P(), P()),
-        out_specs=P(None, "node"))
-    return jax.jit(fn)
-
-
-def _prep(X, W, cfg, schedule):
-    if schedule == "ring":
-        _assert_ring(W)
-    Wj = jnp.asarray(W, X.dtype)
-    deg = jnp.sum(Wj, axis=1)
-    rho = solver.compute_rho(X, cfg.h, cfg.kernel, cfg.rho_safety,
-                             backend=solver.resolve_backend(cfg))
-    return Wj, deg, rho
-
-
 def _lamw(lam_weights, p, dtype):
     return (jnp.ones((p,), dtype) if lam_weights is None
             else jnp.asarray(lam_weights, dtype))
@@ -286,66 +162,8 @@ def _fold_rhos(X, folds, h, kernel, safety):
         lambda mk: solver.compute_rho(X, h, kernel, safety, mask=mk))(folds)
 
 
-def decsvm_fit_sharded(X: Array, y: Array, W: np.ndarray, cfg: ADMMConfig,
-                       mesh: Optional[Mesh] = None,
-                       schedule: str = "gather",
-                       lam_weights: Optional[Array] = None) -> Array:
-    """Run Algorithm 1 with node state sharded across devices.
-
-    X: (m, n, p), y: (m, n), W: (m, m).  m must divide the node-axis size
-    — or pass ``schedule="block"`` to run the chunked node-megabatch
-    engine (``decsvm_fit_chunked``): any m, ceil(m/ndev) nodes per
-    device, block-sparse neighbour sum.
-    lam_weights: optional (p,) per-coordinate l1 multipliers (LLA stage 2).
-    Returns B: (m, p) (fully replicated on exit).
-    """
-    if schedule == "block":
-        return decsvm_fit_chunked(X, y, W, cfg, mesh=mesh,
-                                  lam_weights=lam_weights)
-    sanitize.reject_unsupported(cfg, "decsvm_fit_sharded")
-    mesh = mesh or make_node_mesh()
-    m, _, p = X.shape
-    Wj, deg, rho = _prep(X, W, cfg, schedule)
-    node_sharded = NamedSharding(mesh, P("node"))
-    X = jax.device_put(X.astype(solver.problem_dtype(cfg)), node_sharded)
-    y = jax.device_put(y, node_sharded)
-    fitted = build_sharded_admm(m, p, cfg, mesh, schedule)
-    return _replicated(mesh, fitted(X, y, Wj, deg, rho,
-                                    _lamw(lam_weights, p, jnp.float32)))
-
-
-def decsvm_path_sharded(X: Array, y: Array, W: np.ndarray, lams,
-                        cfg: ADMMConfig, mesh: Optional[Mesh] = None,
-                        schedule: str = "gather",
-                        lam_weights: Optional[Array] = None) -> Array:
-    """Run the whole lambda grid with node state sharded across devices.
-
-    X: (m, n, p), y: (m, n), W: (m, m), lams: (L,) decreasing grid.
-    Returns the path (L, m, p), replicated on exit; score it with
-    ``repro.core.path.score_path`` / select via the modified BIC.
-    cfg.lam is ignored (the grid supplies lambda).  Every device carries
-    all L grid points — see ``decsvm_path_mesh`` for the 2-D layout that
-    shards the grid too.  ``schedule="block"`` routes to the chunked
-    engine (``decsvm_path_chunked``): any m, nodes chunked per device.
-    """
-    if schedule == "block":
-        return decsvm_path_chunked(X, y, W, lams, cfg, mesh=mesh,
-                                   lam_weights=lam_weights)
-    sanitize.reject_unsupported(cfg, "decsvm_path_sharded")
-    mesh = mesh or make_node_mesh()
-    m, _, p = X.shape
-    lams = jnp.asarray(lams, jnp.float32)
-    Wj, deg, rho = _prep(X, W, cfg, schedule)
-    node_sharded = NamedSharding(mesh, P("node"))
-    X = jax.device_put(X.astype(solver.problem_dtype(cfg)), node_sharded)
-    y = jax.device_put(y, node_sharded)
-    fitted = build_sharded_path(m, p, int(lams.shape[0]), cfg, mesh, schedule)
-    return _replicated(mesh, fitted(X, y, Wj, deg, rho, lams,
-                                    _lamw(lam_weights, p, jnp.float32)))
-
-
 # --------------------------------------------------------------------------
-# Chunked node-megabatch engine (schedule="block"): m >> devices
+# Chunked node layout: fit and lambda grid
 # --------------------------------------------------------------------------
 
 
@@ -499,7 +317,7 @@ def build_chunked_admm(m_pad: int, p: int, cfg: ADMMConfig, mesh: Mesh,
 def build_chunked_path(m_pad: int, p: int, L: int, cfg: ADMMConfig,
                        mesh: Mesh, halos):
     """Chunked lambda-grid engine: the grid vmapped on top of the node
-    chunking (the block-schedule analogue of ``build_sharded_path``).
+    chunking, every device carrying all L grid points.
 
     Returns a jitted fn (X, y, W_diag, W_off, deg, lams (L,),
     lam_weights (p,)) -> path (L, m_pad, p).
@@ -573,8 +391,8 @@ def decsvm_path_chunked(X: Array, y: Array, W, lams, cfg: ADMMConfig,
     """Whole lambda grid through the chunked engine (m >> devices).
 
     Returns the path (L, m, p); score/select with
-    ``repro.core.path.score_path`` or use ``decsvm_path_mesh`` with
-    ``schedule="block"`` for fused in-program selection.
+    ``repro.core.path.score_path`` or use ``decsvm_path_mesh`` for fused
+    in-program selection.
     """
     sanitize.reject_unsupported(cfg, "decsvm_path_chunked")
     mesh = _chunk_mesh(X, mesh)
@@ -588,48 +406,51 @@ def decsvm_path_chunked(X: Array, y: Array, W, lams, cfg: ADMMConfig,
     return _replicated(mesh, path)[:, :m]
 
 
-# --------------------------------------------------------------------------
-# True 2-D (node, lam) mesh engine
-# --------------------------------------------------------------------------
 
 
-def make_node_lam_mesh(n_node: int, n_lam: Optional[int] = None) -> Mesh:
-    """2-D device mesh with named axes ("node", "lam")."""
-    from repro.launch.mesh import make_node_lam_mesh as _make
-    return _make(n_node, n_lam)
+# --------------------------------------------------------------------------
+# 2-D (node_chunk, lam) mesh engine
+# --------------------------------------------------------------------------
 
 
 @functools.lru_cache(maxsize=64)
 def build_mesh_path(m: int, p: int, C: int, cfg: ADMMConfig, mesh: Mesh,
-                    schedule: str = "gather", mode: str = "batched",
-                    tol: float = 1e-6, stop_rule: str = "kkt",
-                    with_masks: bool = False, check_every: int = 4,
-                    handoff: bool = True, halos=(),
+                    mode: str = "batched", tol: float = 1e-6,
+                    stop_rule: str = "kkt", with_masks: bool = False,
+                    check_every: int = 4, handoff: bool = True, halos=(),
                     m_real: Optional[int] = None):
-    """Build the 2-D (node, lam) shard_map program.  Cached on all
+    """Build the 2-D (node_chunk, lam) shard_map program.  Cached on all
     arguments (jit caches by function identity — a fresh closure per call
     would recompile every time).
 
     Grid *cells* — (lambda, sample-mask) pairs when ``with_masks``, so CV
     folds ride the same axis as plain grid points — are sharded over
-    "lam"; node state over "node".  Fits AND scoring run inside the one
-    program: per cell it returns (modified BIC on the in-mask data,
-    held-out hinge on the mask complement), reduced over the node axis
-    with psum.  Without masks the gradient skips the masking entirely
-    (every sample counts; held-out hinge is 0).
+    "lam"; node chunks over "node_chunk", with the halo exchange of
+    ``_block_neighbor_sum_fn`` (``halos``: the kept block diagonals and
+    their halo rows).  Fits AND scoring run inside the one program: per
+    cell it returns (modified BIC on the in-mask data, held-out hinge on
+    the mask complement), reduced over the node axis with psum.  Without
+    masks the gradient skips the masking entirely (every sample counts;
+    held-out hinge is 0).  ``m`` is the padded node count and ``m_real``
+    (< m when padded) corrects every scoring mean for the all-zero ghost
+    rows.
 
     Returns a jitted fn
-      (X, y, W, deg, cell_lams (C,), cell_rho (C, m), lam_weights (p,)
-       [, cell_masks (C, m, n)]) -> (path (C, m, p), scores (C, 2),
-                                     iters (C,)).
+      (X, y, (W_diag, W_off, node_mask), deg, cell_lams (C,),
+       cell_rho (C, m), lam_weights (p,) [, cell_masks (C, m, n)])
+      -> (path (C, m, p), scores (C, 2), iters (C,)).
 
     mode "batched": all local cells advance in lockstep (vmap), cold start,
     cfg.max_iter rounds — trajectories match the dense batched engine.
     mode "warm": sequential continuation over each device's local cell
     block with early stop on ``stop_rule`` ("kkt" residual or legacy
-    "progress"), the stop decision pmax-agreed across the node axis, the
-    statistic evaluated every ``check_every`` rounds (collective-safe
-    inner scan — held rounds still run their collectives).
+    "progress"), the statistic evaluated every ``check_every`` rounds
+    (collective-safe inner scan — held rounds still run their
+    collectives).  The halo exchange is a ppermute inside the while body,
+    and XLA's CollectivePermute rendezvous spans the whole mesh, so the
+    stop decision is pmax-agreed across BOTH axes (uniform trip counts
+    mesh-wide; converged lam columns keep refining until all columns
+    stop).  tools/meshcheck NONUNIFORM_STOP proves this at trace time.
     Continuation follows decreasing lambda; wherever lambda jumps back up
     (a full-data/fold block boundary under CV) the fit restarts cold.
 
@@ -640,56 +461,38 @@ def build_mesh_path(m: int, p: int, C: int, cfg: ADMMConfig, mesh: Mesh,
     like the 1-D warm path.  Cells where continuation doesn't apply
     (shard 0, fold-block boundaries) reuse their first-sweep solution, so
     the refinement sweep early-stops almost immediately.
-
-    ``schedule="block"`` runs the chunked node-megabatch layout: the
-    node mesh axis is "node_chunk", m is the *padded* node count, the W
-    operand is the ``(W_diag, W_off, node_mask)`` triple from
-    ``_chunk_prep``-style block operands (``halos`` holds the kept
-    block diagonals and their halo rows), and ``m_real`` (< m when
-    padded) corrects every scoring mean for the all-zero ghost rows.
     """
     if mode not in ("warm", "batched"):
         raise ValueError(f"mode {mode!r} not in ('warm', 'batched')")
     if stop_rule not in ("kkt", "progress"):
         raise ValueError(f"stop_rule {stop_rule!r} not in ('kkt', 'progress')")
-    nax = "node_chunk" if schedule == "block" else "node"
+    nax = "node_chunk"
     nn, nl = mesh.shape[nax], mesh.shape["lam"]
     assert m % nn == 0, f"m={m} must be divisible by node axis={nn}"
     assert C % nl == 0, f"cells={C} must be divisible by lam axis={nl}"
     m_real = m if m_real is None else m_real
-    import math as _math
 
     def prog(Xl, yl, Wop, degl, cell_lams, cell_rho, lamw, cell_masks=None):
-        if schedule == "block":
-            Wd, Woff, nmask = Wop
-            nbr = _block_neighbor_sum_fn(nax, nn, Wd, Woff, halos)
-        else:
-            nmask = None
-            nbr = _neighbor_sum_fn(schedule, nn, Wop)
-        step = solver.make_step(cfg, nbr)
+        Wd, Woff, nmask = Wop
+        step = solver.make_step(
+            cfg, _block_neighbor_sum_fn(nax, nn, Wd, Woff, halos))
         m_local, n, _ = Xl.shape
         C_local = cell_lams.shape[0]
         cells = ((cell_lams, cell_rho) if cell_masks is None
                  else (cell_lams, cell_rho, cell_masks))
-
-        def cell_problem(rhoc, maskc):
-            if schedule == "block":
-                return _padded_problem(Xl, yl, degl, rhoc, cfg, mask=maskc)
-            return _local_problem(Xl, yl, degl, rhoc, cfg, mask=maskc)
+        sdt = jnp.promote_types(Xl.dtype, jnp.float32)
 
         if mode == "batched":
 
             def fit_cell(B0, P0, prog0, lam, rhoc, maskc=None):
-                prob = cell_problem(rhoc, maskc)
+                prob = _padded_problem(Xl, yl, degl, rhoc, cfg, mask=maskc)
                 state = solver.SolverState(B0, P0,
                                            jnp.zeros((), jnp.int32), prog0)
-                run = (solver.run_fixed_cached if schedule == "block"
-                       else solver.run_fixed)
-                final = run(step, prob, lam, lamw,
-                            num_iters=cfg.max_iter, state=state)
+                final = solver.run_fixed_cached(step, prob, lam, lamw,
+                                                num_iters=cfg.max_iter,
+                                                state=state)
                 return final.B, final.t
 
-            sdt = jnp.promote_types(Xl.dtype, jnp.float32)
             B0 = _pvary(jnp.zeros((C_local, m_local, p), sdt),
                         (nax, "lam"))
             P0 = _pvary(jnp.zeros((C_local, m_local, p), sdt),
@@ -701,21 +504,9 @@ def build_mesh_path(m: int, p: int, C: int, cfg: ADMMConfig, mesh: Mesh,
             residual_fn = (solver.kkt_residual_fn(cfg, axis_name=nax,
                                                   node_mask=nmask)
                            if stop_rule == "kkt" else None)
-            # The block AND ring schedules' neighbour sums run ppermute
-            # inside the while body, and XLA's CollectivePermute
-            # rendezvous spans the whole mesh — so under either the stop
-            # decision must be agreed across BOTH axes (uniform trip
-            # counts mesh-wide); converged lam columns keep refining
-            # until all columns stop.  The sub-axis all_gather/psum of
-            # the gather schedule rendezvous per lam column, so that one
-            # keeps per-column stops.  (tools/meshcheck NONUNIFORM_STOP
-            # proves this choice at trace time; ring previously joined
-            # only the node axis — the PR 9 deadlock class.)
-            stop_axes = (nax, "lam") if schedule in ("block", "ring") else nax
-            sdt = jnp.promote_types(Xl.dtype, jnp.float32)
 
             def fit_from(B_init, lam, rhoc, maskc, t0=None):
-                prob = cell_problem(rhoc, maskc)
+                prob = _padded_problem(Xl, yl, degl, rhoc, cfg, mask=maskc)
                 P0 = _pvary(jnp.zeros((m_local, p), sdt), (nax, "lam"))
                 prog0 = _pvary(jnp.asarray(jnp.inf, sdt), (nax, "lam"))
                 t_init = (jnp.zeros((), jnp.int32) if t0 is None
@@ -724,7 +515,7 @@ def build_mesh_path(m: int, p: int, C: int, cfg: ADMMConfig, mesh: Mesh,
                 return solver.run_tol(step, prob, lam, lamw,
                                       max_iter=cfg.max_iter, tol=tol,
                                       state=state, residual_fn=residual_fn,
-                                      axis_name=stop_axes,
+                                      axis_name=(nax, "lam"),
                                       check_every=check_every)
 
             def outer(carry, cell):
@@ -789,9 +580,8 @@ def build_mesh_path(m: int, p: int, C: int, cfg: ADMMConfig, mesh: Mesh,
         f32 = jnp.float32
         margins = jnp.einsum("mnp,cmp->cmn", Xl, path, precision=solver.F32,
                              preferred_element_type=f32) * yl[None]
-        hinge = jnp.maximum(1.0 - margins, 0.0)              # (C_local, m, n)
-        if nmask is not None:
-            hinge = hinge * nmask[None, :, None]
+        hinge = (jnp.maximum(1.0 - margins, 0.0)
+                 * nmask[None, :, None])                     # (C_local, m, n)
         if cell_masks is None:
             hinge_in = jax.lax.psum(jnp.sum(hinge, axis=(1, 2)), nax)
             n_in = jnp.asarray(N_total, f32)
@@ -799,9 +589,7 @@ def build_mesh_path(m: int, p: int, C: int, cfg: ADMMConfig, mesh: Mesh,
         else:
             hinge_in = jax.lax.psum(
                 jnp.sum(hinge * cell_masks, axis=(1, 2)), nax)
-            val = 1.0 - cell_masks
-            if nmask is not None:
-                val = val * nmask[None, :, None]
+            val = (1.0 - cell_masks) * nmask[None, :, None]
             hinge_out = jax.lax.psum(jnp.sum(hinge * val, axis=(1, 2)),
                                      nax)
             n_out = jax.lax.psum(jnp.sum(val, axis=(1, 2)), nax)
@@ -811,14 +599,12 @@ def build_mesh_path(m: int, p: int, C: int, cfg: ADMMConfig, mesh: Mesh,
             jnp.sum((jnp.abs(path) > 1e-8).astype(f32), axis=(1, 2)),
             nax)
         bic = (hinge_in / n_in
-               + _math.sqrt(_math.log(N_total)) * _math.log(p)
+               + math.sqrt(math.log(N_total)) * math.log(p)
                * (supp / m_real) / N_total)
         scores = jnp.stack([bic, val_hinge], axis=-1)        # (C_local, 2)
         return path, scores, iters
 
-    wspec = ((P(nax), P(nax), P(nax)) if schedule == "block"
-             else P(nax))
-    base_specs = (P(nax), P(nax), wspec, P(nax),
+    base_specs = (P(nax), P(nax), (P(nax), P(nax), P(nax)), P(nax),
                   P("lam"), P("lam", nax), P())
     in_specs = base_specs + ((P("lam", nax),) if with_masks else ())
     fn = jax.shard_map(
@@ -827,34 +613,32 @@ def build_mesh_path(m: int, p: int, C: int, cfg: ADMMConfig, mesh: Mesh,
     return jax.jit(fn)
 
 
-def decsvm_path_mesh(X: Array, y: Array, W: np.ndarray, lams,
-                     cfg: ADMMConfig, mesh: Optional[Mesh] = None,
-                     schedule: str = "gather", mode: str = "batched",
+def decsvm_path_mesh(X: Array, y: Array, W, lams, cfg: ADMMConfig,
+                     mesh: Optional[Mesh] = None, mode: str = "batched",
                      tol: float = 1e-6,
                      lam_weights: Optional[Array] = None,
                      stop_rule: str = "kkt", criterion: str = "bic",
                      cv_folds: int = 5, cv_seed: int = 0,
                      check_every: int = 4, handoff: bool = True):
-    """Lambda path on a true 2-D (node, lam) device mesh, with selection.
+    """Lambda path on the 2-D (node_chunk, lam) device mesh, with selection.
 
-    The L-point grid is sharded over the "lam" mesh axis (today's 1-D
-    engine carries all L per device); with ``criterion="cv"`` the k-fold
-    train masks join the grid as extra cells — L*(1+k) cells total — so
-    full-data fits, fold fits, and both scoring rules run inside one
-    shard_map program.  Returns ``repro.core.path.PathResult`` whose
-    ``criteria`` is the selected rule's score per grid point.
+    The L-point grid is sharded over the "lam" mesh axis; with
+    ``criterion="cv"`` the k-fold train masks join the grid as extra
+    cells — L*(1+k) cells total — so full-data fits, fold fits, and both
+    scoring rules run inside one shard_map program.  Returns
+    ``repro.core.path.PathResult`` whose ``criteria`` is the selected
+    rule's score per grid point.
 
     Warm mode evaluates the stop statistic every ``check_every`` rounds
     and, with ``handoff`` (default), ppermutes each lam-shard's boundary
     solution forward so continuation matches the 1-D warm path across
     shard boundaries (see ``build_mesh_path``).
 
-    Requires #cells % lam-axis == 0, and m % node-axis == 0 for the
-    dense schedules; ``schedule="block"`` (the chunked node-megabatch
-    layout on a ("node_chunk", "lam") mesh) takes any m — the tail chunk
-    pads with exact-no-op ghost nodes and every score is corrected to
-    the real node count.  ``W`` may then be a ``graph.BlockTopology``.
-    cfg.lam is ignored (the grid supplies lambda).
+    Requires #cells % lam-axis == 0.  Any m: the tail chunk pads with
+    exact-no-op ghost nodes and every score is corrected to the real node
+    count.  ``W`` may be a dense (m, m) adjacency or a
+    ``graph.BlockTopology``; its device operands come from
+    ``_chunk_prep``.  cfg.lam is ignored (the grid supplies lambda).
     """
     from repro.core.path import PathResult  # local import: avoid cycle
 
@@ -865,55 +649,33 @@ def decsvm_path_mesh(X: Array, y: Array, W: np.ndarray, lams,
     if criterion not in ("bic", "cv"):
         raise ValueError(f"criterion {criterion!r} not in ('bic', 'cv')")
     C = L * (1 + cv_folds) if criterion == "cv" else L
-    chunked = schedule == "block"
 
     if mesh is None:
-        nn, nl = _choose_mesh_shape(m, C, len(jax.devices()),
-                                    chunked=chunked)
-        if chunked:
-            from repro.launch.mesh import make_chunk_lam_mesh
-            mesh = make_chunk_lam_mesh(nn, nl)
-        else:
-            mesh = make_node_lam_mesh(nn, nl)
-    nax = "node_chunk" if chunked else "node"
-    nn = mesh.shape[nax]
-
-    if chunked:
-        top = _as_topology(W)
-        assert top.m == m, (top.m, m)
-        W_diag, halos, W_off = top.chunk_operands(nn)
-        m_work = W_diag.shape[0]
-        pad = m_work - m
-        X = jnp.pad(jnp.asarray(X, jnp.float32),
-                    ((0, pad), (0, 0), (0, 0)))
-        y = jnp.pad(jnp.asarray(y, jnp.float32), ((0, pad), (0, 0)))
-        deg_np = np.zeros((m_work,), np.float32)
-        deg_np[:m] = top.degrees()
-        nmask_np = np.zeros((m_work,), np.float32)
-        nmask_np[:m] = 1.0
-        row_valid = nmask_np
-    else:
-        if schedule == "ring":
-            _assert_ring(W)
-        halos, m_work = (), m
-        row_valid = np.ones((m,), np.float32)
-
+        from repro.launch.mesh import make_chunk_lam_mesh
+        mesh = make_chunk_lam_mesh(*_choose_mesh_shape(
+            C, len(jax.devices())))
+    # rho per node on the unplaced fp32 X; a ghost node's is 0, as the
+    # power iteration gives on its all-zero rows
+    X = jnp.asarray(X, jnp.float32)
     rho_full = solver.compute_rho(X, cfg.h, cfg.kernel, cfg.rho_safety,
                                   backend=solver.resolve_backend(cfg))
+    ops, halos, m_work = _chunk_prep(X, y, W, mesh)
+    ghost = m_work - m
+    rho_full = jnp.pad(rho_full, (0, ghost))
     if criterion == "cv":
         from repro.core.tuning import kfold_masks  # local: avoid cycle
         folds = np.asarray(kfold_masks(m, n, cv_folds, seed=cv_seed))
-        if chunked:                        # ghost rows: mask 0 everywhere
-            folds = np.concatenate(
-                [folds, np.zeros((cv_folds, m_work - m, n), folds.dtype)],
-                axis=1)
-        ones = np.broadcast_to(row_valid[None, :, None], (L, m_work, n))
+        fold_rho = _fold_rhos(X, jnp.asarray(folds, X.dtype), cfg.h,
+                              cfg.kernel, cfg.rho_safety)     # (k, m)
+        fold_rho = jnp.pad(fold_rho, ((0, 0), (0, ghost)))
+        folds = np.concatenate(             # ghost rows: mask 0 everywhere
+            [folds, np.zeros((cv_folds, ghost, n), folds.dtype)], axis=1)
+        real = (np.arange(m_work) < m).astype(np.float32)
+        ones = np.broadcast_to(real[None, :, None], (L, m_work, n))
         cell_masks = jnp.asarray(np.concatenate(
             [ones] + [np.broadcast_to(f, (L, m_work, n)) for f in folds]),
             X.dtype)
         cell_lams = np.concatenate([lams] * (1 + cv_folds))
-        fold_rho = _fold_rhos(X, jnp.asarray(folds, X.dtype), cfg.h,
-                              cfg.kernel, cfg.rho_safety)     # (k, m_work)
         cell_rho = jnp.concatenate(
             [jnp.broadcast_to(rho_full, (L, m_work))]
             + [jnp.broadcast_to(r, (L, m_work)) for r in fold_rho])
@@ -922,32 +684,20 @@ def decsvm_path_mesh(X: Array, y: Array, W: np.ndarray, lams,
         cell_rho = jnp.broadcast_to(rho_full, (L, m_work))
     assert C == len(cell_lams)
 
-    node_s = NamedSharding(mesh, P(nax))
-    if chunked:
-        Wop = (jax.device_put(jnp.asarray(W_diag), node_s),
-               jax.device_put(jnp.asarray(W_off), node_s),
-               jax.device_put(jnp.asarray(nmask_np), node_s))
-        deg = jax.device_put(jnp.asarray(deg_np), node_s)
-    else:
-        Wop = jnp.asarray(W, X.dtype)
-        deg = jnp.sum(Wop, axis=1)
-
     # X narrows to the backend's compute dtype only now — rho (above) and
     # the scoring operands stay fp32
-    X_c = X.astype(solver.problem_dtype(cfg))
-    X_s = jax.device_put(X_c, node_s)
-    y_s = jax.device_put(y, node_s)
-    rho_s = jax.device_put(cell_rho, NamedSharding(mesh, P("lam", nax)))
-    lams_s = jax.device_put(jnp.asarray(cell_lams, jnp.float32),
-                            NamedSharding(mesh, P("lam")))
-    operands = [X_s, y_s, Wop, deg, lams_s, rho_s,
+    cells_s = NamedSharding(mesh, P("lam", "node_chunk"))
+    operands = [ops["X"].astype(solver.problem_dtype(cfg)), ops["y"],
+                (ops["W_diag"], ops["W_off"], ops["nmask"]), ops["deg"],
+                jax.device_put(jnp.asarray(cell_lams, jnp.float32),
+                               NamedSharding(mesh, P("lam"))),
+                jax.device_put(cell_rho, cells_s),
                 _lamw(lam_weights, p, jnp.float32)]
     if cell_masks is not None:
-        operands.append(jax.device_put(
-            cell_masks, NamedSharding(mesh, P("lam", nax))))
+        operands.append(jax.device_put(cell_masks, cells_s))
 
-    fitted = build_mesh_path(m_work, p, C, cfg, mesh, schedule, mode, tol,
-                             stop_rule, with_masks=cell_masks is not None,
+    fitted = build_mesh_path(m_work, p, C, cfg, mesh, mode, tol, stop_rule,
+                             with_masks=cell_masks is not None,
                              check_every=check_every, handoff=handoff,
                              halos=halos, m_real=m)
     path_cells, scores, iters = (_replicated(mesh, a)
@@ -964,44 +714,18 @@ def decsvm_path_mesh(X: Array, y: Array, W: np.ndarray, lams,
     return PathResult(lams_j[i], path[i], lams_j, path, criteria, iters[:L])
 
 
-def _choose_mesh_shape(m: int, C: int, ndev: int, chunked: bool = False):
-    """Pick (node, lam) axis sizes: use every device, maximize balance.
-    ``chunked`` drops the m-divisibility constraint (the block schedule
-    pads the tail chunk), so only the cell count restricts the split."""
+def _choose_mesh_shape(C: int, ndev: int):
+    """Pick (node_chunk, lam) axis sizes: use every device, maximize
+    balance.  Any m pads its tail chunk, so only the cell count restricts
+    the split."""
     best = None
     for nn in range(1, ndev + 1):
         if ndev % nn:
             continue
         nl = ndev // nn
-        if (not chunked and m % nn) or C % nl:
+        if C % nl:
             continue
         key = (min(nn, nl), nl)        # balanced first, then grid-parallel
         if best is None or key > best[0]:
             best = (key, (nn, nl))
-    if best is None:
-        raise ValueError(
-            f"no (node, lam) split of {ndev} devices divides m={m} and "
-            f"cells={C}; pass an explicit mesh")
     return best[1]
-
-
-def _assert_ring(W: np.ndarray) -> None:
-    m = W.shape[0]
-    expect = np.zeros_like(np.asarray(W))
-    for i in range(m):
-        expect[i, (i + 1) % m] = expect[i, (i - 1) % m] = 1.0
-    if not np.array_equal(np.asarray(W) != 0, expect != 0):
-        raise ValueError("schedule='ring' requires a ring-ordered adjacency")
-
-
-def consensus_mix(grads: Array, Wmix: Array, axis: str = "node") -> Array:
-    """One Metropolis mixing round of per-node tensors inside shard_map.
-
-    Beyond-paper utility: applies the paper's one-hop communication pattern
-    to arbitrary per-node gradients (no convex-convergence guarantee for
-    non-convex losses — see DESIGN.md §3).
-    grads: (m_local, ...) local block; Wmix: (m_local, m) local mixing rows.
-    """
-    flat = grads.reshape(grads.shape[0], -1)
-    all_flat = jax.lax.all_gather(flat, axis, axis=0, tiled=True)
-    return solver.mm(Wmix, all_flat).reshape(grads.shape)

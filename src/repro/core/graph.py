@@ -130,7 +130,7 @@ class BlockTopology:
     chunks of ``mc = ceil(m / n_chunks)`` rows (the tail chunk is padded
     with isolated ghost nodes) and views W as an ``n_chunks x n_chunks``
     grid of (mc, mc) blocks; ``chunk_operands`` returns exactly the
-    operands ``decentral``'s block schedule consumes.
+    operands ``decentral``'s chunked engines consume.
     """
 
     def __init__(self, neighbors):

@@ -27,7 +27,7 @@ orchestration and the fused selection criteria:
 ``decsvm_path_select`` fuses modified-BIC (``tuning.modified_bic_jnp``) or
 cross-validation scoring into the same program and returns
 ``(best_lam, best_B, path, criteria)`` as device arrays.  The sharded
-counterparts (node-sharded and true 2-D node x lambda meshes) live in
+counterparts (node-chunked, and the 2-D node_chunk x lambda mesh) live in
 ``repro.core.decentral``.
 
 **Problem batching** (the serving axis, orthogonal to the node x lambda

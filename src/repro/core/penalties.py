@@ -7,7 +7,7 @@ recipe: fit the l1 solution (stage 1), then re-fit with per-coordinate
 penalty weights lam_j = pen'(|beta_j^(1)|; lam) / lam (stage 2).  The
 per-coordinate weights multiply the soft-threshold level of the unified
 Algorithm-1 step (``repro.core.solver``), so *every* engine — dense,
-Pallas, node-sharded, 2-D mesh — runs the stage-2 solve unchanged
+Pallas, node-chunked, 2-D mesh — runs the stage-2 solve unchanged
 (``engine="sharded"`` routes both stages through
 ``repro.core.decentral``).
 """
@@ -55,20 +55,21 @@ def decsvm_fit_lla(X: Array, y: Array, W: Array, cfg: ADMMConfig,
                    penalty: str = "scad",
                    lams: Optional[Sequence[float]] = None,
                    path_mode: str = "warm", engine: str = "dense",
-                   mesh=None, schedule: str = "gather", **pen_kwargs):
+                   mesh=None, **pen_kwargs):
     """Two-stage LLA: l1 pilot -> penalty-weighted re-fit.
 
     When ``lams`` is given, the stage-1 pilot comes from the lambda-path
     engine — ``repro.core.path`` for ``engine="dense"``, the 2-D
-    node x lambda mesh (``decentral.decsvm_path_mesh``) for
+    node_chunk x lambda mesh (``decentral.decsvm_path_mesh``) for
     ``engine="sharded"`` — the modified BIC picks lambda, and both the
     pilot and the stage-2 penalty level use the selected value: one
     compiled program instead of a per-lambda refit loop.  Otherwise the
     pilot is a single l1 fit at ``cfg.lam``.
 
-    engine: "dense" (single-process) or "sharded" (node state sharded via
-    ``repro.core.decentral``; the stage-2 per-coordinate ``lam_weights``
-    ride the sharded step unchanged).
+    engine: "dense" (single-process) or "sharded" (node state chunked over
+    the devices, ``decentral.decsvm_fit_chunked``; the stage-2
+    per-coordinate ``lam_weights`` ride the sharded step unchanged).
+    ``W`` may then be a ``graph.BlockTopology``.
 
     Weights are computed from the network-average pilot (each node can form
     it with one extra all-reduce round in deployment).
@@ -82,8 +83,7 @@ def decsvm_fit_lla(X: Array, y: Array, W: Array, cfg: ADMMConfig,
         if engine == "sharded":
             from repro.core import decentral  # local import: avoid cycle
             res = decentral.decsvm_path_mesh(
-                X, y, np.asarray(W), np.asarray(lams), cfg, mesh=mesh,
-                schedule=schedule, mode=path_mode)
+                X, y, W, np.asarray(lams), cfg, mesh=mesh, mode=path_mode)
         else:
             from repro.core import path as path_mod  # local: avoid cycle
             res = path_mod.decsvm_path_select(X, y, W, jnp.asarray(lams),
@@ -92,16 +92,14 @@ def decsvm_fit_lla(X: Array, y: Array, W: Array, cfg: ADMMConfig,
         B1 = res.best_B
     elif engine == "sharded":
         from repro.core import decentral  # local import: avoid cycle
-        B1 = decentral.decsvm_fit_sharded(X, y, np.asarray(W), cfg,
-                                          mesh=mesh, schedule=schedule)
+        B1 = decentral.decsvm_fit_chunked(X, y, W, cfg, mesh=mesh)
     else:
         B1 = decsvm_fit(X, y, W, cfg)
     pilot = jnp.mean(B1, axis=0)
     w = PENALTIES[penalty](pilot, cfg.lam, **pen_kwargs)
     if engine == "sharded":
         from repro.core import decentral  # local import: avoid cycle
-        B2 = decentral.decsvm_fit_sharded(X, y, np.asarray(W), cfg,
-                                          mesh=mesh, schedule=schedule,
+        B2 = decentral.decsvm_fit_chunked(X, y, W, cfg, mesh=mesh,
                                           lam_weights=w)
     else:
         B2 = decsvm_fit(X, y, W, cfg, lam_weights=w)

@@ -4,7 +4,7 @@ one traced-``(lam, lam_weights)`` step, pluggable everything else.
 Every solver surface in this repo — ``admm.decsvm_fit`` (dense),
 ``admm_adaptive.decsvm_fit_tol`` / ``decsvm_fit_uneven``,
 ``path.decsvm_path_batched`` / ``decsvm_path_warm`` (lambda grid),
-``decentral.decsvm_fit_sharded`` / ``decsvm_path_sharded`` /
+``decentral.decsvm_fit_chunked`` / ``decsvm_path_chunked`` /
 ``decsvm_path_mesh`` (shard_map engines), the LLA stage-2 re-fit in
 ``penalties``, and the Pallas oracle in ``kernels.ref`` — is a thin driver
 over this module.  The update math exists exactly once
@@ -15,10 +15,11 @@ suite (``tests/test_solver.py``) checks the drivers, not per-pair math.
 Pluggable pieces of ``make_step``:
 
 - **neighbour sum** (callable ``B -> (m, p)``): dense ``W @ B``
-  (single process), ``all_gather`` + local adjacency rows (sharded, any
-  graph), or ``ppermute`` of shard-boundary rows (sharded ring).  The
-  step calls it twice per round — once for the primal update, once for
-  the dual — exactly update (7a')/(7b).
+  (single process), or the halo exchange of the node-chunked engines
+  (``decentral._block_neighbor_sum_fn``: a local diagonal-block dot plus
+  a ``ppermute`` of the rows each cross-chunk block reads).  The step
+  calls it twice per round — once for the primal update, once for the
+  dual — exactly update (7a')/(7b).
 - **local-gradient backend**: the jnp reference (``local_update``,
   optionally sample-masked for uneven n / cross-validation folds) or the
   fused Pallas TPU kernel (``kernels.ops.csvm_local_update``).  Under

@@ -30,8 +30,8 @@ Ways to traverse the lambda grid:
   batched wall-clock depends on how aggressively the tolerance lets grid
   points stop (see ``BENCH_lambda_path.json`` for the current trade).
 - **mesh** (``repro.core.decentral.decsvm_path_mesh``): the grid sharded
-  over a true 2-D (node, lam) device mesh; grid points stop multiplying
-  per-device memory and compute.
+  over the 2-D (node_chunk, lam) device mesh, node chunks exchanging
+  halos; grid points stop multiplying per-device memory and compute.
 
 Selection criteria, both fused into the traversal's compiled program:
 the modified BIC above (``criterion="bic"``) and k-fold cross-validated
@@ -142,8 +142,7 @@ def select_lambda_path(X, y, W, cfg, lams: Optional[Sequence[float]] = None,
                        lam_weights=None, criterion: str = "bic",
                        cv_folds: int = 5, cv_seed: int = 0,
                        stop_rule: str = "kkt", engine: str = "dense",
-                       mesh=None, schedule: str = "gather",
-                       check_every: int = 4):
+                       mesh=None, check_every: int = 4):
     """On-device grid selection via ``repro.core.path`` / ``decentral``.
 
     Builds ``lambda_grid(X, y, num)`` when ``lams`` is omitted, runs the
@@ -153,10 +152,9 @@ def select_lambda_path(X, y, W, cfg, lams: Optional[Sequence[float]] = None,
     ``select_lambda`` — table rows are (lambda, criterion, mean support
     size).  The full on-device ``PathResult`` is returned as a fourth
     element.  ``engine="mesh"`` routes the traversal through the 2-D
-    (node, lam) device-mesh engine (``decentral.decsvm_path_mesh``);
-    ``engine="chunked"`` runs the same mesh engine in its block schedule
-    (chunked node-megabatch layout: any m, m >> devices supported, and
-    ``W`` may be a ``graph.BlockTopology``).
+    (node_chunk, lam) device-mesh engine (``decentral.decsvm_path_mesh``:
+    any m, m >> devices supported, and ``W`` may be a
+    ``graph.BlockTopology``).
 
     ``check_every`` (dense engine, warm mode only): evaluate the stop
     statistic every k-th round instead of every round.  The mesh engine
@@ -169,15 +167,11 @@ def select_lambda_path(X, y, W, cfg, lams: Optional[Sequence[float]] = None,
         with TraceAnnotation(trace.SPAN_LAMBDA_GRID):
             lams = lambda_grid(np.asarray(X), np.asarray(y), num=num)
     with TraceAnnotation(trace.SPAN_PATH_PROGRAM):
-        if engine in ("mesh", "chunked"):
+        if engine == "mesh":
             from repro.core import decentral  # local import: avoid cycle
-            if engine == "chunked":
-                schedule = "block"
-            else:
-                W = np.asarray(W)
             res = decentral.decsvm_path_mesh(
                 jnp.asarray(X), jnp.asarray(y), W, lams, cfg,
-                mesh=mesh, schedule=schedule, mode=mode, tol=tol,
+                mesh=mesh, mode=mode, tol=tol,
                 lam_weights=lam_weights, stop_rule=stop_rule,
                 criterion=criterion, cv_folds=cv_folds, cv_seed=cv_seed)
         elif engine == "dense":
@@ -188,8 +182,7 @@ def select_lambda_path(X, y, W, cfg, lams: Optional[Sequence[float]] = None,
                 criterion=criterion, cv_folds=cv_folds, cv_seed=cv_seed,
                 check_every=check_every)
         else:
-            raise ValueError(
-                f"engine {engine!r} not in ('dense', 'mesh', 'chunked')")
+            raise ValueError(f"engine {engine!r} not in ('dense', 'mesh')")
     with TraceAnnotation(trace.SPAN_BIC_TABLE):
         table = [(float(l), float(c),
                   metrics.mean_support_size(np.asarray(B)))

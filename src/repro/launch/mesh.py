@@ -12,33 +12,21 @@ def make_production_mesh(*, multi_pod: bool = False):
     return jax.make_mesh(shape, axes)
 
 
-def make_node_lam_mesh(n_node: int, n_lam=None):
-    """2-D mesh with named axes ("node", "lam") for the deCSVM lambda-path
-    engine (``repro.core.decentral.decsvm_path_mesh``): network nodes are
-    sharded over "node" (the paper's communication axis — collectives run
-    only here), lambda grid cells over "lam" (embarrassingly parallel).
-    """
-    n = len(jax.devices())
-    n_lam = (n // n_node) if n_lam is None else n_lam
-    assert n_node * n_lam <= n, (n_node, n_lam, n)
-    return jax.make_mesh((n_node, n_lam), ("node", "lam"))
-
-
 def make_node_chunk_mesh(n_devices=None):
-    """1-D mesh with named axis ("node_chunk",) for the chunked
-    node-megabatch engines (``repro.core.decentral`` schedule="block"):
-    each device owns a contiguous chunk of ``ceil(m / n_devices)``
-    network nodes, so m is no longer capped by the device count."""
+    """1-D mesh with named axis ("node_chunk",) for the deCSVM engines
+    (``repro.core.decentral``): each device owns a contiguous chunk of
+    ``ceil(m / n_devices)`` network nodes, so m is not capped by the
+    device count."""
     n = len(jax.devices()) if n_devices is None else n_devices
     assert 1 <= n <= len(jax.devices()), (n, len(jax.devices()))
     return jax.make_mesh((n,), ("node_chunk",))
 
 
 def make_chunk_lam_mesh(n_chunk: int, n_lam=None):
-    """2-D mesh with named axes ("node_chunk", "lam"): the chunked
-    analogue of ``make_node_lam_mesh`` for the mesh lambda-path engine
-    at m >> devices — node chunks shard over "node_chunk" (collectives
-    run only here), lambda grid cells over "lam"."""
+    """2-D mesh with named axes ("node_chunk", "lam") for the deCSVM
+    lambda-path engine (``repro.core.decentral.decsvm_path_mesh``): node
+    chunks shard over "node_chunk" (the paper's communication axis — the
+    halo exchange runs only here), lambda grid cells over "lam"."""
     n = len(jax.devices())
     n_lam = (n // n_chunk) if n_lam is None else n_lam
     assert n_chunk * n_lam <= n, (n_chunk, n_lam, n)

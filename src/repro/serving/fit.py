@@ -22,7 +22,7 @@ execution per *bucket*, not per request.
 
 Large-m requests (m above the device count) route to the chunked
 node-megabatch engine instead (``engine="auto"`` on ``FitRequest``;
-``decentral`` schedule="block"): one problem then spans every device via
+``decentral.decsvm_path_mesh``): one problem then spans every device via
 the node-chunk mesh, so such buckets execute per request while still
 sharing one cached compiled program across the bucket.
 
@@ -67,8 +67,8 @@ class FitRequest:
     criterion: "bic" | "cv"; penalty: None (plain l1) or one of
     ``repro.core.penalties.PENALTIES`` for a one-step-LLA stage-2 re-fit.
     engine: "auto" | "dense" | "chunked".  "auto" resolves at submit
-    time: "chunked" (the node-megabatch mesh engine, schedule="block")
-    when m exceeds the device count — such problems cannot run through
+    time: "chunked" (the node-megabatch mesh engine) when m exceeds the
+    device count — such problems cannot run through
     the dense problem-batched program at all — else "dense".  The
     resolved engine is part of the bucket key, so dense and chunked
     requests never co-batch.
@@ -451,7 +451,7 @@ class DecsvmFitServer(FifoEngine):
                 req.W, r0.cfg, lams=lams, mode=r0.mode, tol=r0.tol,
                 criterion=r0.criterion, cv_folds=r0.cv_folds,
                 cv_seed=r0.cv_seed, stop_rule=r0.stop_rule,
-                engine="chunked")
+                engine="mesh")
             best_lams.append(bl)
             best_Bs.append(bB)
             tables.append(table)

@@ -1,7 +1,6 @@
-"""Chunked node-megabatch engine (schedule="block"): block-sparse
-topology operands on the host, and chunked-vs-dense parity on a real
-8-device mesh (subprocesses, so the XLA device-count flag never leaks
-into the main test process)."""
+"""Chunked node-megabatch engine: block-sparse topology operands on the
+host, and chunked-vs-dense parity on a real 8-device mesh (subprocesses,
+so the XLA device-count flag never leaks into the main test process)."""
 import os
 import subprocess
 import sys
@@ -335,33 +334,35 @@ def test_uneven_final_chunk_padding_rows_are_exact_noops():
 
 
 def test_mesh_block_schedule_matches_dense_mesh():
-    """decsvm_path_mesh(schedule="block") — fused selection on the
-    (node_chunk, lam) mesh — agrees with the dense mesh engine, for the
-    batched/BIC and warm/CV modes."""
+    """decsvm_path_mesh — fused selection on the (node_chunk, lam) mesh —
+    agrees with the dense path engine (``path.decsvm_path_select``) for
+    BIC and CV selection."""
     out = run_py("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import ADMMConfig, decentral, graph
+        from repro.core.path import decsvm_path_select
         rng = np.random.default_rng(5)
         m, n, p = 16, 12, 6
         X = rng.normal(size=(m, n, p)).astype(np.float32)
         b = np.zeros(p, np.float32); b[:2] = 1.0
         y = np.sign(X @ b + 0.1*rng.normal(size=(m, n))).astype(np.float32)
         W = graph.ring(m)
+        Wj = jnp.asarray(W, jnp.float32)
         cfg = ADMMConfig(lam=0.1, max_iter=40)
         lams = np.geomspace(0.5, 0.05, 4).astype(np.float32)
-        rd = decentral.decsvm_path_mesh(X, y, W, lams, cfg)
-        rb = decentral.decsvm_path_mesh(X, y, W, lams, cfg,
-                                        schedule="block")
+        rd = decsvm_path_select(jnp.asarray(X), jnp.asarray(y), Wj,
+                                jnp.asarray(lams), cfg, mode="batched")
+        rb = decentral.decsvm_path_mesh(X, y, W, lams, cfg)
         dev = np.abs(np.asarray(rd.path) - np.asarray(rb.path)).max()
         cdev = np.abs(np.asarray(rd.criteria) - np.asarray(rb.criteria)).max()
         print("bic", dev, cdev)
         assert dev <= 1e-5 and cdev <= 1e-5, (dev, cdev)
         assert float(rd.best_lam) == float(rb.best_lam)
-        rcv = decentral.decsvm_path_mesh(X, y, W, lams, cfg,
-                                         criterion="cv", cv_folds=3)
+        rcv = decsvm_path_select(jnp.asarray(X), jnp.asarray(y), Wj,
+                                 jnp.asarray(lams), cfg, mode="batched",
+                                 criterion="cv", cv_folds=3)
         rbc = decentral.decsvm_path_mesh(X, y, W, lams, cfg,
-                                         criterion="cv", cv_folds=3,
-                                         schedule="block")
+                                         criterion="cv", cv_folds=3)
         cdev = np.abs(np.asarray(rcv.criteria) - np.asarray(rbc.criteria)).max()
         print("cv", cdev)
         assert cdev <= 1e-5, cdev

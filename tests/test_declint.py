@@ -20,7 +20,7 @@ from tools.declint.core import check_exempt_list
 from tools.declint.rules import default_rules
 
 ROOT = Path(__file__).resolve().parent.parent
-AXES = {"pod", "data", "model", "node", "node_chunk", "lam"}
+AXES = {"pod", "data", "model", "node_chunk", "lam"}
 
 
 def _rules_of(violations):
@@ -191,7 +191,7 @@ def test_r5_flags_collective_inside_kernel_body():
     import jax
 
     def _kern(x_ref, o_ref):
-        o_ref[...] = jax.lax.psum(x_ref[...], "node")
+        o_ref[...] = jax.lax.psum(x_ref[...], "node_chunk")
     """
     got = lint(bad, path="repro/kernels/foo.py")
     assert _rules_of(got) == ["R5"]
@@ -201,7 +201,7 @@ def test_r5_allows_collectives_between_launches():
     import jax
 
     def neighbour_sum(B):
-        return jax.lax.psum(B, "node")    # mesh level, not a kernel body
+        return jax.lax.psum(B, "node_chunk")  # mesh level, not a kernel body
     """
     assert lint(ok) == []
 
@@ -229,9 +229,9 @@ def test_r6_allows_known_axes_and_skips_without_vocabulary():
     from jax.sharding import PartitionSpec as P
 
     def f(x):
-        return jax.lax.psum(x, "node")
+        return jax.lax.psum(x, "node_chunk")
 
-    spec = P("lam", "node")
+    spec = P("lam", "node_chunk")
     """
     assert lint(ok) == []
     # no launch/mesh.py vocabulary (axes=None): the rule stands down

@@ -25,21 +25,29 @@ def run_py(code: str, devices: int = 8) -> str:
 
 
 def test_sharded_admm_matches_dense_gather_and_ring():
+    """The chunked engine at one node a device: on Erdos-Renyi every kept
+    offset sends its whole chunk (the rows an all_gather moves), on a
+    ring only offsets 1 and 7 send, one row each (the two boundary-row
+    ppermutes); both match the dense fit."""
     out = run_py("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import SimConfig, generate, ADMMConfig, decsvm_fit
-        from repro.core.graph import erdos_renyi, ring
-        from repro.core.decentral import decsvm_fit_sharded
+        from repro.core.graph import BlockTopology, erdos_renyi, ring
+        from repro.core.decentral import decsvm_fit_chunked
         cfg = SimConfig(p=30, s=5, m=8, n=50)
         X, y, bstar = generate(cfg, seed=2)
         acfg = ADMMConfig(lam=0.05, max_iter=80)
         W = erdos_renyi(8, 0.5, seed=3)
+        halos = BlockTopology.from_dense(W).chunk_operands(8)[1]
+        assert halos and all(rows == (0,) for _, rows in halos), halos
         Bd = np.asarray(decsvm_fit(jnp.asarray(X), jnp.asarray(y), jnp.asarray(W), acfg))
-        Bs = np.asarray(decsvm_fit_sharded(jnp.asarray(X), jnp.asarray(y), W, acfg))
+        Bs = np.asarray(decsvm_fit_chunked(jnp.asarray(X), jnp.asarray(y), W, acfg))
         print("gather", np.max(np.abs(Bd - Bs)))
         Wr = ring(8)
+        halos = BlockTopology.from_dense(Wr).chunk_operands(8)[1]
+        assert halos == ((1, (0,)), (7, (0,))), halos
         Bdr = np.asarray(decsvm_fit(jnp.asarray(X), jnp.asarray(y), jnp.asarray(Wr), acfg))
-        Br = np.asarray(decsvm_fit_sharded(jnp.asarray(X), jnp.asarray(y), Wr, acfg, schedule="ring"))
+        Br = np.asarray(decsvm_fit_chunked(jnp.asarray(X), jnp.asarray(y), Wr, acfg))
         print("ring", np.max(np.abs(Bdr - Br)))
         assert np.max(np.abs(Bd - Bs)) < 1e-4
         assert np.max(np.abs(Bdr - Br)) < 1e-4
@@ -48,14 +56,14 @@ def test_sharded_admm_matches_dense_gather_and_ring():
 
 
 def test_sharded_lambda_path_matches_batched_multidevice():
-    """The node x lambda path engine (vmap over collectives inside
+    """The chunked node x lambda path engine (vmap over collectives inside
     shard_map) agrees with the dense batched path on a real 8-device mesh,
-    for both neighbour-exchange schedules."""
+    with whole-chunk (Erdos-Renyi) and one-row (ring) halos."""
     out = run_py("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import SimConfig, generate, ADMMConfig, tuning
         from repro.core.graph import erdos_renyi, ring
-        from repro.core.decentral import decsvm_path_sharded
+        from repro.core.decentral import decsvm_path_chunked
         from repro.core.path import decsvm_path_batched
         cfg = SimConfig(p=30, s=5, m=8, n=50)
         X, y, bstar = generate(cfg, seed=2)
@@ -64,12 +72,12 @@ def test_sharded_lambda_path_matches_batched_multidevice():
         Xj, yj = jnp.asarray(X), jnp.asarray(y)
         W = erdos_renyi(8, 0.5, seed=3)
         dense = np.asarray(decsvm_path_batched(Xj, yj, jnp.asarray(W), jnp.asarray(lams), acfg))
-        shard = np.asarray(decsvm_path_sharded(Xj, yj, W, lams, acfg))
+        shard = np.asarray(decsvm_path_chunked(Xj, yj, W, lams, acfg))
         print("gather", np.max(np.abs(dense - shard)))
         assert np.max(np.abs(dense - shard)) < 1e-4
         Wr = ring(8)
         dense_r = np.asarray(decsvm_path_batched(Xj, yj, jnp.asarray(Wr), jnp.asarray(lams), acfg))
-        shard_r = np.asarray(decsvm_path_sharded(Xj, yj, Wr, lams, acfg, schedule="ring"))
+        shard_r = np.asarray(decsvm_path_chunked(Xj, yj, Wr, lams, acfg))
         print("ring", np.max(np.abs(dense_r - shard_r)))
         assert np.max(np.abs(dense_r - shard_r)) < 1e-4
     """)
@@ -77,7 +85,7 @@ def test_sharded_lambda_path_matches_batched_multidevice():
 
 
 def test_mesh_2d_path_matches_batched_multidevice():
-    """The true 2-D (node, lam) mesh engine — grid cells on their own mesh
+    """The 2-D (node_chunk, lam) mesh engine — grid cells on their own mesh
     axis, fused BIC/CV scoring — agrees with the dense batched path on a
     real 8-device mesh, including warm continuation and lam_weights."""
     out = run_py("""
@@ -86,6 +94,7 @@ def test_mesh_2d_path_matches_batched_multidevice():
         from repro.core.graph import erdos_renyi
         from repro.core import decentral
         from repro.core.path import decsvm_path_batched, decsvm_path_select
+        from repro.launch.mesh import make_chunk_lam_mesh
         cfg = SimConfig(p=30, s=5, m=8, n=50)
         X, y, bstar = generate(cfg, seed=2)
         acfg = ADMMConfig(lam=0.0, max_iter=80)
@@ -96,7 +105,7 @@ def test_mesh_2d_path_matches_batched_multidevice():
                                                jnp.asarray(lams), acfg))
         ref = decsvm_path_select(Xj, yj, jnp.asarray(W), jnp.asarray(lams),
                                  acfg, mode="batched")
-        mesh = decentral.make_node_lam_mesh(4, 2)
+        mesh = make_chunk_lam_mesh(4, 2)
         res = decentral.decsvm_path_mesh(Xj, yj, W, lams, acfg, mesh=mesh)
         print("path", np.max(np.abs(np.asarray(res.path) - dense)))
         assert np.max(np.abs(np.asarray(res.path) - dense)) < 1e-5
@@ -129,7 +138,7 @@ def test_mesh_2d_path_matches_batched_multidevice():
 
 
 def test_mesh_warm_handoff_matches_dense_warm_path():
-    """Cross-shard warm-start hand-off on the (node, lam) mesh: with
+    """Cross-shard warm-start hand-off on the (node_chunk, lam) mesh: with
     ppermute hand-off the warm path tracks the dense warm reference much
     more closely than cold-started lambda shards (each shard's first cell
     otherwise restarts from zero instead of its left neighbour's solution)."""
@@ -139,6 +148,7 @@ def test_mesh_warm_handoff_matches_dense_warm_path():
         from repro.core.graph import erdos_renyi
         from repro.core import decentral
         from repro.core.path import decsvm_path_warm
+        from repro.launch.mesh import make_chunk_lam_mesh
         cfg = SimConfig(p=20, s=4, m=4, n=60)
         X, y, _ = generate(cfg, seed=1)
         Xj, yj = jnp.asarray(X), jnp.asarray(y)
@@ -148,7 +158,7 @@ def test_mesh_warm_handoff_matches_dense_warm_path():
         dense, it_d = decsvm_path_warm(Xj, yj, jnp.asarray(W, jnp.float32),
                                        jnp.asarray(lams), acfg, tol=1e-5)
         dense = np.asarray(dense)
-        mesh = decentral.make_node_lam_mesh(2, 4)   # 4 lambda shards x 2
+        mesh = make_chunk_lam_mesh(2, 4)   # 4 lambda shards x 2
         devs = {}
         for handoff in (True, False):
             res = decentral.decsvm_path_mesh(Xj, yj, W, lams, acfg,
@@ -165,13 +175,13 @@ def test_mesh_warm_handoff_matches_dense_warm_path():
 
 
 def test_sharded_lam_weights_matches_dense_multidevice():
-    """Non-uniform per-coordinate penalties through the sharded engines
-    (the PR-3 feature gap): dense == sharded-gather == sharded-ring."""
+    """Non-uniform per-coordinate penalties through the chunked engine
+    (the PR-3 feature gap): dense == whole-chunk halos == one-row halos."""
     out = run_py("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import SimConfig, generate, ADMMConfig, decsvm_fit
         from repro.core.graph import erdos_renyi, ring
-        from repro.core.decentral import decsvm_fit_sharded
+        from repro.core.decentral import decsvm_fit_chunked
         cfg = SimConfig(p=30, s=5, m=8, n=50)
         X, y, bstar = generate(cfg, seed=2)
         acfg = ADMMConfig(lam=0.05, max_iter=80)
@@ -181,14 +191,13 @@ def test_sharded_lam_weights_matches_dense_multidevice():
         W = erdos_renyi(8, 0.5, seed=3)
         Bd = np.asarray(decsvm_fit(Xj, yj, jnp.asarray(W), acfg,
                                    lam_weights=w))
-        Bs = np.asarray(decsvm_fit_sharded(Xj, yj, W, acfg, lam_weights=w))
+        Bs = np.asarray(decsvm_fit_chunked(Xj, yj, W, acfg, lam_weights=w))
         print("gather", np.max(np.abs(Bd - Bs)))
         assert np.max(np.abs(Bd - Bs)) < 1e-4
         Wr = ring(8)
         Bdr = np.asarray(decsvm_fit(Xj, yj, jnp.asarray(Wr), acfg,
                                     lam_weights=w))
-        Br = np.asarray(decsvm_fit_sharded(Xj, yj, Wr, acfg,
-                                           schedule="ring", lam_weights=w))
+        Br = np.asarray(decsvm_fit_chunked(Xj, yj, Wr, acfg, lam_weights=w))
         print("ring", np.max(np.abs(Bdr - Br)))
         assert np.max(np.abs(Bdr - Br)) < 1e-4
     """)
@@ -225,29 +234,6 @@ def test_jitted_train_step_on_host_mesh():
                 losses.append(float(m["loss"]))
         print("losses", losses)
         assert losses[-1] < losses[0], losses
-    """)
-
-
-def test_consensus_mix_shard_map():
-    run_py("""
-        import numpy as np, jax, jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-        from jax import shard_map
-        from repro.core.graph import erdos_renyi, metropolis_weights
-        from repro.core.decentral import consensus_mix, make_node_mesh
-        m = 8
-        W = erdos_renyi(m, 0.6, seed=0)
-        M = jnp.asarray(metropolis_weights(W))
-        g = jnp.asarray(np.random.default_rng(0).standard_normal((m, 5, 3)), jnp.float32)
-        mesh = make_node_mesh()
-        fn = shard_map(lambda gl, Ml: consensus_mix(gl, Ml),
-                       mesh=mesh, in_specs=(P("node"), P("node")), out_specs=P("node"))
-        out = np.asarray(jax.jit(fn)(g, M))
-        want = np.einsum("mk,kab->mab", np.asarray(M), np.asarray(g))
-        assert np.max(np.abs(out - want)) < 1e-5
-        # doubly-stochastic mixing preserves the mean
-        assert np.max(np.abs(out.mean(0) - np.asarray(g).mean(0))) < 1e-5
-        print("ok")
     """)
 
 

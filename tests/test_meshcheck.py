@@ -177,9 +177,10 @@ def test_both_axes_reduced_flag_is_clean():
 
 
 def test_mesh_ring_warm_driver_traces_clean_post_fix():
-    """The real code path: decsvm_path_mesh(schedule="ring", mode="warm")
-    — the caller the uniformity pass flagged (stop_axes joined only the
-    node axis around a whole-mesh ppermute) — now proves uniform."""
+    """The real code path: decsvm_path_mesh(mode="warm") on a ring W —
+    the caller the uniformity pass flagged (stop_axes joined only the
+    node axis around a whole-mesh ppermute) — proves uniform, its stop
+    pmax-agreed over both mesh axes."""
     from repro.core import graph
     from repro.core.admm import ADMMConfig
 
@@ -192,12 +193,15 @@ def test_mesh_ring_warm_driver_traces_clean_post_fix():
     from repro.core import decentral
     closed = jax.make_jaxpr(
         lambda X, y: decentral.decsvm_path_mesh(
-            X, y, W, [0.1, 0.05], cfg, schedule="ring", mode="warm",
+            X, y, W, [0.1, 0.05], cfg, mode="warm",
             check_every=2).path)(X, y)
     ana = analyze_driver("mesh-ring-warm", closed)
     assert ana.findings == [], [f.format() for f in ana.findings]
     assert ana.n_while >= 1
-    assert any("ppermute" in e for e in ana.fingerprint)
+    assert any("pmax[lam,node_chunk]" in e for e in ana.fingerprint)
+    if jax.device_count() > 1:
+        # the halo exchange elides its ppermutes on a 1-device mesh
+        assert any("ppermute" in e for e in ana.fingerprint)
 
 
 # -- deadlock negative #2: non-bijective ppermute chains ---------------------
@@ -338,7 +342,7 @@ def test_drift_gate_refuses_cross_device_count_comparison():
 def test_registry_covers_gossip_and_chunked_mesh_drivers():
     reg = drivers.build_registry()
     assert {"gossip", "mesh-2d-block"} <= set(reg)
-    assert len(reg) >= 20
+    assert len(reg) >= 19
 
 
 def test_repo_drivers_prove_uniform():
@@ -348,10 +352,10 @@ def test_repo_drivers_prove_uniform():
     report, kept, errors = meshcheck.run_report()
     assert kept == [], [f.format() for f in kept]
     assert errors == []
-    assert len(report["drivers"]) >= 20
+    assert len(report["drivers"]) >= 19
     # the sharded engines' schedules are non-empty and name their axes
-    assert any("ppermute[node]" in e
-               for e in report["drivers"]["sharded-ring"]["fingerprint"])
+    assert any("psum[node_chunk]" in e
+               for e in report["drivers"]["mesh-2d"]["fingerprint"])
     if jax.device_count() > 1:
         # the chunked engine elides ALL collectives on a 1-device mesh
         # (every block is local); its schedule only exists multi-device

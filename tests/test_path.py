@@ -102,7 +102,7 @@ def test_sharded_path_matches_batched(sim):
     cfg, X, y, W, lams = sim
     acfg = ADMMConfig(lam=0.0, max_iter=MAX_ITER)
     bat = np.asarray(decsvm_path_batched(X, y, W, jnp.asarray(lams), acfg))
-    shd = np.asarray(decentral.decsvm_path_sharded(
+    shd = np.asarray(decentral.decsvm_path_chunked(
         X, y, np.asarray(W), lams, acfg))
     np.testing.assert_allclose(shd, bat, atol=1e-5)
 
@@ -129,8 +129,8 @@ def test_cv_selection_alongside_bic(sim):
 
 
 def test_mesh_engine_via_select_lambda_path(sim):
-    """engine="mesh" routes selection through the 2-D (node, lam) mesh and
-    agrees with the dense engine on path and criteria."""
+    """engine="mesh" routes selection through the 2-D (node_chunk, lam)
+    mesh and agrees with the dense engine on path and criteria."""
     cfg, X, y, W, lams = sim
     acfg = ADMMConfig(lam=0.0, max_iter=MAX_ITER)
     best_d, B_d, table_d, res_d = tuning.select_lambda_path(

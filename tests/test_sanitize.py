@@ -26,7 +26,7 @@ from repro.core import path as path_mod
 from repro.core import sanitize, solver
 from repro.core.admm import ADMMConfig, decsvm_fit
 from repro.core.admm_adaptive import decsvm_fit_tol, decsvm_fit_uneven
-from repro.core.graph import ring
+from repro.core.graph import k_regular, ring
 from tools.jaxtrace import walk
 
 M, N, P = 4, 12, 8
@@ -87,10 +87,9 @@ def _recipes(mk):
         "path-warm": lambda X, y: path_mod.decsvm_path_warm(
             X, y, Wj, LAMS, pz, tol=1e-6, stop_rule="kkt",
             check_every=2)[0],
-        "sharded-gather": lambda X, y: decentral.decsvm_fit_sharded(
-            X, y, Wn, a, schedule="gather"),
-        "sharded-ring": lambda X, y: decentral.decsvm_fit_sharded(
-            X, y, Wn, a, schedule="ring"),
+        "chunked": lambda X, y: decentral.decsvm_fit_chunked(X, y, Wn, a),
+        "chunked-topology": lambda X, y: decentral.decsvm_fit_chunked(
+            X, y, k_regular(M, 2), a),
         "mesh-2d": lambda X, y: decentral.decsvm_path_mesh(
             X, y, Wn, lams_host, pz, mode="batched").path,
         "megakernel": lambda X, y: decsvm_fit(X, y, Wj, mkc),
@@ -282,9 +281,11 @@ def test_first_failing_check_wins_when_everything_is_poisoned():
 def test_sharded_mesh_and_grid_engines_reject_sanitize():
     cfg_s = ADMMConfig(lam=LAM, max_iter=ITERS, sanitize=True)
     with pytest.raises(NotImplementedError, match="sanitize"):
-        decentral.decsvm_fit_sharded(X0, Y0, Wn, cfg_s, schedule="gather")
+        decentral.decsvm_fit_chunked(X0, Y0, Wn, cfg_s)
     with pytest.raises(NotImplementedError, match="sanitize"):
         decentral.decsvm_path_mesh(X0, Y0, Wn, [LAM], cfg_s, mode="batched")
+    with pytest.raises(NotImplementedError, match="sanitize"):
+        decentral.decsvm_path_mesh(X0, Y0, Wn, [LAM], cfg_s, mode="warm")
     with pytest.raises(NotImplementedError, match="sanitize"):
         path_mod.decsvm_path_batched(X0, Y0, Wj, LAMS, cfg_s)
     with pytest.raises(NotImplementedError, match="sanitize"):

@@ -2,9 +2,9 @@
 must produce the identical trajectory from the single Algorithm-1 step.
 
 These parity tests replace the old per-pair agreement tests: since dense,
-tolerance, uneven-n, path, sharded and Pallas engines are all thin drivers
-over ``solver.make_step``, one shared fixture checks them all against the
-dense reference (and each other) to <= 1e-5.
+tolerance, uneven-n, path, node-chunked and Pallas engines are all thin
+drivers over ``solver.make_step``, one shared fixture checks them all
+against the dense reference (and each other) to <= 1e-5.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -15,7 +15,7 @@ from repro.core import (ADMMConfig, SimConfig, decsvm_fit, generate, solver,
                         tuning)
 from repro.core import decentral
 from repro.core.admm_adaptive import decsvm_fit_tol, decsvm_fit_uneven
-from repro.core.graph import ring
+from repro.core.graph import k_regular, ring
 from repro.core.path import decsvm_path_batched, decsvm_path_warm
 
 MAX_ITER = 60
@@ -27,7 +27,7 @@ ATOL = 1e-5
 def fixture():
     cfg = SimConfig(p=20, s=4, m=4, n=60)
     X, y, _ = generate(cfg, seed=1)
-    W = ring(cfg.m)            # ring graph: every schedule can run on it
+    W = ring(cfg.m)            # ring graph: also a k_regular(m, 2) topology
     return (cfg, jnp.asarray(X), jnp.asarray(y),
             jnp.asarray(W, jnp.float32), np.asarray(W))
 
@@ -59,10 +59,10 @@ def _drivers(fixture):
         "path-warm": lambda: decsvm_path_warm(X, y, Wj, lams1, pcfg,
                                               tol=-1.0,
                                               stop_rule="progress")[0][0],
-        "sharded-gather": lambda: decentral.decsvm_fit_sharded(
-            X, y, Wn, acfg, schedule="gather"),
-        "sharded-ring": lambda: decentral.decsvm_fit_sharded(
-            X, y, Wn, acfg, schedule="ring"),
+        "chunked": lambda: decentral.decsvm_fit_chunked(X, y, Wn, acfg),
+        # the same ring as a graph.BlockTopology (no dense W on the host)
+        "chunked-topology": lambda: decentral.decsvm_fit_chunked(
+            X, y, k_regular(cfg.m, 2), acfg),
         "mesh-2d": lambda: decentral.decsvm_path_mesh(
             X, y, Wn, [LAM], pcfg, mode="batched").path[0],
         # megakernel backend: whole rounds fused into one pallas_call —
@@ -90,7 +90,7 @@ def _drivers(fixture):
 
 @pytest.mark.parametrize("name", ["dense", "pallas", "tol", "uneven",
                                   "path-batched", "path-warm",
-                                  "sharded-gather", "sharded-ring",
+                                  "chunked", "chunked-topology",
                                   "mesh-2d", "megakernel", "megakernel-tol",
                                   "megakernel-path-warm",
                                   "mesh-2d-megakernel"])
@@ -159,7 +159,7 @@ def _dot_precisions(closed):
 
 
 @pytest.mark.parametrize("name", ["dense", "tol", "path-warm",
-                                  "sharded-gather", "mesh-2d"])
+                                  "chunked", "mesh-2d"])
 def test_streaming_contractions_are_pinned_fp32(fixture, name):
     """On the TPU an f32 dot at default precision rounds to bf16 (a 300-round
     fit moved by 5.7e-2 on v5e).  The CPU cannot show that numerically, so
@@ -234,13 +234,13 @@ def test_nonuniform_penalty_parity_dense_vs_sharded_vs_path(fixture):
     acfg = ADMMConfig(lam=LAM, max_iter=MAX_ITER)
     pcfg = ADMMConfig(lam=0.0, max_iter=MAX_ITER)
     dense = np.asarray(decsvm_fit(X, y, Wj, acfg, lam_weights=w))
-    sharded = np.asarray(decentral.decsvm_fit_sharded(
+    sharded = np.asarray(decentral.decsvm_fit_chunked(
         X, y, Wn, acfg, lam_weights=w))
-    ring_s = np.asarray(decentral.decsvm_fit_sharded(
-        X, y, Wn, acfg, schedule="ring", lam_weights=w))
+    ring_s = np.asarray(decentral.decsvm_fit_chunked(
+        X, y, k_regular(cfg.m, 2), acfg, lam_weights=w))
     path = np.asarray(decsvm_path_batched(
         X, y, Wj, jnp.asarray([LAM]), pcfg, lam_weights=w))[0]
-    spath = np.asarray(decentral.decsvm_path_sharded(
+    spath = np.asarray(decentral.decsvm_path_chunked(
         X, y, Wn, [LAM], pcfg, lam_weights=w))[0]
     mesh = np.asarray(decentral.decsvm_path_mesh(
         X, y, Wn, [LAM], pcfg, lam_weights=w).path[0])
@@ -295,10 +295,10 @@ def test_sharded_program_cache_hits(fixture):
     by function identity, so rebuilding per call would recompile)."""
     cfg, X, y, _, Wn = fixture
     acfg = ADMMConfig(lam=LAM, max_iter=5)
-    decentral.decsvm_fit_sharded(X, y, Wn, acfg)
-    before = decentral.build_sharded_admm.cache_info().hits
-    decentral.decsvm_fit_sharded(X, y, Wn, acfg)
-    assert decentral.build_sharded_admm.cache_info().hits == before + 1
+    decentral.decsvm_fit_chunked(X, y, Wn, acfg)
+    before = decentral.build_chunked_admm.cache_info().hits
+    decentral.decsvm_fit_chunked(X, y, Wn, acfg)
+    assert decentral.build_chunked_admm.cache_info().hits == before + 1
     decentral.decsvm_path_mesh(X, y, Wn, [LAM], acfg)
     before = decentral.build_mesh_path.cache_info().hits
     decentral.decsvm_path_mesh(X, y, Wn, [LAM], acfg)

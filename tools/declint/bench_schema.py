@@ -1,7 +1,7 @@
 """Schema for the repo-root ``BENCH_<name>.json`` benchmark artifacts.
 
 Every JSON-emitting bench (``benchmarks/bench_megakernel.py``,
-``bench_mesh_path.py``, ``bench_lambda_path.py``, ``bench_fit_serving.py``)
+``bench_lambda_path.py``, ``bench_fit_serving.py``)
 writes the same core shape; CI and ``benchmarks/run.py --bench <name>``
 validate the artifact against this module so a bench refactor cannot
 silently drop the fields the ROADMAP acceptance gates read.

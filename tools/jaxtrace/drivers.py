@@ -1,24 +1,22 @@
 """Traceable registry of every public driver entry point.
 
 Mirrors the 13-driver parity matrix of `tests/test_solver.py::_drivers`
-(the contract: those recipes ARE the public surface), plus the bf16
-megakernel mode (the reason contract (b) exists), the two
-batch-serving programs: `decsvm_path_select_many` — the fit-serving
-bucket executor behind `serving.fit` — and the mesh path engine, plus
-the chunked node-megabatch engine (`decsvm_fit_chunked` at m = 2x the
-forced device count, so the block-sparse neighbour-sum trace is real),
-the Metropolis gossip scan (`gossip.gossip_average`), and the chunked
-warm path on the (node_chunk, lam) mesh (`mesh-2d-block`, odd m — the
-ghost-padding + two-axis-stop trace).
+(the contract: those recipes ARE the public surface; its two chunked
+fits run here at m = 2x the forced device count, so the halo-exchange
+trace is real), plus the bf16 megakernel mode (the reason contract (b)
+exists), the fit-serving bucket executor (`decsvm_path_select_many`,
+behind `serving.fit`), the Metropolis gossip scan
+(`gossip.gossip_average`), and the warm path on the (node_chunk, lam)
+mesh (`mesh-2d-block`, odd m — the ghost-padding + two-axis-stop
+trace).
 
 Shapes are deliberately tiny (m=8, n=12, p=8, 2-point grids): tracing
 cost is what matters, not solution quality; `jax.make_jaxpr` never
-executes a round.  Sharded/mesh drivers trace against whatever CPU
-devices exist (a 1-device mesh still emits `shard_map` + collective
-equations, which is what the contracts inspect); the CLIs force host
-devices before importing jax so CI traces a real multi-device binding —
-4 for `tools.jaxtrace`, 8 for `tools.meshcheck` — so m must divide
-evenly by both (m=8 does; the sharded engines assert m % ndev == 0).
+executes a round.  Chunked/mesh drivers trace against whatever CPU
+devices exist (a 1-device mesh still emits `shard_map` equations, which
+is what the contracts inspect); the CLIs force host devices before
+importing jax so CI traces a real multi-device binding — 4 for
+`tools.jaxtrace`, 8 for `tools.meshcheck`.
 """
 from __future__ import annotations
 
@@ -34,7 +32,7 @@ LAM = 0.05
 #: the 13 parity drivers of tests/test_solver.py, by registry name
 PARITY_DRIVERS = (
     "dense", "pallas", "tol", "uneven", "path-batched", "path-warm",
-    "sharded-gather", "sharded-ring", "mesh-2d", "megakernel",
+    "chunked", "chunked-topology", "mesh-2d", "megakernel",
     "megakernel-tol", "megakernel-path-warm", "mesh-2d-megakernel",
 )
 
@@ -55,7 +53,7 @@ def build_registry() -> Dict[str, Driver]:
     from repro.core import path as path_mod
     from repro.core.admm import ADMMConfig, decsvm_fit
     from repro.core.admm_adaptive import decsvm_fit_tol, decsvm_fit_uneven
-    from repro.core.graph import ring
+    from repro.core.graph import k_regular, ring
 
     Wn = np.asarray(ring(M), np.float32)
     Wj = jnp.asarray(Wn)
@@ -103,10 +101,6 @@ def build_registry() -> Dict[str, Driver]:
         "path-warm": (lambda X, y: path_mod.decsvm_path_warm(
             X, y, Wj, lams, pz, tol=1e-6, stop_rule="kkt",
             check_every=2)[0], (X, y), False),
-        "sharded-gather": (lambda X, y: decentral.decsvm_fit_sharded(
-            X, y, Wn, a, schedule="gather"), (X, y), False),
-        "sharded-ring": (lambda X, y: decentral.decsvm_fit_sharded(
-            X, y, Wn, a, schedule="ring"), (X, y), False),
         "mesh-2d": (lambda X, y: decentral.decsvm_path_mesh(
             X, y, Wn, lams_host, pz, mode="batched").path, (X, y), False),
         "megakernel": (lambda X, y: decsvm_fit(X, y, Wj, mk), (X, y), False),
@@ -135,21 +129,22 @@ def build_registry() -> Dict[str, Driver]:
         "serving-bucket": (lambda Xs, ys: path_mod.decsvm_path_select_many(
             Xs, ys, Ws, lams, a, mode="warm", criterion="bic",
             check_every=2).best_B, (Xs, ys), False),
-        # chunked node-megabatch engine: m = 2x devices, so the trace
-        # carries the block-sparse neighbour sum (local dot + ppermute
-        # ring) and the ghost-padding guards
+        # chunked engine: m = 2x devices, so the trace carries the halo
+        # exchange (local dot + ppermute ring) and the ghost-padding
+        # guards; the same ring as a dense W and as a BlockTopology
         "chunked": (lambda X8, y8: decentral.decsvm_fit_chunked(
             X8, y8, W8n, a), (X8, y8), False),
+        "chunked-topology": (lambda X8, y8: decentral.decsvm_fit_chunked(
+            X8, y8, k_regular(2 * M, 2), a), (X8, y8), False),
         # lax.scan Metropolis gossip — the decentralized averaging
         # primitive the async-topology work will build on
         "gossip": (lambda v: gossip.gossip_average(v, Wj, rounds=ITERS),
                    (vals,), False),
-        # chunked node-megabatch INSIDE the lambda mesh: warm mode on the
-        # (node_chunk, lam) mesh at odd m, so the trace carries the
-        # block-sparse halo ppermutes, ghost padding, AND the
-        # two-axis pmax-agreed stop (the PR 9 deadlock surface)
+        # warm mode on the (node_chunk, lam) mesh at odd m, so the trace
+        # carries the halo ppermutes, ghost padding, AND the two-axis
+        # pmax-agreed stop (the deadlock surface of NONUNIFORM_STOP)
         "mesh-2d-block": (lambda Xb, yb: decentral.decsvm_path_mesh(
-            Xb, yb, Wblk, lams_host, pz, schedule="block", mode="warm",
+            Xb, yb, Wblk, lams_host, pz, mode="warm",
             check_every=2).path, (Xblk, yblk), False),
     }
     return {name: Driver(name, fn, args, bf16)
