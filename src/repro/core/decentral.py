@@ -54,6 +54,20 @@ def _pvary(x, axes):
     return jax.lax.pcast(x, axes, to="varying")
 
 
+@functools.lru_cache(maxsize=16)
+def _replicator(mesh: Mesh):
+    """The one program that replicates an array over ``mesh``: a reshard
+    to ``P()``, which XLA lowers to an all-gather on the devices.  The
+    ``reshard`` gives the output its replicated type where the mesh's axes
+    are explicit, also when a caller is traced (the ghost trim then
+    traces); ``out_shardings`` makes it replicated on an ``Auto`` mesh,
+    where the reshard alone would be a hint.  Built once per mesh; jit's
+    own cache holds one compiled program per shape."""
+    whole = NamedSharding(mesh, P())
+    return jax.jit(lambda x: jax.sharding.reshard(x, whole),
+                   out_shardings=whole)
+
+
 def _replicated(mesh: Mesh, x: Array) -> Array:
     """An engine's result, replicated on every device of ``mesh``.
 
@@ -61,8 +75,15 @@ def _replicated(mesh: Mesh, x: Array) -> Array:
     mesh with explicit axis types (``jax.make_mesh``'s default) slicing or
     indexing such an axis outside the program -- the ghost-row trim, the
     selected path row -- is a sharding type error, so every engine hands its
-    result back replicated."""
-    return jax.device_put(x, NamedSharding(mesh, P()))
+    result back replicated.
+
+    The copies are made on the devices, by an all-gather (``_replicator``).
+    A plain ``jax.device_put(x, NamedSharding(mesh, P()))`` would give the
+    same array, but JAX moves a row-sharded array to a replicated one on
+    the same devices through the host: no source shard holds a whole
+    replica, so it fetches the array and puts a copy back on every
+    device, while the devices wait."""
+    return _replicator(mesh)(x)
 
 
 def make_node_chunk_mesh(n_devices: Optional[int] = None) -> Mesh:

@@ -11,7 +11,9 @@ assert on.
 - the compiled program holds no whole-X or second-X buffer;
 - the cell's check fails the ``no_exchange`` and ``state_unchanged``
   faults;
-- ghost padding still serves an m that does not divide.
+- ghost padding still serves an m that does not divide;
+- every engine replicates its result on the devices, not through the
+  host, and a second fit at the same shapes compiles nothing.
 """
 import json
 import os
@@ -99,6 +101,65 @@ B14, t14 = decentral.decsvm_fit_chunked(Xs, ys, top14, acfg, **stop)
 B14d, _ = decsvm_fit_tol(jnp.asarray(Xs), jnp.asarray(ys),
                          jnp.asarray(top14.to_dense()), acfg, **stop)
 out["ghost_gap"] = float(np.max(np.abs(np.asarray(B14) - np.asarray(B14d))))
+
+# the hand-off: each engine's result is replicated on the devices, never
+# through the host's slow path of a resharding device_put, and is the
+# array that device_put gives, ghost trim and row selection included
+import time
+import jax._src.array as jarray
+from jax.sharding import NamedSharding, PartitionSpec
+from repro.core import trace
+
+slow = [0]
+slow_path = jarray.shard_sharded_device_array_slow_path
+def spy(*args, **kwargs):
+    slow[0] += 1
+    return slow_path(*args, **kwargs)
+jarray.shard_sharded_device_array_slow_path = spy
+by_device_put = lambda mesh, x: jax.device_put(
+    x, NamedSharding(mesh, PartitionSpec()))
+
+def handoff(name, run, mesh):
+    got = {}
+    for side in ("device_put", "replicator"):
+        saved = decentral._replicated
+        if side == "device_put":
+            decentral._replicated = by_device_put
+        try:
+            slow[0] = 0
+            res = jax.block_until_ready(run())
+            got[side] = (slow[0], [np.asarray(a) for a in res])
+        finally:
+            decentral._replicated = saved
+    out["slow_path_" + name] = got["replicator"][0]
+    out["slow_path_device_put_" + name] = got["device_put"][0]
+    out["same_as_device_put_" + name] = all(
+        np.array_equal(a, b) for a, b in zip(got["device_put"][1],
+                                             got["replicator"][1]))
+    out["replicated_" + name] = all(
+        a.sharding.is_fully_replicated
+        and a.sharding.device_set == set(mesh.devices.flat) for a in res)
+
+mesh4 = decentral.make_node_chunk_mesh(4)
+lams = [lam, 2 * lam]
+fcfg = dataclasses.replace(acfg, max_iter=20)
+handoff("fit", lambda: decentral.decsvm_fit_chunked(X, y, top, acfg, **stop),
+        mesh)
+handoff("fit_ghost", lambda: decentral.decsvm_fit_chunked(
+    Xs, ys, top14, acfg, mesh=mesh4, **stop), mesh4)
+handoff("path_chunked", lambda: (decentral.decsvm_path_chunked(
+    Xs, ys, top14, lams, fcfg, mesh=mesh4),), mesh4)
+mesh2d = jax.make_mesh((2, 2), ("node_chunk", "lam"))
+handoff("path_mesh", lambda: (lambda r: (r.best_B, r.path, r.criteria))(
+    decentral.decsvm_path_mesh(Xs, ys, top14, lams, fcfg, mesh=mesh2d)),
+    mesh2d)
+
+# a second fit at the same shapes builds no program: the hand-off's is
+# made once per mesh
+t0 = time.perf_counter()
+jax.block_until_ready(decentral.decsvm_fit_chunked(X, y, top, acfg, **stop))
+out["compiles_second_fit"] = trace.compiles.backend_between(
+    t0, time.perf_counter())
 print("RESULT " + json.dumps(out))
 """
 
@@ -146,6 +207,24 @@ def test_the_cells_check_fails_a_fault(readings, fault):
 
 def test_ghost_nodes_still_pad_an_m_that_does_not_divide(readings):
     assert readings["ghost_gap"] <= 2e-5, readings
+
+
+@pytest.mark.parametrize("engine", ["fit", "fit_ghost", "path_chunked",
+                                    "path_mesh"])
+def test_result_is_replicated_on_the_devices(readings, engine):
+    """Each engine hands its result back replicated over its mesh without
+    the host round trip of a resharding ``device_put`` (whose slow path
+    the spy sees), and the result is bit for bit the array that
+    ``device_put`` gives: m=16 that divides the 4 devices, m=14 that
+    pads ghost rows, and the path engines' trim and selected row."""
+    assert readings["slow_path_device_put_" + engine] >= 1, readings
+    assert readings["slow_path_" + engine] == 0, readings
+    assert readings["same_as_device_put_" + engine] is True
+    assert readings["replicated_" + engine] is True
+
+
+def test_a_second_fit_compiles_nothing(readings):
+    assert readings["compiles_second_fit"] == 0, readings
 
 
 # -- the cell's per-layer metrics on hand-made reductions ---------------------

@@ -238,3 +238,31 @@ def test_p2p_fit_program_compiles_for_four_chips(topo, mosaic, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1.1 * share
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + share < 16e9
+
+
+def test_p2p_handoff_is_one_all_gather_on_four_chips(topo):
+    """The chunked fit's result, B (1024, 20001) sharded by node over a
+    described v5e:2x2, is replicated by ``decentral._replicated``'s program
+    on the chips: one all-gather of the chip's (256, 20001) rows, no
+    temporary and no host transfer."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import decentral
+    m, p = 1024, 20001
+    mesh = Mesh(np.array(topo.devices), ("node_chunk",))
+    B = jax.ShapeDtypeStruct((m, p), jnp.float32,
+                             sharding=NamedSharding(mesh, P("node_chunk")))
+    compiled = decentral._replicator(mesh).lower(B).compile()
+    text = compiled.as_text()
+    gathers = re.findall(r"= (f32\[[\d,]+\])\S* all-gather(?:-start)?\(",
+                         text)
+    assert gathers == [f"f32[{m},{p}]"], gathers
+    assert not re.search(r"custom-call|infeed|outfeed|send|recv", text)
+    mem = compiled.memory_analysis()      # sizes of (8, 128) tiles
+    share = 4 * (m // 4) * p
+    assert share <= mem.argument_size_in_bytes < 1.01 * share
+    assert 4 * share <= mem.output_size_in_bytes < 4.04 * share
+    assert mem.temp_size_in_bytes == 0
